@@ -6,7 +6,8 @@
 # Or bring up the 3-broker TCP cluster: docker compose -f docker/compose.yml up
 #
 # The image runs CPU JAX by default; on a TPU VM mount the libtpu runtime and
-# drop the JAX_PLATFORMS pin (the kernel backend probes the default backend).
+# drop the JAX_PLATFORMS pin: the broker then resolves the chip in-process at
+# start-up and fails there if none answers (one broker process per chip).
 
 FROM python:3.12-slim
 

@@ -26,7 +26,7 @@ def make_router(put_s, get_s):
 
 
 def test_slow_link_routes_to_host():
-    r = make_router(put_s=0.07, get_s=0.07)  # tunnel-grade link
+    r = make_router(put_s=0.07, get_s=0.07)  # a link with a 70 ms floor
     bucket = ("t", 2048, 2048)
     # unseated host model: trial run on host
     assert r.choose(bucket) is r._host

@@ -67,6 +67,34 @@ class TestGatewayRpcs:
         # job is gone afterwards
         assert client.activate_jobs("rt_work") == []
 
+    def test_activate_keeps_jobs_when_a_later_partition_sheds(
+            self, stack, monkeypatch):
+        # jobs partition 1 already activated must reach the worker even
+        # when partition 2 answers RESOURCE_EXHAUSTED: aborting the call
+        # would strand them, activated, until their job timeout
+        from zeebe_tpu.gateway.broker_client import ResourceExhaustedError
+        from zeebe_tpu.protocol import ValueType
+
+        client, runtime = stack
+        client.deploy_resource(("shed.bpmn", one_task("shed", "shed_work")))
+        for _ in range(2):  # round-robin: one instance per partition
+            client.create_instance("shed")
+        real_submit = runtime.submit
+
+        def shedding(partition_id, record, **kw):
+            if partition_id == 2 and record.value_type == ValueType.JOB_BATCH:
+                raise ResourceExhaustedError("partition 2 sheds")
+            return real_submit(partition_id, record, **kw)
+
+        monkeypatch.setattr(runtime, "submit", shedding)
+        jobs = client.activate_jobs("shed_work", request_timeout_ms=5_000)
+        assert len(jobs) == 1
+        monkeypatch.undo()
+        jobs += client.activate_jobs("shed_work", request_timeout_ms=5_000)
+        assert len(jobs) == 2
+        for job in jobs:
+            client.complete_job(job.key, {})
+
     def test_create_with_result(self, stack):
         client, _ = stack
         client.deploy_resource(("wr.bpmn", one_task("wr", "wr_work")))

@@ -128,100 +128,6 @@ class TestSupervisor:
 
 
 # ---------------------------------------------------------------------------
-# killable device probe
-
-
-class TestKillableProbe:
-    def test_wedged_probe_killed_at_deadline(self):
-        from zeebe_tpu.utils.backend_probe import probe_with_diagnostics
-
-        t0 = time.monotonic()
-        res, diag = probe_with_diagnostics(
-            probe_cmd=_sleeper(600), timeout=1, use_cache=False)
-        elapsed = time.monotonic() - t0
-        assert res is None
-        assert diag["outcome"] == "probe-killed"
-        assert diag["killed"] is True
-        assert diag["timeout_s"] == 1
-        assert elapsed < 8, f"kill took {elapsed}s — deadline not enforced"
-
-    def test_probe_verdict_memoized_per_process(self):
-        # broker startup, worker boot, and mesh construction all consult the
-        # probe: the SECOND consult must reuse the verdict, not pay another
-        # subprocess deadline
-        from zeebe_tpu.utils.backend_probe import probe_with_diagnostics
-
-        cmd = _sleeper(601)  # distinct from other tests' commands
-        res1, diag1 = probe_with_diagnostics(probe_cmd=cmd, timeout=1)
-        assert res1 is None and "cached" not in diag1
-        t0 = time.monotonic()
-        res2, diag2 = probe_with_diagnostics(probe_cmd=cmd, timeout=1)
-        assert res2 is None
-        assert diag2["cached"] is True
-        assert time.monotonic() - t0 < 0.5, "cached probe paid the deadline"
-
-    def test_probe_failure_is_a_verdict_not_an_exception(self):
-        from zeebe_tpu.utils.backend_probe import probe_with_diagnostics
-
-        res, diag = probe_with_diagnostics(
-            probe_cmd=[sys.executable, "-c", "raise SystemExit(3)"],
-            timeout=5)
-        assert res is None
-        assert diag["outcome"] == "nonzero-exit"
-        assert diag["rc"] == 3
-
-    def test_env_pinned_cpu_short_circuits(self, monkeypatch):
-        from zeebe_tpu.utils.backend_probe import probe_with_diagnostics
-
-        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-        monkeypatch.setenv(
-            "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-        res, diag = probe_with_diagnostics()
-        assert res == ("cpu", 8)
-        assert diag["outcome"] == "env-pinned-cpu"
-
-    def test_probe_timeout_env_override(self, monkeypatch):
-        from zeebe_tpu.utils.backend_probe import (
-            PROBE_TIMEOUT_SECS,
-            probe_timeout_secs,
-        )
-
-        monkeypatch.delenv("ZEEBE_PROBE_TIMEOUT_S", raising=False)
-        assert probe_timeout_secs() == PROBE_TIMEOUT_SECS
-        monkeypatch.setenv("ZEEBE_PROBE_TIMEOUT_S", "7")
-        assert probe_timeout_secs() == 7
-        monkeypatch.setenv("ZEEBE_PROBE_TIMEOUT_S", "not-a-number")
-        assert probe_timeout_secs() == PROBE_TIMEOUT_SECS
-
-    def test_wedged_probe_degrades_mesh_to_host_devices(self):
-        """THE acceptance scenario: a wedged device probe (subprocess that
-        never answers) is killed at its deadline and the process continues
-        on host devices — mesh construction included — instead of hanging.
-        Runs in a subprocess with JAX_PLATFORMS unset so the in-process
-        fast path cannot mask the probe."""
-        env = dict(os.environ, PYTHONPATH=REPO)
-        env.pop("JAX_PLATFORMS", None)
-        env["ZEEBE_PROBE_CMD"] = f"{sys.executable} -c 'import time; time.sleep(600)'"
-        env["ZEEBE_PROBE_TIMEOUT_S"] = "2"
-        code = (
-            "from zeebe_tpu.parallel.mesh import make_mesh\n"
-            "import jax\n"
-            "mesh = make_mesh()\n"
-            "assert str(jax.config.jax_platforms or '').startswith('cpu')\n"
-            "print('DEGRADED-OK', mesh.devices.size, "
-            "jax.devices()[0].platform)\n")
-        t0 = time.monotonic()
-        proc = subprocess.run(
-            [sys.executable, "-c", code], env=env, cwd=REPO,
-            capture_output=True, text=True, timeout=120)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        assert "DEGRADED-OK" in proc.stdout
-        assert "cpu" in proc.stdout
-        # jax import + one 2s probe kill, not a 240s hang
-        assert time.monotonic() - t0 < 60
-
-
-# ---------------------------------------------------------------------------
 # gateway ↔ worker protocol over the deterministic loopback (fast, tier-1)
 
 

@@ -3,10 +3,11 @@
 The engine's correctness story rests on replicated-state-machine determinism:
 replay must rebuild byte-identical state, so appliers and state facades can
 never touch wall clocks, RNGs, or iteration-order-sensitive constructs; no
-code may initialize the default jax backend outside the killable probe; pump
+code may query the jax backend outside the one module that chooses the
+device; pump
 hooks must never block; ingress/query threads must read through committed
 accessors. Every one of those is an *architectural invariant* that reviewers
-kept re-discovering by hand (the wedged-tunnel rule, the ColdStore
+kept re-discovering by hand (the one-module-chooses-the-device rule, the ColdStore
 dict-changed-size fix, the drifted `_collect_flight_dumps` copies) — zlint
 machine-checks them instead.
 

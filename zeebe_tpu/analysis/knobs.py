@@ -293,12 +293,6 @@ KNOB_NOTES: dict[str, str] = {
         "serviceContext.service for stackdriver-shaped logs"),
     "ZEEBE_LOG_STACKDRIVER_SERVICEVERSION": (
         "serviceContext.version for stackdriver-shaped logs"),
-    "ZEEBE_PROBE_CMD": (
-        "test/chaos seam: replaces the killable device-probe child command "
-        "(simulate a wedged tunnel from outside the process)"),
-    "ZEEBE_PROBE_TIMEOUT_S": (
-        "killable device probe: hard SIGKILL deadline (seconds, default "
-        "90) for the default-backend query subprocess"),
     "ZEEBE_REQUEST_DEDUPE_RETENTIONPOSITIONS": (
         "replicated request-dedupe retention: entries age out once the log "
         "advances this many positions past them (default 100k). "

@@ -36,7 +36,8 @@ offline gate joins against the injected-fault ledger.
 Scope caveats (also in docs/device-faults.md): the ladder is per-BROKER
 (one state for every partition in the process, matching the shared
 router), per-process not per-chip, and it watches the *direct* dispatch
-path — mesh dispatch has its own killable probe (PR 7).
+path only — mesh dispatch is neither watched nor shadow-verified
+(ROADMAP S7).
 """
 
 from __future__ import annotations

@@ -301,16 +301,27 @@ class GatewayService:
                         partition_id, request.type,
                         tenant_filter.get("tenantIds", [DEFAULT_TENANT])):
                     continue
-                record = self._submit(
-                    context, partition_id,
-                    command(ValueType.JOB_BATCH, JobBatchIntent.ACTIVATE, {
-                        "type": request.type,
-                        "worker": request.worker or "default",
-                        "timeout": request.timeout or 300_000,
-                        "maxJobsToActivate": remaining,
-                        **tenant_filter,
-                    }),
-                )
+                activate = command(ValueType.JOB_BATCH, JobBatchIntent.ACTIVATE, {
+                    "type": request.type,
+                    "worker": request.worker or "default",
+                    "timeout": request.timeout or 300_000,
+                    "maxJobsToActivate": remaining,
+                    **tenant_filter,
+                })
+                if jobs:
+                    # jobs an earlier partition already activated must reach
+                    # the worker: a later partition that sheds, times out or
+                    # has no leader ends the fan-out — aborting the call would
+                    # strand them, activated, until their job timeout
+                    try:
+                        record = self.runtime.submit(partition_id, activate)
+                    except (NoLeaderError, ResourceExhaustedError,
+                            RequestTimeoutError):
+                        break
+                    if record.is_rejection:
+                        break
+                else:
+                    record = self._submit(context, partition_id, activate)
                 for key, job in zip(record.value.get("jobKeys", []),
                                     record.value.get("jobs", [])):
                     jobs.append(self._activated_job(request, key, job))

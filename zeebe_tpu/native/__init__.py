@@ -14,6 +14,8 @@ Current components:
 
 from __future__ import annotations
 
+import glob
+import hashlib
 import importlib.util
 import logging
 import os
@@ -27,18 +29,24 @@ logger = logging.getLogger("zeebe_tpu.native")
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LOCK = threading.Lock()
 _CACHE: dict[str, object | None] = {}
+#: modules this process compiled from source (vs. found already built)
+BUILT_HERE: set[str] = set()
 
 
 def _build_and_load(module_name: str, source: str):
     src = os.path.join(_DIR, source)
     tag = sysconfig.get_config_var("SOABI") or "so"
-    out = os.path.join(_DIR, f"{module_name}.{tag}.so")
-    if not os.path.exists(out) or os.path.getmtime(out) < os.path.getmtime(src):
+    # the source's content hash is part of the file name: a binary is only
+    # ever loaded for the exact source it was built from, so a ``.so`` that
+    # arrived by copy (mtimes mean nothing there) is never trusted over it
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(_DIR, f"{module_name}.{digest}.{tag}.so")
+    if not os.path.exists(out):
         include = sysconfig.get_paths()["include"]
         # compile to a per-pid temp path and rename into place: rename is
         # atomic, so concurrent processes racing the build can never dlopen a
-        # half-written .so (they either see the old complete one or the new
-        # complete one)
+        # half-written .so
         tmp = f"{out}.{os.getpid()}.tmp"
         cmd = [
             os.environ.get("CC", "gcc"), "-O2", "-shared", "-fPIC",
@@ -50,6 +58,10 @@ def _build_and_load(module_name: str, source: str):
         finally:
             if os.path.exists(tmp):
                 os.unlink(tmp)
+        for stale in glob.glob(os.path.join(_DIR, f"{module_name}.*.so")):
+            if stale != out:
+                os.unlink(stale)  # binaries of older sources
+        BUILT_HERE.add(module_name)
     spec = importlib.util.spec_from_file_location(module_name, out)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)

@@ -37,7 +37,6 @@ import numpy as np
 from zeebe_tpu.parallel.mesh import (
     BATCH_AXIS,
     make_mesh,
-    shard_map_compat,
     state_specs,
 )
 
@@ -116,6 +115,9 @@ class MeshKernelRunner:
         self.coalesced_dispatches = 0
         self.windows_slept = 0
         self.windows_skipped = 0
+        #: every device that held a shard of a dispatch's result — a mesh
+        #: that quietly put all shards on one device shows up here
+        self.shard_devices: set = set()
 
     # -- the deterministic core: one sharded dispatch per compatible batch --
 
@@ -205,6 +207,7 @@ class MeshKernelRunner:
         overflow = [False] * n_req
         for _ in range(max(1, max_steps // chunk)):
             state, packed = collect(lead.device_tables, state)
+            self.shard_devices |= packed.devices()
             flat = np.asarray(jax.device_get(packed))  # [chunk, S*row_len]
             for ri in range(n_req):
                 if quiesced[ri]:
@@ -259,7 +262,7 @@ class MeshKernelRunner:
                     new_state[name] = new_state[name][None]
                 return new_state, packed
 
-            fn = jax.jit(shard_map_compat(
+            fn = jax.jit(jax.shard_map(
                 local,
                 mesh=self.mesh,
                 in_specs=(

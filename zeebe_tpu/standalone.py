@@ -131,7 +131,7 @@ def _run_workers_mode(args) -> int:
         supervisor=supervisor,
     )
     # signal handlers BEFORE anything spawns: a SIGTERM during the (long —
-    # probe deadline + jax import) boot window must run the teardown below,
+    # device bring-up + jax import) boot window must run the teardown below,
     # not the default action that would orphan the detached workers
     stop = threading.Event()
     signal.signal(signal.SIGINT, lambda *a: stop.set())
@@ -143,15 +143,13 @@ def _run_workers_mode(args) -> int:
         # sit INSIDE the teardown scope: a thread-start failure after the
         # spawn would otherwise orphan the detached worker processes
         runtime.start()
-        # worker boot pays the killable device probe BEFORE binding
-        # messaging (up to ZEEBE_PROBE_TIMEOUT_S on a wedged host), then
-        # jax import + broker recovery: budget for all of it, in short
-        # slices so a stop signal interrupts the wait
+        # worker boot resolves its device (the TPU runtime takes tens of
+        # seconds to come up) BEFORE binding messaging, then jax import +
+        # broker recovery: budget for all of it, in short slices so a stop
+        # signal interrupts the wait
         import time as _time
 
-        from zeebe_tpu.utils.backend_probe import probe_timeout_secs
-
-        boot_deadline = _time.monotonic() + probe_timeout_secs() + 120.0
+        boot_deadline = _time.monotonic() + 180.0
         while not stop.is_set():
             try:
                 runtime.await_leaders(timeout_s=2.0)

@@ -12,7 +12,7 @@ fault class lands exactly where real hardware would produce it:
 - **dispatch_fail** — a dispatch raises after compile (runtime launch
   failure, a dying device rejecting work);
 - **stall** — a device fetch blocks ``stall_ms`` before returning (the
-  wedged-tunnel / dying-HBM latency tail — "Gray Failure"'s
+  stalled-runtime / dying-HBM latency tail — "Gray Failure"'s
   degraded-not-dead shape; trips the backend's dispatch watchdog);
 - **chunk_fail** — a fetch raises mid-group after earlier chunks already
   landed (partial-group device failure);
