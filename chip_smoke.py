@@ -210,26 +210,21 @@ class CompileLedger:
 # the served system: standalone.py's constructors, in this process
 
 
-class AcceleratorOnly:
-    """Built lazily (needs zeebe_tpu importable): a BackendRouter whose rule
-    is 'the accelerator'. The default rule weighs the measured link against
-    the host XLA backend and may send groups host-ward; this script tests the
-    chip, so every group goes there and the measured link is only printed."""
+def hold_groups_on_the_accelerator():
+    """Install a process-wide BackendRouter whose rule is 'the accelerator'.
+    The default rule weighs the measured link against the host XLA backend
+    and sends groups host-ward when nine transfers cost over 2 ms (on the
+    v5e they do); this script tests the chip, so every group goes there and
+    the measured link is only printed (ROADMAP D2 decides the rule)."""
+    from zeebe_tpu.utils.device_link import BackendRouter, install_shared_router
 
-    @staticmethod
-    def install():
-        from zeebe_tpu.utils.device_link import (
-            BackendRouter,
-            install_shared_router,
-        )
+    class AcceleratorOnly(BackendRouter):
+        def choose(self, bucket):
+            return self.accel_device()
 
-        class _AcceleratorOnly(BackendRouter):
-            def choose(self, bucket):
-                return self.accel_device()
-
-        router = _AcceleratorOnly()
-        install_shared_router(router)
-        return router
+    router = AcceleratorOnly()
+    install_shared_router(router)
+    return router
 
 
 class Served:
@@ -243,6 +238,7 @@ class Served:
         })
         require(cfg.base.kernel_backend, "kernel backend is off in the config")
         self.partitions = partitions
+        self.data_dir = data_dir
         self.runtime = ClusterRuntime(
             exporters_factory=lambda: {"smoke": _tally_exporter(tally)},
             kernel_backend=cfg.base.kernel_backend,
@@ -263,9 +259,13 @@ class Served:
         (self.broker,) = self.runtime.brokers.values()
 
     def stop(self) -> None:
+        import shutil
+
         if self.gateway is not None:
             self.gateway.stop()
         self.runtime.stop()
+        # tens of MB of journals and snapshots: not worth carrying home
+        shutil.rmtree(self.data_dir, ignore_errors=True)
 
     def leaders(self) -> dict:
         return {pid: self.broker.partitions[pid]
@@ -410,7 +410,7 @@ def mixed_plan(models, per_definition: int, partitions: int, seed: int) -> list:
     rng = random.Random(seed)
     head, rest = [], []
     for m in models:
-        xs = [-5, 5, 15, 25, 35, 45] * (per_definition // 6 + 1)
+        xs = [(-5, 5, 15, 25, 35, 45)[i % 6] for i in range(per_definition)]
         rng.shuffle(xs)
         entries = [(m.process_id, {"x": xs[i]}, i % 125 == 124)
                    for i in range(per_definition)]
@@ -779,7 +779,7 @@ def main(argv: list[str] | None = None) -> int:
 
     out_dir = Path(args.out) / time.strftime("%Y%m%dT%H%M%S")
     out_dir.mkdir(parents=True)
-    router = AcceleratorOnly.install()
+    router = hold_groups_on_the_accelerator()
     say(f"  routing: every group held on the accelerator; link "
         f"put/get measured in-process at the first group")
 

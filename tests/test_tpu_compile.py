@@ -94,11 +94,12 @@ def table_sets():
     return {"one_task": registry_tables([bench.one_task()]), "mixed9": mixed}
 
 
-def _abstract(tree, sharding_of):
+def _abstract(tree, sharding, scalars_as=()):
+    """``tree``'s arrays as ShapeDtypeStructs placed by ``sharding``."""
     import jax
 
-    return {k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding_of(k))
-            for k, v in tree.items()}
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape or scalars_as, a.dtype, sharding=sharding), tree)
 
 
 def _group_shapes(tables, instances: int, shards: int = 1):
@@ -116,12 +117,8 @@ def _group_shapes(tables, instances: int, shards: int = 1):
 
 
 def _one_chip_args(tables, instances, one_chip):
-    import jax
-
     dt, state = _group_shapes(tables, instances)
-    return (jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=one_chip), dt),
-            _abstract(state, lambda _k: one_chip))
+    return _abstract(dt, one_chip), _abstract(state, one_chip)
 
 
 def _compile_run_collect(set_name, instances, table_sets, one_chip, mesh):
@@ -190,7 +187,6 @@ def _compile_decision(_set_name, contexts, table_sets, one_chip, mesh):
 def _compile_mesh_collect(set_name, instances, table_sets, one_chip, mesh):
     """The sharded program of ``MeshKernelRunner._sharded_collect`` over the
     four described chips, arguments placed as ``_dispatch`` places them."""
-    import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from zeebe_tpu.parallel.mesh import BATCH_AXIS
@@ -198,14 +194,10 @@ def _compile_mesh_collect(set_name, instances, table_sets, one_chip, mesh):
 
     tables = table_sets[set_name]
     dt, state = _group_shapes(tables, instances, shards=MESH_CHIPS)
-    rows = NamedSharding(mesh, P(BATCH_AXIS))
-    replicated = NamedSharding(mesh, P())
     # per-shard scalar tails ride as length-S rows (mesh_runner._dispatch)
-    state = {k: jax.ShapeDtypeStruct(v.shape or (MESH_CHIPS,), v.dtype,
-                                     sharding=rows)
-             for k, v in state.items()}
-    dt = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=replicated), dt)
+    state = _abstract(state, NamedSharding(mesh, P(BATCH_AXIS)),
+                      scalars_as=(MESH_CHIPS,))
+    dt = _abstract(dt, NamedSharding(mesh, P()))
     collect = MeshKernelRunner(mesh=mesh)._sharded_collect(
         CHUNK_STEPS, tables.kernel_config)
     compiled = collect.lower(dt, state).compile()
