@@ -1,0 +1,348 @@
+#!/usr/bin/env python3
+"""The load generator and the job workers: a process of its own, as clients
+are in every deployment. It never imports ``jax``; it talks to the gateway
+only through ``ZeebeTpuClient``/``JobWorker`` over loopback gRPC, and to the
+harness through JSON lines on stdin/stdout:
+
+    {"cmd": "deploy"}        deploy the mix's definitions, start the workers
+    {"cmd": "first_touch"}   one client, sequentially, definition order
+    {"cmd": "warm"}          start the mix's traffic (the warm-up stretch)
+    {"cmd": "window", "t0": <monotonic>, "seconds": s}
+                             the measured stretch; answers once every request
+                             due in it was answered or given up
+    {"cmd": "stop"}          stop the workers, answer their counts, exit
+
+Every request is stamped with the time it was **due** (``time.monotonic()``,
+which parent and child share on one machine): in the open loop the schedule's
+time, in the closed loop the moment its creator was free to send it.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import random
+import sys
+import threading
+import time
+from collections import Counter
+from concurrent.futures import ThreadPoolExecutor
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, os.path.dirname(HERE))   # the checkout: zeebe_tpu.client
+sys.path.insert(0, HERE)
+
+import definitions as defs  # noqa: E402
+import schedule  # noqa: E402
+
+
+class Retrying:
+    """A client under backpressure: retried with backoff and counted, never
+    dropped silently (copied from ``chip_smoke.Retrying``; it also counts the
+    RPCs sent, for ``gateway_shed_share``)."""
+
+    def __init__(self, seed: int) -> None:
+        import grpc
+
+        self.grpc = grpc
+        self.retryable = {grpc.StatusCode.RESOURCE_EXHAUSTED,
+                          grpc.StatusCode.UNAVAILABLE,
+                          grpc.StatusCode.DEADLINE_EXCEEDED}
+        self.not_found = grpc.StatusCode.NOT_FOUND
+        self.counts: Counter = Counter()
+        self.lock = threading.Lock()
+        self._rng = random.Random(seed)
+
+    def call(self, what: str, fn, *args, not_found: str = "raise",
+             give_up_at: float | None = None, **kw):
+        """Returns ``(result, attempts, sheds)``; ``result`` is None where a
+        NOT_FOUND was only counted (a job delivered twice) or the call was
+        given up (``give_up_at`` passed, or an answer that no retry cures)."""
+        delay, attempts, sheds = 0.01, 0, 0
+        while True:
+            attempts += 1
+            try:
+                return fn(*args, **kw), attempts, sheds
+            except self.grpc.RpcError as err:
+                code = err.code()
+                with self.lock:
+                    self.counts[f"{what}:{code.name}"] += 1
+                    jitter = 0.5 + self._rng.random()
+                sheds += code == self.grpc.StatusCode.RESOURCE_EXHAUSTED
+                if code == self.not_found and not_found == "count":
+                    return None, attempts, sheds
+                known = code in self.retryable or (
+                    code == self.not_found and not_found == "retry")
+                if not known or (give_up_at is not None
+                                 and time.monotonic() > give_up_at):
+                    with self.lock:
+                        self.counts[f"{what}:given_up"] += 1
+                    return None, attempts, sheds
+            time.sleep(delay * jitter)
+            delay = min(delay * 2, 1.0)
+
+
+class LoadGen:
+    def __init__(self, address: str, traffic: dict, partitions: int,
+                 seed: int, out_dir: str) -> None:
+        from zeebe_tpu.client import ZeebeTpuClient
+
+        self.Client = ZeebeTpuClient
+        self.address = address
+        self.traffic = traffic
+        self.partitions = partitions
+        self.seed = seed
+        self.out_dir = out_dir
+        self.retry = Retrying(seed)
+        self.definitions = defs.build_definitions(traffic["definitions"])
+        self.payload = defs.make_payload(traffic.get("payload"), seed)
+        self.give_up_s = float(traffic.get("give_up_s", 10.0))
+        self.records: list = []
+        self.completed_jobs: list = []   # keys of acknowledged completions
+        self.rpcs = 0
+        self.sheds = 0
+        self.lock = threading.Lock()
+        self.local = threading.local()
+        self.clients: list = []
+        self.workers: list = []
+        self.warm_stop = threading.Event()
+        self.warm_threads: list = []
+        self.error: BaseException | None = None
+        self.t0: float | None = None
+        self.t_end: float | None = None
+
+    def client(self):
+        if not hasattr(self.local, "client"):
+            self.local.client = self.Client(self.address)
+            with self.lock:
+                self.clients.append(self.local.client)
+        return self.local.client
+
+    # -- one create ---------------------------------------------------------
+
+    def create(self, pid: str, variables: dict, due: float, phase: str) -> dict:
+        sent = time.monotonic()
+        inst, attempts, sheds = self.retry.call(
+            "create", self.client().create_instance, pid, variables=variables,
+            not_found="retry", give_up_at=sent + self.give_up_s)
+        ack = time.monotonic()
+        rec = {"phase": phase, "pid": pid, "x": variables.get("x"), "due": due,
+               "sent": sent, "ack": ack, "ok": inst is not None,
+               "key": None if inst is None else inst.process_instance_key,
+               "attempts": attempts, "sheds": sheds}
+        with self.lock:
+            self.records.append(rec)
+            self.rpcs += attempts
+            self.sheds += sheds
+        return rec
+
+    # -- phases -------------------------------------------------------------
+
+    def deploy(self) -> dict:
+        from zeebe_tpu.client import JobWorker
+
+        resources = [(f"{d['id']}.bpmn", defs.to_bpmn_xml(d))
+                     for d in self.definitions]
+        result, _, _ = self.retry.call(
+            "deploy", self.client().deploy_resource, *resources,
+            give_up_at=time.monotonic() + 60.0)
+        if result is None:
+            raise RuntimeError("the deployment was refused")
+        w = self.traffic["workers"]
+        delay_s = float(w["completion_delay_ms"]) / 1e3
+        # upstream's worker answers every job with its own payload file
+        returned = self.payload if w["complete_with_payload"] else {}
+
+        def complete(_job_client, job, client) -> None:
+            if delay_s:
+                time.sleep(delay_s)     # the work a job stands for
+
+            def acknowledged() -> bool:
+                client.complete_job(job.key, returned)
+                return True
+
+            done, attempts, sheds = self.retry.call(
+                "complete", acknowledged, not_found="count",
+                give_up_at=time.monotonic() + 60.0)
+            with self.lock:
+                self.rpcs += attempts
+                self.sheds += sheds
+                if done:        # acknowledged: it has to be durable
+                    self.completed_jobs.append(job.key)
+
+        for job_type in defs.job_types(self.definitions):
+            for _ in range(int(w["per_job_type"])):
+                client = self.Client(self.address)
+                self.clients.append(client)
+                self.workers.append(JobWorker(
+                    client, job_type,
+                    lambda jc, job, client=client: complete(jc, job, client),
+                    # an activation whose response was lost comes back after
+                    # this long, not after the five-minute default
+                    timeout_ms=60_000, auto_complete=False,
+                    max_backoff_s=float(w["max_backoff_s"])).start())
+        return {"definitions": len(resources), "workers": len(self.workers),
+                "payload_bytes": defs.payload_bytes(self.payload)}
+
+    def first_touch(self) -> dict:
+        plan = defs.first_touch_plan(self.definitions, self.partitions,
+                                     self.payload)
+        recs = [self.create(pid, variables, time.monotonic(), "first_touch")
+                for pid, variables in plan]
+        if not all(r["ok"] for r in recs):
+            raise RuntimeError("a first-touch create was refused")
+        return {"keys": [r["key"] for r in recs]}
+
+    def _closed_creator(self, i: int, n: int) -> None:
+        """One closed-loop client: the warm-up's plan until the window opens,
+        then its share of the window's plan until the window closes — its
+        next request goes out when the last was answered, with no gap at the
+        change-over."""
+        plans = {phase: defs.request_plan(self.definitions, n * 2000,
+                                          self.payload, seed)[i::n]
+                 for phase, seed in (("warm", self.seed ^ 0xAAAA),
+                                     ("window", self.seed))}
+        for phase in ("warm", "window"):
+            for pid, variables in plans[phase]:
+                due = time.monotonic()
+                if phase == "warm" and self.t0 is not None and due >= self.t0:
+                    break
+                if phase == "window" and due >= self.t_end:
+                    return
+                self.create(pid, variables, due, phase)
+            else:
+                raise RuntimeError("a closed-loop creator ran out of plan")
+
+    def _spawn(self, target, *args) -> threading.Thread:
+        t = threading.Thread(target=self._guard, args=(target, *args),
+                             daemon=True)
+        t.start()
+        return t
+
+    def _guard(self, target, *args) -> None:
+        try:
+            target(*args)
+        except BaseException as exc:  # noqa: BLE001 — reported, then re-raised
+            self.error = exc
+            raise
+
+    def warm(self) -> dict:
+        """The mix's own traffic, until the window takes over."""
+        loop = self.traffic["loop"]
+        if loop["kind"] == "closed":
+            n = int(loop["clients"])
+            self.warm_threads = [self._spawn(self._closed_creator, i, n)
+                                 for i in range(n)]
+        else:
+            self.pool = ThreadPoolExecutor(max_workers=int(loop["senders"]))
+            self.warm_threads = [self._spawn(self._open_warm, loop)]
+        return {}
+
+    def _open_warm(self, loop: dict) -> None:
+        rng = random.Random(self.seed ^ 0xAAAA)
+        plan = defs.request_plan(self.definitions, 100_000, self.payload,
+                                 self.seed ^ 0xAAAA)
+        due = time.monotonic()
+        for pid, variables in plan:
+            due += (1.0 / float(loop["rate_per_s"])
+                    if loop["arrivals"] == "fixed"
+                    else rng.expovariate(float(loop["rate_per_s"])))
+            while (left := due - time.monotonic()) > 0:
+                if self.warm_stop.wait(min(left, 0.05)):
+                    return
+            if self.warm_stop.is_set():     # running late: no gap was waited
+                return
+            self.pool.submit(self.create, pid, variables, due, "warm")
+
+    def window(self, t0: float, seconds: float) -> dict:
+        loop = self.traffic["loop"]
+        self.t0, self.t_end = t0, t0 + seconds
+        while (left := t0 - time.monotonic()) > 0:
+            time.sleep(min(0.01, left))
+        self.warm_stop.set()
+        rpcs0, sheds0 = self.rpcs, self.sheds
+        if loop["kind"] == "closed":
+            for t in self.warm_threads:     # they go on into the window's plan
+                t.join()
+        else:
+            offsets = schedule.offsets_of(loop, seconds, self.seed)
+            plan = defs.request_plan(self.definitions, len(offsets),
+                                     self.payload, self.seed)
+            futures = []
+            for offset, (pid, variables) in zip(offsets, plan):
+                due = t0 + offset
+                while (left := due - time.monotonic()) > 0:
+                    time.sleep(min(left, 0.05))
+                futures.append(self.pool.submit(self.create, pid, variables,
+                                                due, "window"))
+            for f in futures:
+                f.result()
+            for t in self.warm_threads:
+                t.join()
+            self.pool.shutdown(wait=True)
+        if self.error is not None:
+            raise self.error
+        with self.lock:
+            records = [r for r in self.records if r["phase"] != "first_touch"]
+            window = [r for r in records if r["phase"] == "window"]
+        path = os.path.join(self.out_dir, "requests.jsonl")
+        with open(path, "w") as out:
+            for r in records:
+                out.write(json.dumps(r) + "\n")
+        return {"records_file": path, "window_requests": len(window),
+                "rpcs": self.rpcs - rpcs0, "sheds": self.sheds - sheds0}
+
+    def stop(self) -> dict:
+        for w in self.workers:
+            w.stop()
+        for c in self.clients:
+            c.close()
+        path = os.path.join(self.out_dir, "completed_jobs.json")
+        with open(path, "w") as out:
+            json.dump(self.completed_jobs, out)
+        return {"jobs_handled": sum(w.handled_count for w in self.workers),
+                "jobs_file": path,
+                "job_handlers_failed": sum(w.failed_count for w in self.workers),
+                "retries": dict(self.retry.counts),
+                "rpcs": self.rpcs, "sheds": self.sheds}
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--address", required=True)
+    parser.add_argument("--traffic", required=True, help="the mix's data file")
+    parser.add_argument("--partitions", type=int, required=True)
+    parser.add_argument("--seed", type=int, required=True)
+    parser.add_argument("--out-dir", required=True)
+    args = parser.parse_args(argv)
+    if "jax" in sys.modules:
+        raise RuntimeError("the load generator must not import jax")
+    with open(args.traffic) as f:
+        traffic = json.load(f)
+    gen = LoadGen(args.address, traffic, args.partitions, args.seed,
+                  args.out_dir)
+    reply = sys.stdout
+    for line in sys.stdin:
+        msg = json.loads(line)
+        cmd = msg.pop("cmd")
+        try:
+            if cmd not in ("deploy", "first_touch", "warm", "window", "stop"):
+                raise ValueError(f"unknown command {cmd!r}")
+            answer = {**getattr(gen, cmd)(**msg), "ok": True}
+        except Exception as exc:  # noqa: BLE001 — the harness decides
+            import traceback
+
+            traceback.print_exc()
+            answer = {"ok": False, "error": f"{type(exc).__name__}: {exc}"}
+        if "jax" in sys.modules:
+            answer = {"ok": False, "error": "jax was imported in the load generator"}
+        reply.write(json.dumps(answer) + "\n")
+        reply.flush()
+        if cmd == "stop" or not answer["ok"]:
+            break
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,549 @@
+#!/usr/bin/env python3
+"""The benchmark's one command:
+
+    python benchmarks/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+
+It finds the cell in ``BENCHMARK.json`` and everything that belongs to it by
+name: the deployment in ``benchmarks/configs/``, the traffic mix in
+``benchmarks/traffic/``, each per-layer metric in ``benchmarks/layer_metrics/``
+and its reader in ``benchmarks/readers/``. This process is the only one that
+imports JAX, so it holds the cell's chips: it builds the deployment's cluster
+and gateway in itself, starts ``loadgen.py`` as a child (clients and job
+workers, over loopback gRPC), warms the cell's own shapes, measures exactly
+``--seconds`` of the cell's traffic, drains, checks what the window produced
+against the plain reference, and prints one JSON line.
+
+``--rehearse-cpu`` (with ``JAX_PLATFORMS=cpu``) walks the same path on the host
+for a rehearsal: it ends with exit code 3 and prints **no** result line.
+``--fault <name>`` breaks the timed path underneath the comparison — in the
+program's own Raft path (``lying_follower``) or where the harness takes its
+answers (the others) — for the controls and the fault tests
+(benchmarks/tests/); never used by a measurement.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib.util
+import json
+import math
+import os
+import queue
+import shutil
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+from pathlib import Path
+
+T_PROCESS_START = time.monotonic()
+HERE = Path(__file__).resolve().parent
+ROOT = HERE.parent
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(HERE))
+
+import definitions as defs  # noqa: E402
+import reference  # noqa: E402
+import roofline  # noqa: E402
+import schedule  # noqa: E402
+import trace_reduce  # noqa: E402
+
+REHEARSAL_EXIT = 3
+REFUSED_EXIT = 2
+FAULTS = ("lying_follower", "lose_acked", "at_least_once", "alter_record",
+          "replica_export_differs")
+
+
+class Refused(Exception):
+    """The run cannot be a result: no chip, an unknown name, a group off the
+    TPU. Ends the process non-zero with no result line."""
+
+
+def say(message: str) -> None:
+    print(f"[{time.monotonic() - T_PROCESS_START:6.1f}s] {message}",
+          file=sys.stderr, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# the cell, by name
+
+
+def load_json(path: Path, what: str) -> dict:
+    if not path.is_file():
+        raise Refused(f"unknown {what}: no file {path.relative_to(ROOT)}")
+    return json.loads(path.read_text())
+
+
+def resolve_cell(name: str, manifest: dict | None = None) -> dict:
+    manifest = manifest or load_json(ROOT / "BENCHMARK.json", "manifest")
+    cells = {w["name"]: w for w in manifest["workloads"]}
+    if name not in cells:
+        raise Refused(f"unknown cell {name!r}; known: {sorted(cells)}")
+    cell = cells[name]
+    configs = {c["name"]: c for c in manifest["configs"]}
+    if cell["config"] not in configs:
+        raise Refused(f"unknown configuration {cell['config']!r}")
+    config = load_json(ROOT / configs[cell["config"]]["file"], "configuration")
+    traffic_path = HERE / "traffic" / f"{cell['traffic']}.json"
+    traffic = load_json(traffic_path, "traffic mix")
+
+    def reported(metric: dict) -> bool:
+        return name in metric.get("workloads", [name])
+
+    end_to_end = [m for m in manifest["end_to_end"] if reported(m)]
+    per_layer = []
+    for m in manifest["per_layer"]:
+        if reported(m):
+            spec = load_json(HERE / "layer_metrics" / f"{m['name']}.json",
+                             "per-layer metric")
+            per_layer.append({**m, "reader": spec["reader"],
+                              "args": spec.get("args", {})})
+    return {"cell": cell, "config": config, "traffic": traffic,
+            "traffic_path": traffic_path, "end_to_end": end_to_end,
+            "per_layer": per_layer}
+
+
+def load_reader(name: str):
+    path = HERE / "readers" / f"{name}.py"
+    if not path.is_file():
+        known = sorted(p.stem for p in (HERE / "readers").glob("*.py"))
+        raise Refused(f"unknown metric reader {name!r}; known: {known}")
+    spec = importlib.util.spec_from_file_location(f"bench_reader_{name}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.read
+
+
+# ---------------------------------------------------------------------------
+# the child
+
+
+class Child:
+    def __init__(self, address: str, traffic_path: Path, partitions: int,
+                 seed: int, out_dir: Path) -> None:
+        env = dict(os.environ)
+        env.pop("JAX_PLATFORMS", None)   # it never imports jax; keep it so
+        self.proc = subprocess.Popen(
+            [sys.executable, str(HERE / "loadgen.py"), "--address", address,
+             "--traffic", str(traffic_path), "--partitions", str(partitions),
+             "--seed", str(seed), "--out-dir", str(out_dir)],
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True, env=env)
+        self.answers: queue.Queue = queue.Queue()
+        threading.Thread(target=self._read, daemon=True).start()
+
+    def _read(self) -> None:
+        for line in self.proc.stdout:
+            self.answers.put(line)
+        self.answers.put(None)
+
+    def send(self, cmd: str, **kw) -> None:
+        self.proc.stdin.write(json.dumps({"cmd": cmd, **kw}) + "\n")
+        self.proc.stdin.flush()
+
+    def answer(self, timeout: float) -> dict:
+        try:
+            line = self.answers.get(timeout=timeout)
+        except queue.Empty:
+            raise RuntimeError("the load generator did not answer in "
+                               f"{timeout:.0f}s") from None
+        if line is None:
+            raise RuntimeError("the load generator died")
+        answer = json.loads(line)
+        if not answer.get("ok"):
+            raise RuntimeError(f"load generator: {answer.get('error')}")
+        return answer
+
+    def ask(self, cmd: str, timeout: float, **kw) -> dict:
+        self.send(cmd, **kw)
+        return self.answer(timeout)
+
+    def close(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.kill()
+        self.proc.wait(timeout=30)
+
+
+# ---------------------------------------------------------------------------
+# metrics arithmetic (pure: tests call these)
+
+
+def window_metrics(window: list, completed_at: dict, all_completions: list,
+                   t0: float, seconds: float) -> dict:
+    """End-to-end numbers over **all** requests due in the window: a request
+    refused, unanswered or never completed counts as missing."""
+    attempted = len(window)
+    acks = [r["ack"] - r["due"] for r in window if r["ok"]]
+    completions = [completed_at[r["key"]] - r["due"] for r in window
+                   if r["ok"] and r["key"] in completed_at]
+    inside = sum(1 for t in all_completions if t0 <= t < t0 + seconds)
+    backlog = sum(1 for r in window if r["ok"]
+                  and completed_at.get(r["key"], math.inf) >= t0 + seconds)
+    late = [r["sent"] - r["due"] for r in window]
+    return {
+        "attempted": attempted,
+        "failed": attempted - len(completions),
+        "backlog_at_close": backlog,
+        "completed_per_s": inside / seconds,
+        "ack_p95_ms": 1e3 * schedule.percentile(acks, 0.95, attempted),
+        "completion_p50_ms": 1e3 * schedule.percentile(completions, 0.50, attempted),
+        "completion_p95_ms": 1e3 * schedule.percentile(completions, 0.95, attempted),
+        "generator_late_p95_ms": 1e3 * schedule.percentile(late, 0.95, attempted),
+    }
+
+
+def decide_correct(numbers: dict) -> bool:
+    """``numbers``: name -> ``{"value", "limit"}``; every value within its
+    limit (a limit is an upper bound; ``min`` marks a lower one)."""
+    return all(n["value"] >= n["limit"] if n.get("min") else
+               n["value"] <= n["limit"] for n in numbers.values())
+
+
+def compare(definitions: list, requests: list, observed_events: dict,
+            completed_at: dict, returned: dict, completed_jobs: list,
+            logs: dict, marks: dict) -> dict:
+    """The comparison that decides ``correct``, over every acknowledged
+    create of the run: its exported records against the plain reference, its
+    completion, and — for it and for every acknowledged job completion — its
+    presence in every replica's log on disk; and the replicas' logs against
+    each other, byte for byte, as far as all had committed. Exact: limits 0.
+
+    ``logs``: ``served.replica_logs``; ``marks``: (partition, broker) -> the
+    replica's commit index while the cluster ran."""
+    by_id = {d["id"]: d for d in definitions}
+    acked = [r for r in requests if r["ok"]]
+    never = [r["key"] for r in acked if r["key"] not in completed_at]
+    bad = reference.mismatches(
+        by_id, [(r["key"], r["pid"], r["variables"]) for r in acked
+                if r["key"] in completed_at], observed_events, returned)
+    # a key's upper bits name its partition (the protocol's layout)
+    keys = {r["key"] for r in acked}
+    jobs = set(completed_jobs)
+    missing = differing = 0
+    for (pid, _broker), log in logs.items():
+        missing += len({k for k in keys if k >> 51 == pid} - log["created"])
+        missing += len({k for k in jobs if k >> 51 == pid} - log["jobs_completed"])
+    for pid in {pid for pid, _broker in logs}:
+        replicas = [log for (p, _b), log in logs.items() if p == pid]
+        committed = min(mark for (p, _b), mark in marks.items() if p == pid)
+        for index in set().union(*(r["entries"] for r in replicas)):
+            if index <= committed:
+                differing += len({r["entries"][index] for r in replicas
+                                  if index in r["entries"]}) > 1
+    numbers = {
+        "acked_never_completed": {"value": len(never), "limit": 0},
+        "reference_mismatches": {"value": len(bad), "limit": 0},
+        "instances_compared": {"value": len(acked) - len(never), "limit": 1,
+                               "min": True},
+        "acks_missing_in_a_replica": {"value": missing, "limit": 0},
+        "replica_log_entries_differing": {"value": differing, "limit": 0},
+    }
+    return {"numbers": numbers, "examples": bad[:3], "never": never[:3]}
+
+
+# ---------------------------------------------------------------------------
+# the run
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--seed", type=int, required=True)
+    parser.add_argument("--seconds", type=float, required=True)
+    parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    parser.add_argument("--rehearse-cpu", action="store_true")
+    parser.add_argument("--fault", choices=FAULTS, default=None)
+    parser.add_argument("--keep-events", default=None,
+                        help="write one instance's records per (definition, "
+                             "x) to this JSON file (the tests' recorded data)")
+    parser.add_argument("--keep-trace", default=None,
+                        help="write the trace's plain form to this JSON file")
+    args = parser.parse_args(argv)
+    try:
+        return run(args)
+    except Refused as err:
+        say(f"refused: {err}")
+        return REFUSED_EXIT
+
+
+def run(args) -> int:
+    what = resolve_cell(args.workload)
+    cell, config, traffic = what["cell"], what["config"], what["traffic"]
+    readers = {m["name"]: load_reader(m["reader"]) for m in what["per_layer"]}
+    try:
+        import zeebe_tpu  # noqa: F401
+    except ImportError:
+        raise Refused("the program (zeebe_tpu) is not in this directory") from None
+
+    from zeebe_tpu import native
+    from zeebe_tpu.utils import backend
+    from zeebe_tpu.utils.xla_cache import enable_persistent_cache
+
+    import served as srv
+
+    cache_dir = enable_persistent_cache()
+    import jax
+
+    # programs that compile in under a second are cached too: set-up is
+    # paid by every run of every later check
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    ledger = srv.CompileLedger()
+    devices = backend.devices()
+    first = devices[0]
+    rehearsal = first.platform != "tpu"
+    if rehearsal and not (args.rehearse_cpu and backend.cpu_requested()):
+        raise Refused(f"no TPU: jax found {first.platform}")
+    if not rehearsal and args.rehearse_cpu:
+        raise Refused("--rehearse-cpu on a machine with an accelerator")
+    if len(devices) != cell["chips"]:
+        raise Refused(f"the cell asks for {cell['chips']} chip(s), jax sees "
+                      f"{len(devices)}")
+    peaks = None if rehearsal else roofline.peaks_of(first.device_kind)
+    if native.load_codec() is None:
+        raise Refused("the native codec did not build from codec.c")
+    say(f"device: platform={first.platform} kind={first.device_kind} "
+        f"count={len(devices)} cache={cache_dir}")
+
+    (ROOT / ".bench_data").mkdir(exist_ok=True)
+    data_dir = Path(tempfile.mkdtemp(prefix="run-", dir=ROOT / ".bench_data"))
+    layout = config["layout"]
+    seconds = float(args.seconds)
+    setup = traffic.get("setup", {})
+    definitions = defs.build_definitions(traffic["definitions"])
+    payload = defs.make_payload(traffic.get("payload"), args.seed)
+
+    from zeebe_tpu.engine.device_health import shared_device_health
+
+    if "accelerator_router_rule" in config.get("assumed", {}):
+        srv.hold_groups_on_the_accelerator()
+    health = shared_device_health()
+    deployed_shadow_rate = health.cfg.shadow_sample_rate
+    observed = srv.Observed()
+    if args.fault == "lying_follower":
+        srv.plant_lying_follower(observed)
+    system = srv.Served(layout, data_dir / "data", observed)
+    child = None
+    trace_result = None
+    try:
+        child = Child(system.address, what["traffic_path"], system.partitions,
+                      args.seed, data_dir)
+        deployed = child.ask("deploy", 90.0)
+        say(f"deployed: {deployed}")
+        # every group of the first touches is also run by the host oracle, so
+        # that the oracle's own programs are compiled before the window; then
+        # back to the deployment's sample rate
+        health.cfg.shadow_sample_rate = 1.0
+        say("cluster up, definitions deployed, workers started")
+        touched = child.ask("first_touch", 300.0)["keys"]
+        wait_completed(observed, touched, 120.0, "first touches")
+        say(f"first touches done: {ledger.report()}")
+        child.ask("warm", 30.0)
+        warm_start = time.monotonic()
+        time.sleep(float(setup.get("shadow_warm_s", 2.0)))
+        health.cfg.shadow_sample_rate = deployed_shadow_rate
+        quiet_s = float(setup.get("quiet_s", 2.0))
+        while True:
+            now = time.monotonic()
+            if (now - warm_start >= float(setup.get("warm_min_s", 5.0))
+                    and now - ledger.last_at >= quiet_s):
+                break
+            if now - warm_start > float(setup.get("warm_max_s", 60.0)):
+                say("warm-up: compiles never settled; measuring anyway")
+                break
+            time.sleep(0.1)
+        t0 = time.monotonic() + 0.3
+        child.send("window", t0=t0, seconds=seconds)
+        sleep_until(t0)
+        observed.fault = args.fault
+        counters0 = system.counters()
+        elections0 = system.elections()
+        compiles0 = ledger.compiles
+        setup_s = t0 - T_PROCESS_START
+        say(f"window opens: setup_s={setup_s:.2f} {ledger.report()}")
+        if args.trace:
+            trace_result = traced_stretch(jax, data_dir, t0, seconds,
+                                          args.keep_trace)
+        sleep_until(t0 + seconds)
+        counters1 = system.counters()
+        elections_in_window = system.elections() - elections0
+        compiles_in_window = ledger.compiles - compiles0
+        reply = child.answer(seconds + 90.0)
+        requests = [json.loads(line) for line in
+                    Path(reply["records_file"]).read_text().splitlines()]
+        for r in requests:
+            r["variables"] = {"x": r["x"], **payload}
+        acked = [r["key"] for r in requests if r["ok"]]
+        drain_start = time.monotonic()
+        wait_completed(observed, acked, float(setup.get("drain_max_s", 60.0)),
+                       "drain", fatal=False)
+        drain_s = time.monotonic() - drain_start
+        peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devices)
+        stopped = child.ask("stop", 60.0)
+        completed_jobs = json.loads(Path(stopped.pop("jobs_file")).read_text())
+        counters_end = system.counters()
+        marks = system.raft_marks()
+        shadow = {"checks": health.shadow_checks,
+                  "mismatches": health.shadow_mismatches,
+                  "state": str(health.state)}
+        # the program's state is freed; what its replicas hold is read from
+        # their files alone
+        system.stop()
+        t_check = time.monotonic()
+        logs = srv.replica_logs(data_dir / "data", layout)
+    finally:
+        if child is not None:
+            child.close()
+        system.stop()
+        shutil.rmtree(data_dir, ignore_errors=True)
+
+    # ---- the window's numbers
+    with observed.lock:
+        events = dict(observed.events)
+        completed_at = dict(observed.completed_at)
+    window = [r for r in requests if r["phase"] == "window"]
+    numbers = window_metrics(window, completed_at, list(completed_at.values()),
+                             t0, seconds)
+    delta = {k: counters1["counts"].get(k, 0) - counters0["counts"].get(k, 0)
+             for k in counters1["counts"]}
+    window_keys = {r["key"] for r in window if r["ok"]}
+    steps = roofline.token_steps(
+        [e for key, evs in events.items() if key in window_keys for e in evs])
+    context = {
+        "seconds": seconds, "counts": delta, "child": reply,
+        "numbers": numbers, "compiles_in_window": compiles_in_window,
+        "elections_in_window": elections_in_window,
+        "trace": trace_result, "device_kind": first.device_kind,
+        "peaks": peaks, "max_fanout": defs.max_fanout(definitions),
+        "token_steps_per_s": steps / seconds,
+    }
+    say(f"window: {json.dumps({k: round(v, 3) for k, v in numbers.items()})}")
+    say(f"counts in window: {json.dumps(delta)}")
+    say(f"child: window={ {k: v for k, v in reply.items() if k != 'records_file'} } "
+        f"stop={stopped} drain_s={drain_s:.2f}")
+    say(f"groups by device (whole run): {counters_end['groups_by_device']} "
+        f"mesh shards: {counters_end['shard_devices']} "
+        f"failures: {counters_end['failures']} shadow: {shadow} "
+        f"compiles: {ledger.report()} peak_bytes={peak} "
+        f"elections in window: {elections_in_window} "
+        f"records exported: {observed.records} repeats compared: "
+        f"{observed.repeats} appends lied about: {observed.lies}")
+
+    # ---- where the groups ran
+    wanted = "cpu" if rehearsal else "tpu"
+    ran_on = set(counters_end["groups_by_device"]) | set(counters_end["shard_devices"])
+    off = sorted(d for d in ran_on if not d.startswith(wanted))
+    if off or delta.get("groups", 0) <= 0:
+        raise Refused(f"kernel groups off the {wanted} ({off}) or none in the "
+                      f"window ({delta.get('groups', 0)})")
+
+    if args.keep_events:
+        kept = {}
+        for r in requests:
+            if r["ok"] and r["key"] in completed_at:
+                kept.setdefault(f"{r['pid']}:{r['x']}", {
+                    "pid": r["pid"], "variables": r["variables"],
+                    "events": events[r["key"]]})
+        Path(args.keep_events).write_text(json.dumps(
+            {"definitions": traffic["definitions"], "instances": kept}))
+
+    # ---- correct
+    returned = payload if traffic["workers"]["complete_with_payload"] else {}
+    verdict = compare(definitions, requests, events, completed_at, returned,
+                      completed_jobs, logs, marks)
+    checks = verdict["numbers"]
+    checks["exports_differing_at_a_position"] = {"value": observed.differing,
+                                                 "limit": 0}
+    checks["device_failures"] = {
+        "value": sum(counters_end["failures"].values()), "limit": 0}
+    checks["shadow_mismatches"] = {"value": shadow["mismatches"], "limit": 0}
+    correct = decide_correct(checks)
+    say(f"check took {time.monotonic() - t_check:.2f}s; examples: "
+        f"{verdict['examples']} never completed: {verdict['never']}")
+
+    metrics = {}
+    if args.trace:
+        for m in what["per_layer"]:
+            value = readers[m["name"]](context, m["args"])
+            if value is not None and math.isfinite(value):
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    else:
+        for m in what["end_to_end"]:
+            value = setup_s if m["name"] == "setup_s" else numbers[m["name"]]
+            metrics[m["name"]] = {
+                "value": value if math.isfinite(value) else 1e12,
+                "unit": m["unit"]}
+    device = {"platform": first.platform, "kind": first.device_kind,
+              "count": len(devices), "memory_peak_bytes": peak}
+    result = {"correct": correct, "attempted": numbers["attempted"],
+              "failed": numbers["failed"], "metrics": metrics, "device": device}
+    if trace_result is not None:
+        device["busy_s"] = trace_result["busy_s"]
+        device["window_s"] = trace_result["window_s"]
+        result["breakdown"] = {"device_ops": trace_result["device_ops"],
+                               "idle_gaps": trace_result["idle_gaps"]}
+    result["checks"] = checks
+    for name, n in checks.items():
+        say(f"check {name}: value={n['value']} "
+            f"{'min' if n.get('min') else 'limit'}={n['limit']}")
+    say(f"correct={str(correct).lower()} attempted={numbers['attempted']} "
+        f"failed={numbers['failed']}")
+    if rehearsal:
+        say(f"rehearsal on {first.platform}: not a chip run, no result. "
+            f"metrics would be: {json.dumps(metrics)}")
+        return REHEARSAL_EXIT
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+def sleep_until(t: float) -> None:
+    while (left := t - time.monotonic()) > 0:
+        time.sleep(min(left, 0.05))
+
+
+def wait_completed(observed, keys: list, timeout: float, what: str,
+                   fatal: bool = True) -> None:
+    deadline = time.monotonic() + timeout
+    want = set(keys)
+    while True:
+        with observed.lock:
+            missing = len(want - observed.completed_at.keys())
+        if not missing:
+            return
+        if time.monotonic() > deadline:
+            if fatal:
+                raise RuntimeError(f"{what}: {missing} of {len(want)} instances "
+                                   f"did not complete in {timeout:.0f}s")
+            say(f"{what}: {missing} of {len(want)} instances did not complete "
+                f"in {timeout:.0f}s")
+            return
+        time.sleep(0.02)
+
+
+def traced_stretch(jax, data_dir: Path, t0: float, seconds: float,
+                   keep: str | None) -> dict:
+    """A few seconds of the window under the profiler, then the reduction."""
+    length = min(3.0, seconds / 4)
+    sleep_until(t0 + min(3.0, seconds / 4))
+    trace_dir = data_dir / "trace"
+    # the profiler's Python tracer slows every Python thread several times
+    # over; the device's own clock and XLA's host events are all that is read
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    started = time.monotonic()
+    sleep_until(started + length)
+    stopping = time.monotonic()
+    jax.profiler.stop_trace()
+    say(f"trace: {stopping - started:.2f}s traced, stop took "
+        f"{time.monotonic() - stopping:.2f}s")
+    trace = trace_reduce.load(trace_reduce.find_xplane(trace_dir))
+    if keep:
+        Path(keep).parent.mkdir(parents=True, exist_ok=True)
+        Path(keep).write_text(json.dumps(trace))
+    return trace_reduce.reduce(trace, stopping - started)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,345 @@
+"""The system under test, built as ``python -m zeebe_tpu.standalone`` builds it
+(``load_broker_cfg`` -> ``ClusterRuntime`` -> ``Gateway``), in the harness's
+process — the only one that touches JAX — with the benchmark's observers on
+it: an exporter that keeps what the reference needs of every record and stamps
+completions, jax's compile events, and the program's own counters. Copied from
+``chip_smoke.py`` (``Served``, ``Tally``, ``CompileLedger``,
+``hold_groups_on_the_accelerator``, ``kernel_report``); see PERF.md."""
+
+from __future__ import annotations
+
+import threading
+import time
+from collections import Counter
+from pathlib import Path
+
+#: reasons under which a kernel group failed or was contained
+DEVICE_FAILURES = ("device-dispatch-error", "device-wedged", "device-quarantined",
+                   "geometry-bounds", "no-quiesce", "token-overflow",
+                   "group-error", "mesh-dispatch-error", "mesh-no-quiesce",
+                   "mesh-token-overflow")
+
+
+class Observed:
+    """What the exporter saw: per process instance its records in log order,
+    as the reference's plain tuples, and when each instance completed."""
+
+    def __init__(self) -> None:
+        self.lock = threading.Lock()
+        self.events: dict = {}       # instance key -> [event tuple]
+        self.completed_at: dict = {}  # instance key -> time.monotonic()
+        self.records = 0
+        #: export is at-least-once and every replica exports: a position is
+        #: seen several times. The first sighting is kept, under
+        #: (partition, position), as what the record was; every later one is
+        #: compared with it and counted in ``differing`` where it differs
+        self.seen: dict = {}
+        self.repeats = 0
+        self.differing = 0
+        #: set by the harness when the window opens (control and fault tests)
+        self.fault: str | None = None
+        #: ``lying_follower``: appends acknowledged and not stored
+        self.lies = 0
+
+
+def _broken(event: tuple, fault: str):
+    """The control and the fault tests: what the timed path produced, broken
+    where the harness takes it (one instance in ten). ``lose_acked``: an
+    acknowledged instance's records never arrive (durability broken);
+    ``at_least_once``: a job's completion is applied twice (exactly-once
+    broken); ``alter_record``: a token is sent down another flow;
+    ``replica_export_differs``: as ``alter_record``, but in a later replica's
+    export of a position, not in the first."""
+    if fault == "lose_acked":
+        return []
+    if fault == "at_least_once" and event[:2] == ("JOB", "COMPLETED"):
+        return [event, event]
+    if (fault in ("alter_record", "replica_export_differs")
+            and event[:2] == ("PI", "SEQUENCE_FLOW_TAKEN")):
+        other = "flow_2" if event[2] == "flow_1" else "flow_1"
+        return [event[:2] + (other,) + event[3:]]
+    return [event]
+
+
+def capture_exporter(observed: Observed):
+    """The standard exporter SPI, as ``ZEEBE_BROKER_EXPORTERS_*`` would load
+    it. Completion is observed here, where a deployment observes it."""
+    from zeebe_tpu.exporters.api import Exporter
+    from zeebe_tpu.protocol import ValueType
+    from zeebe_tpu.protocol.intent import ProcessInstanceIntent as PI
+
+    pi_type, job_type, var_type = (ValueType.PROCESS_INSTANCE, ValueType.JOB,
+                                   ValueType.VARIABLE)
+
+    class CaptureExporter(Exporter):
+        def export(self, logged) -> None:
+            record = logged.record
+            value_type = record.value_type
+            event = key = None
+            if record.is_event:
+                value = record.value
+                if value_type == pi_type:
+                    event = ("PI", record.intent.name, value["elementId"],
+                             record.key, value["flowScopeKey"])
+                elif value_type == job_type:
+                    event = ("JOB", record.intent.name, value["elementId"],
+                             value["type"], record.key,
+                             value["elementInstanceKey"])
+                elif value_type == var_type:
+                    event = ("VAR", record.intent.name, value["name"],
+                             value["value"])
+                if event is not None:
+                    key = value["processInstanceKey"]
+            kept = [] if event is None else [event]
+            fault = observed.fault
+            where = (record.partition_id, logged.position)
+            if event is not None and fault is not None and (key >> 3) % 10 == 0:
+                if fault != "replica_export_differs" or where in observed.seen:
+                    kept = _broken(event, fault)
+            said = (int(record.record_type), int(value_type),
+                    int(record.intent), record.key, tuple(kept))
+            with observed.lock:
+                first = observed.seen.setdefault(where, said)
+                if first is not said:
+                    observed.repeats += 1
+                    observed.differing += first != said
+                else:
+                    observed.records += 1
+                    if kept:
+                        observed.events.setdefault(key, []).extend(kept)
+                        if (value_type == pi_type and record.key == key
+                                and record.intent == PI.ELEMENT_COMPLETED):
+                            observed.completed_at[key] = time.monotonic()
+            self.controller.update_last_exported_position(logged.position)
+
+    return CaptureExporter()
+
+
+class CompileLedger:
+    """Compile requests and seconds, from jax's own monitoring events."""
+
+    def __init__(self) -> None:
+        import jax.monitoring as monitoring
+
+        self.compiles = 0
+        self.seconds = 0.0
+        self.last_at = time.monotonic()
+        self.events: Counter = Counter()
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event: str, seconds: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.seconds += seconds
+            self.last_at = time.monotonic()
+
+    def _on_event(self, event: str, **_kw) -> None:
+        if event.startswith("/jax/compilation_cache/"):
+            self.events[event.rsplit("/", 1)[1]] += 1
+
+    def report(self) -> dict:
+        return {"compiles": self.compiles,
+                "compile_seconds": round(self.seconds, 3),
+                "persistent_cache_hits": self.events["cache_hits"],
+                "persistent_cache_misses": self.events["cache_misses"]}
+
+
+def hold_groups_on_the_accelerator():
+    """The configuration's assumed router rule: 'the accelerator'. The
+    default rule weighs the measured link against the host XLA backend and
+    sends groups host-ward on the v5e (PERF.md, PR 22, item 3), so a default
+    deployment may not drive the chip at all; ROADMAP D2 decides the rule."""
+    from zeebe_tpu.utils.device_link import BackendRouter, install_shared_router
+
+    class AcceleratorOnly(BackendRouter):
+        def choose(self, bucket):
+            return self.accel_device()
+
+    router = AcceleratorOnly()
+    install_shared_router(router)
+    return router
+
+
+def device_name(device) -> str:
+    return f"{device.platform}:{device.id}"
+
+
+class Served:
+    def __init__(self, layout: dict, data_dir: Path, observed: Observed) -> None:
+        from zeebe_tpu.broker.config import load_broker_cfg
+        from zeebe_tpu.gateway import ClusterRuntime, Gateway
+
+        self.partitions = int(layout["partitions"])
+        self.data_dir = data_dir
+        cfg = load_broker_cfg(overrides={
+            "base.partition_count": self.partitions,
+            "base.replication_factor": int(layout["replication_factor"]),
+        })
+        if not cfg.base.kernel_backend:
+            raise RuntimeError("kernel backend is off in the config")
+        self.gateway = None
+        self.runtime = ClusterRuntime(
+            exporters_factory=lambda: {"bench": capture_exporter(observed)},
+            kernel_backend=cfg.base.kernel_backend,
+            broker_count=int(layout["brokers"]),
+            partition_count=self.partitions,
+            replication_factor=int(layout["replication_factor"]),
+            directory=data_dir,
+            backpressure_algorithm=cfg.backpressure.algorithm,
+            backpressure_enabled=cfg.backpressure.enabled,
+            disk_min_free_bytes=(cfg.disk.min_free_bytes
+                                 if cfg.disk.enable_monitoring else 0),
+        )
+        self.runtime.start()
+        self.gateway = Gateway(self.runtime, bind="127.0.0.1:0")
+        self.gateway.start()
+        self.address = self.gateway.address
+
+    def stop(self) -> None:
+        """Idempotent. The data directory stays: the harness reads the
+        replicas' logs from it and removes it."""
+        if self.gateway is not None:
+            self.gateway.stop()
+            self.gateway = None
+        if self.runtime is not None:
+            self.runtime.stop()
+            self.runtime = None
+
+    def replicas(self, partition_id: int) -> list:
+        return [b.partitions[partition_id]
+                for b in self.runtime.brokers.values()
+                if partition_id in b.partitions]
+
+    def backends(self) -> list:
+        """Every replica's kernel backend that exists (followers only replay,
+        so theirs count nothing)."""
+        out = []
+        for pid in range(1, self.partitions + 1):
+            for replica in self.replicas(pid):
+                processor = getattr(replica, "processor", None)
+                backend = getattr(processor, "kernel_backend", None)
+                if backend is not None:
+                    out.append(backend)
+        return out
+
+    def mesh_runner(self):
+        for pid in range(1, self.partitions + 1):
+            for replica in self.replicas(pid):
+                runner = getattr(replica, "mesh_runner", None)
+                if runner is not None:
+                    return runner
+        return None
+
+    def counters(self) -> dict:
+        """The program's own counts and host-clock stage sums, cumulative:
+        the harness reads them at the window's two ends and subtracts."""
+        from zeebe_tpu.utils.metrics import REGISTRY
+
+        out: Counter = Counter()
+        by_device: Counter = Counter()
+        reasons: Counter = Counter()
+        for b in self.backends():
+            out["groups"] += b.groups_processed
+            out["commands"] += b.commands_processed
+            by_device.update({device_name(d): n
+                              for d, n in b.groups_by_device.items()})
+            reasons.update(b.fallback_reasons)
+        for name, kind, _labels, value in REGISTRY.snapshot():
+            if kind == "histogram" and "stream_processor_pipeline_" in name:
+                stage = name.rsplit("_pipeline_", 1)[1]
+                out[f"{stage}_count"] += value[0]
+                out[f"{stage}_seconds"] += value[1]
+        runner = self.mesh_runner()
+        if runner is not None:
+            out["mesh_dispatches"] = runner.dispatches
+            out["mesh_coalesced"] = runner.coalesced_dispatches
+            out["mesh_groups"] = runner.groups_dispatched
+        return {"counts": dict(out), "groups_by_device": dict(by_device),
+                "failures": {r: n for r, n in reasons.items()
+                             if r.split(":")[0] in DEVICE_FAILURES},
+                "shard_devices": sorted(device_name(d)
+                                        for d in runner.shard_devices)
+                if runner is not None else []}
+
+    def raft_marks(self) -> dict:
+        """(partition, broker) -> the replica's commit index, read while the
+        cluster still runs: up to the lowest of a partition's, every replica's
+        log has to hold the same bytes."""
+        return {(pid, name): replica.raft.commit_index
+                for name, broker in self.runtime.brokers.items()
+                for pid, replica in broker.partitions.items()}
+
+    def elections(self) -> int:
+        """Raft elections started so far, over all replicas (the program's
+        ``raft_elections_total``)."""
+        from zeebe_tpu.utils.metrics import REGISTRY
+
+        return int(sum(value for name, kind, _labels, value in REGISTRY.snapshot()
+                       if kind == "counter" and name.endswith("raft_elections_total")))
+
+
+def replica_logs(data_dir: Path, layout: dict) -> dict:
+    """(partition, broker) -> what that replica's Raft log holds **on disk**,
+    read once the cluster is stopped and with none of its memory: ``entries``
+    (raft index -> the entry's bytes), ``created`` (the instance keys whose
+    creation it holds) and ``jobs_completed`` (the job keys whose completion
+    it holds). The durability side of an acknowledgement."""
+    from zeebe_tpu.journal.journal import read_only_records
+    from zeebe_tpu.logstreams.log_stream import _deserialize_batch
+    from zeebe_tpu.protocol import ValueType
+    from zeebe_tpu.protocol.intent import (JobIntent,
+                                           ProcessInstanceCreationIntent)
+    from zeebe_tpu.protocol.msgpack import unpackb
+
+    out = {}
+    for b in range(int(layout["brokers"])):
+        name = f"broker-{b}"
+        for pid in range(1, int(layout["partitions"]) + 1):
+            log_dir = data_dir / name / f"partition-{pid}" / "raft" / "raft-log"
+            if not log_dir.is_dir():
+                continue
+            entries, created, jobs = {}, set(), set()
+            for journal_record in read_only_records(log_dir):
+                entry = unpackb(journal_record.data)
+                data = entry.get("data")
+                if not data:
+                    continue
+                entries[journal_record.index] = bytes(data)
+                for logged in _deserialize_batch(data, pid):
+                    record = logged.record
+                    if not record.is_event:
+                        continue
+                    if (record.value_type == ValueType.PROCESS_INSTANCE_CREATION
+                            and record.intent == ProcessInstanceCreationIntent.CREATED):
+                        created.add(record.value["processInstanceKey"])
+                    elif (record.value_type == ValueType.JOB
+                          and record.intent == JobIntent.COMPLETED):
+                        jobs.add(record.key)
+            out[(pid, name)] = {"entries": entries, "created": created,
+                                "jobs_completed": jobs}
+    return out
+
+
+def plant_lying_follower(observed: Observed, victim: str = "broker-2"):
+    """The control in the program's own path: from the window's start one
+    broker's Raft followers acknowledge every append **without storing it**,
+    so acknowledgements rest on a quorum that does not hold them and that
+    replica's log ends where the window began."""
+    from zeebe_tpu.cluster.raft import RaftNode as Raft
+
+    honest = Raft._on_append_request
+
+    def lying(self, sender, req):
+        if (observed.fault != "lying_follower" or self.member_id != victim
+                or not req["entries"]):
+            return honest(self, sender, req)
+        claimed = req["entries"][-1]["index"]
+        observed.lies += 1
+        honest(self, sender, {**req, "entries": [],
+                              "commit": min(req["commit"], self.commit_index)})
+        self._send(sender, "append-resp", {
+            "term": self.current_term, "success": True,
+            "lastIndex": claimed, "follower": self.member_id})
+
+    Raft._on_append_request = lying
