@@ -482,3 +482,107 @@ class TestFailedFlushEmitsNothing:
                              if s.name == "processor.ack"]
                 assert len(ack_spans) > 0
             journal.close()
+
+
+# -- the window reduction on recorded spans (ISSUE 26) ------------------------
+
+
+class TestWindowAccount:
+    """``account.window_account`` over three instances' traces recorded on a
+    TPU v5e (the benchmark's cell, tracer on): the arithmetic, not a speed."""
+
+    @pytest.fixture(scope="class")
+    def recorded(self):
+        from pathlib import Path
+
+        path = Path(__file__).parent / "data" / "account_spans.jsonl"
+        head = json.loads(path.read_text().splitlines()[0])["header"]
+        return load_spans([path]), head
+
+    def test_per_rpc_edges_conserve_and_add_up(self, recorded):
+        from zeebe_tpu.observability.account import window_account
+
+        spans, head = recorded
+        report = window_account(spans, head, *head["windowUs"])
+        assert report["evicted"] == 0
+        assert report["conservation_violations"] == []
+        assert set(report["rpcs"]) == {"CreateProcessInstance", "ActivateJobs",
+                                       "CompleteJob"}
+        create = report["rpcs"]["CreateProcessInstance"]
+        assert create["count"] == 3
+        assert create["median_ms"] == pytest.approx(27.539)  # ack_p50_ms
+        for rpc in report["rpcs"].values():
+            assert set(rpc["edges_mean_ms"]) == {*EDGES, "unattributed"}
+            # means of the edges add up to the mean total
+            assert sum(rpc["edges_mean_ms"].values()) == pytest.approx(
+                rpc["mean_ms"], rel=1e-3)
+        assert create["edges_mean_ms"]["device"] > 0
+
+    def test_account_of_an_instance_adds_up_to_the_whole(self, recorded):
+        from zeebe_tpu.observability.account import (
+            STRETCHES,
+            format_report,
+            window_account,
+        )
+
+        spans, head = recorded
+        report = window_account(spans, head, *head["windowUs"])
+        account = report["account"]
+        assert account["creates_in_window"] == account["instances"] == 3
+        assert account["incomplete"] == 0
+        assert [s["name"] for s in account["stretches"]] == [
+            name for name, _ in STRETCHES]
+        assert sum(s["mean_ms"] for s in account["stretches"]) == pytest.approx(
+            account["whole"]["mean_ms"])
+        hold = account["stretches"][4]
+        assert hold["covered_by"] == "outside"
+        assert 50.0 <= hold["median_ms"] <= 60.0   # the workers wait 50 ms
+        assert account["stretches"][0]["mean_ms"] == pytest.approx(
+            report["rpcs"]["CreateProcessInstance"]["mean_ms"])
+        assert 0 < account["uncovered_share"] < 0.15
+        text = format_report(report, printed_median_ms=160.0)
+        assert "ack_p50_ms" in text and "uncovered:" in text
+        assert "outside the program's spans" in text
+
+    def test_requests_outside_the_window_are_left_out(self, recorded):
+        from zeebe_tpu.observability.account import window_account
+
+        spans, head = recorded
+        start, end = head["windowUs"]
+        report = window_account(spans, head, end, end + 1_000_000)
+        assert "CreateProcessInstance" not in report["rpcs"]
+        assert report["account"]["creates_in_window"] == 0
+
+    def test_a_ring_that_evicted_spans_of_the_window_refuses(self, recorded):
+        from zeebe_tpu.observability.account import (
+            SpansEvicted,
+            window_account,
+        )
+
+        spans, head = recorded
+        start, end = head["windowUs"]
+        overflowed = {**head, "evicted": 7}
+        with pytest.raises(SpansEvicted):
+            window_account(spans, overflowed, start, end)
+        # what was dropped ended before the oldest span left: a window that
+        # opens after that is whole, and is reported
+        oldest_end = spans[0]["startUs"] + spans[0]["durUs"]
+        late = [s for s in spans if s["startUs"] > oldest_end]
+        later_start = min(s["startUs"] for s in late
+                          if s["name"] == "gateway.request")
+        report = window_account(spans, overflowed, later_start, end)
+        assert report["evicted"] == 7
+
+    def test_the_collector_reports_what_it_evicted(self):
+        from zeebe_tpu.observability.account import (
+            SpansEvicted,
+            window_account,
+        )
+
+        collector = SpanCollector(capacity=8)
+        for i in range(20):
+            collector.add(Span("1:1", "gateway.request", 1000 + i, 1))
+        assert collector.evicted == 12
+        spans = [s.to_dict() for s in collector.snapshot()]
+        with pytest.raises(SpansEvicted):
+            window_account(spans, collector.header(), 1000, 2000)

@@ -123,7 +123,49 @@ class TestSamplerAndCollector:
         jsonl = tmp_path / "spans.jsonl"
         assert c.to_jsonl(jsonl) == 2
         lines = [json.loads(line) for line in jsonl.read_text().splitlines()]
-        assert lines[0]["name"] == "processor.command"
+        assert lines[0]["header"]["evicted"] == 0  # the dump's head line
+        assert lines[1]["name"] == "processor.command"
+
+
+    def test_anchor_lays_a_span_on_the_monotonic_clock_and_back(self, tracing):
+        import time
+
+        from zeebe_tpu.observability.span import from_clock_ns, to_clock_ns
+
+        anchor = tracing.anchor
+        assert set(anchor) == {"wallNs", "monotonicNs", "perfCounterNs"}
+        mono0 = time.monotonic_ns()
+        tracing.emit("1:1", "marker", 0.0, 1)
+        mono1 = time.monotonic_ns()
+        start_us = tracing.collector.snapshot()[-1].start_us
+        on_mono = to_clock_ns(anchor, start_us)
+        # within a millisecond of the instant it was emitted at
+        assert mono0 - 1_000_000 <= on_mono <= mono1 + 1_000_000
+        assert abs(from_clock_ns(anchor, on_mono) - start_us) <= 1
+        on_perf = to_clock_ns(anchor, start_us, "perfCounterNs")
+        assert abs(on_perf - time.perf_counter_ns()) < 1_000_000_000
+
+    def test_every_dump_is_headed_by_the_anchor_and_evicted(self, tmp_path,
+                                                            tracing):
+        tracing.collector.resize(4)
+        for i in range(10):
+            tracing.emit("1:1", f"s{i}", 0.0, 1)
+        collector = tracing.collector
+        assert collector.evicted == 6
+        header = collector.header()
+        assert header == {"capacity": 4, "emitted": 10, "evicted": 6,
+                          "anchor": tracing.anchor}
+        path = tmp_path / "spans.jsonl"
+        assert collector.to_jsonl(path) == 4
+        lines = [json.loads(line) for line in path.read_text().splitlines()]
+        assert lines[0] == {"header": header}
+        assert [line["name"] for line in lines[1:]] == ["s6", "s7", "s8", "s9"]
+        other = collector.chrome_trace()["otherData"]
+        assert other["evicted"] == 6 and other["anchor"] == tracing.anchor
+        # the merge of dumps reads past the header line
+        from zeebe_tpu.observability import load_spans
+
+        assert len(load_spans([path])) == 4
 
 
 # ---------------------------------------------------------------------------
@@ -496,6 +538,8 @@ class TestTracesEndpoint:
             with urllib.request.urlopen(f"{base}/traces", timeout=5) as resp:
                 doc = json.loads(resp.read())
             assert doc["enabled"] is True
+            assert doc["emitted"] == 2 and doc["evicted"] == 0
+            assert doc["anchor"] == tracing.anchor
             assert len(doc["spans"]) == 2
             assert doc["spans"][0]["traceId"] == "1:5"
             with urllib.request.urlopen(
@@ -503,6 +547,7 @@ class TestTracesEndpoint:
                 chrome = json.loads(resp.read())
             assert len(chrome["traceEvents"]) == 1
             assert chrome["traceEvents"][0]["ph"] == "X"
+            assert chrome["otherData"]["evicted"] == 0
         finally:
             server.stop()
 

@@ -335,3 +335,123 @@ class TestCrossWaveSpeculation:
             h.close()
         consumed1, _ = _spec_counts()
         assert consumed1 == consumed0
+
+
+# -- 4. the group's time, covered and split (ISSUE 26) ------------------------
+
+SUB_STAGES = ("build", "device_dispatch", "device_fetch", "device_unpack")
+
+
+def _stage(stage: str) -> tuple[int, float]:
+    fam = REGISTRY._metrics.get(f"zeebe_stream_processor_pipeline_{stage}")
+    child = fam._children.get(("1",)) if fam is not None else None
+    return (child.count, child.sum) if child is not None else (0, 0.0)
+
+
+class TestGroupStageSplit:
+    def test_sub_stages_observed_once_a_group_and_sum_to_device(self):
+        before = {s: _stage(s) for s in (*SUB_STAGES, "device")}
+        h = EngineHarness(use_kernel_backend=True)
+        try:
+            drive_waves(h, n_instances=40)
+            groups = h.kernel_backend.groups_processed
+        finally:
+            h.close()
+        assert groups > 0
+        delta = {s: (_stage(s)[0] - before[s][0], _stage(s)[1] - before[s][1])
+                 for s in before}
+        # single-device groups: every histogram of the split moves once a
+        # group, exactly as `device` does
+        for stage in SUB_STAGES:
+            assert delta[stage][0] == delta["device"][0] == groups
+        assert delta["build"][1] > 0
+        parts = sum(delta[s][1] for s in SUB_STAGES[1:])
+        # the parts share device_elapsed's clock reads: equal up to rounding
+        assert parts == pytest.approx(delta["device"][1], rel=1e-6, abs=1e-7)
+
+    def test_pending_group_parts_add_up(self):
+        h = EngineHarness(use_kernel_backend=True)
+        try:
+            h.deploy(one_task())
+            h.stream.writer.try_write(
+                [LogAppendEntry(create_cmd()) for _ in range(4)])
+            with h.db.transaction():
+                pg = h.kernel_backend.begin_group(
+                    h.processor._iter_candidate_commands())
+                cmds, _ = h.kernel_backend.finish_group(
+                    pg, ProcessingResultBuilder)
+            assert cmds and not pg.mesh
+            assert pg.t_build > 0 and pg.t_dispatch > 0 and pg.t_fetch > 0
+            assert 0 < pg.t_device_get <= pg.t_fetch
+            assert pg.t_unpack >= 0
+            assert pg.t_dispatch + pg.t_fetch + pg.t_unpack == pytest.approx(
+                pg.device_elapsed, rel=1e-9, abs=1e-9)
+        finally:
+            h.close()
+
+
+class TestPhaseAnnotations:
+    def test_helper_annotates_with_the_tracer_off(self):
+        import jax
+
+        from zeebe_tpu.observability import get_tracer
+        from zeebe_tpu.observability.profiler import (
+            PHASE_PREFIX,
+            PHASES,
+            phase_annotation,
+        )
+
+        assert not get_tracer().enabled
+        assert PHASE_PREFIX == "zeebe.kernel_chunk."
+        assert len(PHASES) <= 9 and len(set(PHASES)) == len(PHASES)
+        for phase in PHASES:
+            with phase_annotation(phase) as annotation:
+                assert isinstance(annotation, jax.profiler.TraceAnnotation)
+        with pytest.raises(KeyError):  # the set of names is closed
+            phase_annotation("first")
+
+    def _record_phases(self, monkeypatch) -> list:
+        import contextlib
+
+        from zeebe_tpu.engine import kernel_backend
+        from zeebe_tpu.observability.profiler import PHASES
+        from zeebe_tpu.stream import processor
+
+        seen: list = []
+
+        def recording(phase):
+            assert phase in PHASES
+            seen.append(phase)
+            return contextlib.nullcontext()
+
+        monkeypatch.setattr(kernel_backend, "phase_annotation", recording)
+        monkeypatch.setattr(processor, "phase_annotation", recording)
+        return seen
+
+    def test_an_empty_admission_probe_emits_none(self, monkeypatch):
+        h = EngineHarness(use_kernel_backend=True)
+        try:
+            h.deploy(one_task())
+            h.pump()
+            seen = self._record_phases(monkeypatch)
+            for _ in range(5):
+                assert h.processor.process_available_batch() == 0
+            assert seen == []
+        finally:
+            h.close()
+
+    def test_a_group_is_annotated_from_build_to_side_effects(self, monkeypatch):
+        h = EngineHarness(use_kernel_backend=True)
+        try:
+            h.deploy(one_task())
+            seen = self._record_phases(monkeypatch)
+            h.stream.writer.try_write(
+                [LogAppendEntry(create_cmd()) for _ in range(3)])
+            h.pump()
+            assert h.kernel_backend.groups_processed > 0
+        finally:
+            h.close()
+        first = [p for i, p in enumerate(seen) if p not in seen[:i]]
+        assert first[:7] == ["build", "dispatch", "fetch", "unpack",
+                             "materialize", "append", "flush"]
+        assert "side_effects" in seen

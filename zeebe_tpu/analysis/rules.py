@@ -814,8 +814,9 @@ class KernelResultCommitDisciplineRule(Rule):
     ``stream/`` the device-result primitives — ``run_collect`` dispatch,
     ``jax.device_get`` fetch, ``unpack_events`` decode — are legal ONLY in
     the registered seam functions of ``engine/kernel_backend.py``
-    (``_dispatch_first_chunk`` / ``_complete_device_run`` / ``_fetch_rows``
-    / ``_shadow_execute``), whose results flow to materialization
+    (``_dispatch_first_chunk`` / ``_dispatch_chunk`` /
+    ``_complete_device_run`` / ``_fetch_rows`` / ``_shadow_execute``), whose
+    results flow to materialization
     exclusively via ``finish_group``'s shadow-verification gate. A direct
     fetch+decode anywhere else is a path for silently-corrupted device
     output to reach the replicated log without the watchdog, the chaos
@@ -832,6 +833,7 @@ class KernelResultCommitDisciplineRule(Rule):
     SEAM_MODULE = "zeebe_tpu/engine/kernel_backend.py"
     DEFAULT_SEAM_SCOPES = (
         "KernelBackend._dispatch_first_chunk",
+        "KernelBackend._dispatch_chunk",
         "KernelBackend._complete_device_run",
         "KernelBackend._fetch_rows",
         "KernelBackend._shadow_execute",

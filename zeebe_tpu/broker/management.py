@@ -243,14 +243,15 @@ class ManagementServer:
                 limit = 0
             if limit > 0:
                 spans = spans[-limit:]
+            header = tracer.collector.header()
             if params.get("format", ["json"])[0] == "chrome":
-                handler._send(200, json.dumps(chrome_trace(spans)))
+                handler._send(200, json.dumps(chrome_trace(spans, header)))
             else:
                 handler._send(200, json.dumps({
                     "enabled": tracer.enabled,
                     "sampleRate": tracer.sampler.rate,
                     "seed": tracer.sampler.seed,
-                    "emitted": tracer.collector.emitted,
+                    **header,
                     "spans": [s.to_dict() for s in spans],
                 }))
         elif path == "/profile":

@@ -42,6 +42,7 @@ import numpy as np
 
 from zeebe_tpu.engine.eligibility import PathAccounting, esp_start_host_reason
 from zeebe_tpu.models.bpmn.executable import ExecutableElement, ExecutableProcess
+from zeebe_tpu.observability.profiler import phase_annotation
 from zeebe_tpu.feel.feel import (
     FeelEvalError,
     Lit as _FeelLit,
@@ -1197,23 +1198,6 @@ def _watchdog_call(fn, deadline_s: float):
     return box["value"]
 
 
-def _profiler_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` around one kernel-chunk dispatch —
-    the device-side counterpart of the observability spans: a
-    ``jax.profiler.trace()`` capture taken while tracing is enabled shows the
-    chunk boundaries by name in Perfetto/TensorBoard. A no-op context when
-    tracing is off, so the dispatch hot path pays one attribute read."""
-    from zeebe_tpu.observability.tracer import get_tracer
-
-    if not get_tracer().enabled:
-        import contextlib
-
-        return contextlib.nullcontext()
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
-
-
 @dataclass
 class _PendingGroup:
     """One admitted command group with its device run in flight — the
@@ -1256,9 +1240,21 @@ class _PendingGroup:
     canary: bool = False
     raw_rows: list = field(default_factory=list)
     corrupt_tokens: list = field(default_factory=list)
-    # stage wall times (seconds), observed by the stream processor
+    # stage wall times (seconds), observed by the stream processor.
+    # Single-device groups split device_elapsed into its three parts:
+    # t_dispatch (every run_collect call: the jit call converts and uploads
+    # the host-filled arrays), t_fetch (device→host, the watchdog's thread
+    # hop included; t_device_get is the part inside jax.device_get alone)
+    # and t_unpack (the host decode between them) — they add up to
+    # device_elapsed by construction. t_build is the array build, which
+    # runs after admission closed and before the first dispatch.
     t_admit: float = 0.0
+    t_build: float = 0.0
     device_elapsed: float = 0.0
+    t_dispatch: float = 0.0
+    t_fetch: float = 0.0
+    t_device_get: float = 0.0
+    t_unpack: float = 0.0
     t_materialize: float = 0.0
     t_shadow: float = 0.0
 
@@ -2218,7 +2214,12 @@ class KernelBackend:
         caller overlaps host work with the device compute before calling
         ``_await_kernel``. Mesh groups stay synchronous (the runner's submit
         blocks), so they only record the build."""
-        built = self._build_group_arrays(pg.admitted)
+        import time as _time
+
+        t0 = _time.perf_counter()
+        with phase_annotation("build"):
+            built = self._build_group_arrays(pg.admitted)
+        pg.t_build = _time.perf_counter() - t0
         if built is None:
             pg.failed = True
             pg.fail_reason = "geometry-bounds"
@@ -2228,8 +2229,6 @@ class KernelBackend:
         if self.mesh_runner is not None:
             pg.mesh = True
             return
-
-        import time as _time
 
         # link-aware backend choice: the identical program, on the device
         # where (link + compute) is cheapest for this shape bucket. The
@@ -2264,7 +2263,8 @@ class KernelBackend:
             chaos = _DEVICE_CHAOS
             if chaos is not None:
                 chaos.dispatch_fault()
-            self._dispatch_first_chunk(pg)
+            with phase_annotation("dispatch"):
+                self._dispatch_first_chunk(pg)
         except Exception as exc:  # noqa: BLE001 — containment: a device
             # failure (chaos-injected or real) must degrade to the host
             # path, never poison the pump
@@ -2273,7 +2273,7 @@ class KernelBackend:
         # device_elapsed feeds the router's cost model: it must cover only
         # dispatch + fetch/decode windows, never the host work the caller
         # overlaps between them
-        pg.device_elapsed = _time.perf_counter() - t0
+        pg.t_dispatch = pg.device_elapsed = _time.perf_counter() - t0
 
     def _await_kernel(self, pg: "_PendingGroup") -> list[dict] | None:
         """Stage 2: block on the in-flight device run (or submit the mesh
@@ -2335,6 +2335,9 @@ class KernelBackend:
             pg.device_elapsed += _time.perf_counter() - t0
             return None
         pg.device_elapsed += _time.perf_counter() - t0
+        # the host decode is what the run leaves once its dispatches and
+        # fetches are taken out, so the three parts cover device_elapsed
+        pg.t_unpack = pg.device_elapsed - pg.t_dispatch - pg.t_fetch
         if self.router is not None and pg.dev is not None and steps is not None:
             # failed runs (non-quiescence, pool overflow) fall back to the
             # sequential path; their pathological wall times say nothing
@@ -2423,17 +2426,32 @@ class KernelBackend:
                 import time as _time
 
                 t_compile = _time.perf_counter()
-            with _profiler_annotation("zeebe.kernel_chunk.first"):
-                pg.run = run_collect(pg.dt, state, n_steps=self.chunk_steps,
-                                     config=pg.config)
+            pg.run = run_collect(pg.dt, state, n_steps=self.chunk_steps,
+                                 config=pg.config)
             if first_dispatch:
                 self._compiles_seen.add(compile_key)
                 self._observe_compile(pg.I, pg.T,
                                       _time.perf_counter() - t_compile)
         self.groups_by_device[_device_of(pg.run[1])] += 1
 
+    def _dispatch_chunk(self, pg: "_PendingGroup", state):
+        """One further chunk off the device-side carry (prefetched or next),
+        its call timed into ``t_dispatch``."""
+        import time as _time
+
+        from zeebe_tpu.ops.automaton import run_collect
+
+        t0 = _time.perf_counter()
+        with _device_ctx(pg.dev), phase_annotation("dispatch"):
+            run = run_collect(pg.dt, state, n_steps=self.chunk_steps,
+                              config=pg.config)
+        pg.t_dispatch += _time.perf_counter() - t0
+        return run
+
     def _complete_device_run(self, pg: "_PendingGroup"):
-        from zeebe_tpu.ops.automaton import run_collect, unpack_events
+        import time as _time
+
+        from zeebe_tpu.ops.automaton import unpack_events
 
         # chunked device loop: one dispatch + ONE host transfer per chunk of
         # lock-steps (vs two transfers per step). Quiesced states are fixed
@@ -2457,11 +2475,11 @@ class KernelBackend:
         hit_quiescence = False
         for k in range(max_chunks):
             if pg.pipeline_chunks and k >= 1 and k + 1 < max_chunks:
-                with _device_ctx(pg.dev), \
-                        _profiler_annotation("zeebe.kernel_chunk.prefetch"):
-                    nxt = run_collect(pg.dt, state, n_steps=chunk,
-                                      config=pg.config)
-            flat = self._fetch_rows(pg, packed, k)
+                nxt = self._dispatch_chunk(pg, state)
+            t0 = _time.perf_counter()
+            with phase_annotation("fetch"):
+                flat = self._fetch_rows(pg, packed, k)
+            pg.t_fetch += _time.perf_counter() - t0
             pg.chunks_run = k + 1
             # per row: T*(2+FO) packed event ints + (active, overflow) tail
             events_host = flat[:, :-2].reshape(chunk, T, 2 + FO)
@@ -2474,8 +2492,9 @@ class KernelBackend:
             # decoder never walks empty tail steps
             quiesced = np.flatnonzero(active == 0)
             keep = int(quiesced[0]) + 1 if quiesced.size else chunk
-            for s in range(keep):
-                steps.append(unpack_events(events_host[s], I))
+            with phase_annotation("unpack"):
+                for s in range(keep):
+                    steps.append(unpack_events(events_host[s], I))
             if quiesced.size:
                 hit_quiescence = True
                 break  # a prefetched over-run chunk is simply never fetched
@@ -2485,10 +2504,7 @@ class KernelBackend:
             elif k + 1 < max_chunks:
                 # last iteration dispatches nothing: a non-quiescing group is
                 # about to fall back, and the chunk would never be fetched
-                with _device_ctx(pg.dev), \
-                        _profiler_annotation("zeebe.kernel_chunk"):
-                    state, packed = run_collect(pg.dt, state, n_steps=chunk,
-                                                config=pg.config)
+                state, packed = self._dispatch_chunk(pg, state)
         if not hit_quiescence:
             pg.fail_reason = "no-quiesce"
             logger.warning("kernel group did not quiesce in %d steps; falling back", self.max_steps)
@@ -2507,6 +2523,8 @@ class KernelBackend:
         decode. The chaos seam (stalls, partial-chunk failures, result
         corruption) and the dispatch watchdog live exactly here; sampled
         groups additionally retain the rows for shadow comparison."""
+        import time as _time
+
         import jax
 
         chaos = _DEVICE_CHAOS
@@ -2514,7 +2532,10 @@ class KernelBackend:
         def fetch():
             if chaos is not None:
                 chaos.fetch_fault(chunk_index)
-            return jax.device_get(packed)
+            t0 = _time.perf_counter()
+            rows = jax.device_get(packed)
+            pg.t_device_get += _time.perf_counter() - t0
+            return rows
 
         deadline_ms = self.dispatch_timeout_ms
         # the watchdog thread-hop is paid only where it can pay off: on a
@@ -2614,8 +2635,7 @@ class KernelBackend:
         steps: list[dict] = []
         rows: list = []
         max_chunks = max(1, self.max_steps // chunk)
-        with _device_ctx(host_dev), \
-                _profiler_annotation("zeebe.kernel_chunk.shadow"):
+        with _device_ctx(host_dev):
             state = self._group_state(pg, host_dev)
             run = run_collect(dt, state, n_steps=chunk, config=config)
         self.shadow_by_device[_device_of(run[1])] += 1
@@ -2632,8 +2652,7 @@ class KernelBackend:
             if quiesced.size:
                 return steps, rows
             if k + 1 < max_chunks:
-                with _device_ctx(host_dev), \
-                        _profiler_annotation("zeebe.kernel_chunk.shadow"):
+                with _device_ctx(host_dev):
                     run = run_collect(dt, carry, n_steps=chunk, config=config)
         # the oracle did not quiesce: the group is genuinely pathological —
         # raise so the caller abandons it (sequential host re-execution)
@@ -2654,7 +2673,8 @@ class KernelBackend:
         health.note_shadow_check()
         t0 = _time.perf_counter()
         try:
-            shadow_steps, shadow_rows = self._shadow_execute(pg)
+            with phase_annotation("shadow"):
+                shadow_steps, shadow_rows = self._shadow_execute(pg)
         except Exception as exc:  # noqa: BLE001 — oracle failure: abandon
             # the group rather than commit an unverified device result; the
             # failed canary is noted ONCE, by finish_group's decline branch
@@ -2864,9 +2884,10 @@ class KernelBackend:
         t0 = _time.perf_counter()
         admitted = pg.admitted
         results = []
-        for adm in admitted:
-            ops = self._cascade_ops(adm.inst, steps)
-            results.append(self._materialize(adm, ops, make_builder))
+        with phase_annotation("materialize"):
+            for adm in admitted:
+                ops = self._cascade_ops(adm.inst, steps)
+                results.append(self._materialize(adm, ops, make_builder))
         pg.t_materialize = _time.perf_counter() - t0
         self.groups_processed += 1
         self.commands_processed += len(admitted)

@@ -25,6 +25,7 @@ from typing import Callable
 
 from zeebe_tpu.exporters.api import Exporter, ExporterContext, ExporterController
 from zeebe_tpu.logstreams import LogStream
+from zeebe_tpu.observability.tracer import instance_attrs
 from zeebe_tpu.state import ZbDb
 from zeebe_tpu.state.db import ColumnFamilyCode as CF
 from zeebe_tpu.utils.health import HealthStatus
@@ -405,9 +406,14 @@ class ExporterDirector:
         # on SUCCESS so a retried failure still gets its span
         if ok and tracer.mark_exported(
                 (container.exporter_id, pid, logged.position)):
+            record = logged.record
             tracer.emit(trace_id, "exporter.export", dur, pid,
                         attrs={"position": logged.position,
-                               "exporter": container.exporter_id})
+                               "exporter": container.exporter_id,
+                               "valueType": record.value_type.name,
+                               "intent": record.intent.name,
+                               "key": record.key,
+                               **instance_attrs(record.value)})
 
     def export_available(self, max_records: int = 10_000) -> int:
         """Export committed records not yet seen; returns the work done this

@@ -338,7 +338,7 @@ class ClusterRuntime(GatewayRuntimeBase):
         (processing, export) key on — the gateway request joins its causal
         tree with no extra wire fields."""
         from zeebe_tpu.broker.partition import BackpressureExceeded
-        from zeebe_tpu.observability.tracer import get_tracer
+        from zeebe_tpu.observability.tracer import get_tracer, instance_attrs
 
         tracer = get_tracer()
         # capture the enabled flag ONCE: enabling tracing while this request
@@ -393,6 +393,8 @@ class ClusterRuntime(GatewayRuntimeBase):
                          "intent": record.intent.name}
                 if response.is_rejection:
                     attrs["rejection"] = response.rejection_type.name
+                else:
+                    attrs.update(instance_attrs(response.value))
                 tracer.emit(trace_id, "gateway.request", latency, partition_id,
                             attrs=attrs)
         return response

@@ -32,8 +32,9 @@ broker, plus the two telemetry sources the host profiler cannot see:
   the threads were doing* at that moment.
 - :class:`DeviceTraceCapture` — single-flight on-demand
   ``jax.profiler.trace()`` into ``<data-dir>/jax-trace-<ts>/`` behind
-  ``POST /profile/device``, so the kernel chunks' ``TraceAnnotation``s
-  (tracer.py) become visible in Perfetto/TensorBoard.
+  ``POST /profile/device``, where the kernel groups' phases
+  (:func:`phase_annotation`) show beside the device's events in
+  Perfetto/TensorBoard.
 
 Cost contract (same shape as the metrics plane): ``profiling_hz=0``
 constructs nothing — one is-None check; at the default 19 Hz one sampling
@@ -364,6 +365,30 @@ def observe_compile(bucket: str, seconds: float) -> str:
     _M_COMPILE_SECONDS.labels(bucket).observe(seconds)
     _M_COMPILES.labels(cache).inc()
     return cache
+
+
+# -- kernel-group phases in the device trace ----------------------------------
+
+# the closed set of phases of one kernel group on its pump thread, from the
+# array build to the deferred side effects. Nine names at most: with
+# ``unattributed`` they fit the ten lines the benchmark's idle-gap table
+# keeps, and its trace reader takes host events by this prefix.
+PHASE_PREFIX = "zeebe.kernel_chunk."
+PHASES = ("build", "dispatch", "fetch", "unpack", "materialize", "append",
+          "flush", "side_effects", "shadow")
+_PHASE_NAMES = {phase: PHASE_PREFIX + phase for phase in PHASES}
+
+
+def phase_annotation(phase: str):
+    """``jax.profiler.TraceAnnotation`` over one phase of a kernel group, so
+    that any ``jax.profiler`` capture — the tracer on or off — shows what the
+    pump thread was in beside the device's own events. Inert (one atomic
+    read in the profiler) while no capture is recording. Only work that
+    follows an admitted group is annotated: the pump's empty admission
+    probes run every millisecond and would flood a trace."""
+    import jax
+
+    return jax.profiler.TraceAnnotation(_PHASE_NAMES[phase])
 
 
 # -- device memory telemetry --------------------------------------------------

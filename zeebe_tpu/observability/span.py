@@ -10,7 +10,12 @@ Design constraints:
 
 - **Bounded**: the collector is a per-process ring buffer (``deque`` with a
   ``maxlen``) — tracing can never grow memory without bound, the oldest spans
-  simply fall off.
+  fall off, and every dump says how many did (``evicted``).
+- **Anchored**: spans are stamped on the wall clock (``startUs``); the
+  collector carries one :func:`clock_anchor` — the same instant on the wall,
+  monotonic and perf-counter clocks — at the head of every dump, so a span
+  can be laid beside a harness's ``time.monotonic()`` stamps or a profiler
+  trace's events (docs/observability.md states the conversions).
 - **Deterministic**: the sampler's keep/drop decision is a pure function of
   (seed, trace id), so a chaos run replayed from its seed samples the exact
   same traces and the span stream is reproducible.
@@ -90,19 +95,45 @@ class DeterministicSampler:
                           self._seed_crc) < self._threshold
 
 
+def clock_anchor() -> dict:
+    """One instant read on the three clocks the program and its harnesses
+    stamp with. The wall clock is read on both sides of the other two and
+    the middle kept, so the three agree to well under a microsecond."""
+    wall0 = time.time_ns()
+    monotonic = time.monotonic_ns()
+    perf = time.perf_counter_ns()
+    wall1 = time.time_ns()
+    return {"wallNs": (wall0 + wall1) // 2, "monotonicNs": monotonic,
+            "perfCounterNs": perf}
+
+
+def to_clock_ns(anchor: dict, start_us: int, clock: str = "monotonicNs") -> int:
+    """A span's ``startUs`` (wall clock, microseconds) on another of the
+    anchor's clocks, in nanoseconds."""
+    return start_us * 1000 - anchor["wallNs"] + anchor[clock]
+
+
+def from_clock_ns(anchor: dict, t_ns: int, clock: str = "monotonicNs") -> int:
+    """The reverse of :func:`to_clock_ns`: a reading of ``clock`` in
+    nanoseconds as a span's ``startUs``."""
+    return (t_ns - anchor[clock] + anchor["wallNs"]) // 1000
+
+
 class SpanCollector:
     """Bounded per-process span ring buffer. Adds take the lock — the
     ``emitted`` counter is a read-modify-write and ``resize`` swaps the
     deque, so a lock-free add could undercount or land a span on an
     orphaned buffer. The lock is only paid for spans that survived the
-    enabled + sampled guards. ``emitted`` counts every span ever added —
-    ``emitted - len(self)`` is the number the ring has already evicted."""
+    enabled + sampled guards. ``emitted`` counts every span ever added;
+    ``evicted`` is how many of them the ring has already dropped."""
 
     def __init__(self, capacity: int = 16384) -> None:
         self.capacity = capacity
         self._spans: collections.deque[Span] = collections.deque(maxlen=capacity)
         self._lock = threading.Lock()
         self.emitted = 0
+        # set by Tracer.enable(): the clocks' common instant (clock_anchor)
+        self.anchor: dict | None = None
 
     def add(self, span: Span) -> None:
         with self._lock:
@@ -111,6 +142,18 @@ class SpanCollector:
 
     def __len__(self) -> int:
         return len(self._spans)
+
+    @property
+    def evicted(self) -> int:
+        return self.emitted - len(self._spans)
+
+    def header(self) -> dict:
+        """What every dump says of itself before its spans: the ring's
+        size, how many spans it dropped, and the clock anchor."""
+        with self._lock:
+            return {"capacity": self.capacity, "emitted": self.emitted,
+                    "evicted": self.emitted - len(self._spans),
+                    "anchor": self.anchor}
 
     def snapshot(self) -> list[Span]:
         with self._lock:
@@ -129,29 +172,33 @@ class SpanCollector:
     # -- export ---------------------------------------------------------------
 
     def to_jsonl(self, path) -> int:
-        """One span JSON object per line; returns the number written."""
-        spans = self.snapshot()
+        """The header (``{"header": {...}}``) on the first line, then one
+        span JSON object per line; returns the number of spans written."""
+        header, spans = self.header(), self.snapshot()
         with open(path, "w") as f:
+            f.write(json.dumps({"header": header}))
+            f.write("\n")
             for span in spans:
                 f.write(json.dumps(span.to_dict()))
                 f.write("\n")
         return len(spans)
 
     def chrome_trace(self) -> dict:
-        return chrome_trace(self.snapshot())
+        return chrome_trace(self.snapshot(), self.header())
 
     def write_chrome_trace(self, path) -> int:
         spans = self.snapshot()
         with open(path, "w") as f:
-            json.dump(chrome_trace(spans), f)
+            json.dump(chrome_trace(spans, self.header()), f)
             f.write("\n")
         return len(spans)
 
 
-def chrome_trace(spans: Iterable[Span]) -> dict:
+def chrome_trace(spans: Iterable[Span], header: dict | None = None) -> dict:
     """Chrome trace-event JSON (the format Perfetto and ``chrome://tracing``
     open directly): one complete event per span, process = partition, one
-    thread lane per trace id so a trace's spans stack together visually."""
+    thread lane per trace id so a trace's spans stack together visually.
+    ``header`` (``SpanCollector.header()``) rides ``otherData``."""
     tids: dict[str, int] = {}
     events = []
     for span in spans:
@@ -177,7 +224,7 @@ def chrome_trace(spans: Iterable[Span]) -> dict:
     return {
         "traceEvents": events,
         "displayTimeUnit": "ms",
-        "otherData": {"source": "zeebe_tpu.observability"},
+        "otherData": {"source": "zeebe_tpu.observability", **(header or {})},
     }
 
 

@@ -42,6 +42,7 @@ from zeebe_tpu.observability.span import (
     DeterministicSampler,
     Span,
     SpanCollector,
+    clock_anchor,
     now_us,
 )
 from zeebe_tpu.utils import evict_oldest_half as _evict_oldest_half
@@ -102,7 +103,14 @@ class Tracer:
         self.sampler = DeterministicSampler(seed=seed, rate=sample_rate)
         if capacity != self.collector.capacity:
             self.collector.resize(capacity)
+        self.collector.anchor = clock_anchor()
         self.enabled = True
+
+    @property
+    def anchor(self) -> dict | None:
+        """The instant of the last ``enable()`` on the wall, monotonic and
+        perf-counter clocks (``span.clock_anchor``); heads every span dump."""
+        return self.collector.anchor
 
     def disable(self) -> None:
         self.enabled = False
@@ -204,6 +212,23 @@ class Tracer:
             _evict_oldest_half(seen, _EXPORT_SEEN_LIMIT)
         seen[identity] = None
         return True
+
+
+def instance_attrs(value) -> dict:
+    """The process instance(s) a record's value names, as span attributes:
+    ``processInstanceKey``, or ``processInstanceKeys`` for a batch of jobs —
+    what lets a reduction lay one instance's spans out end to end."""
+    get = getattr(value, "get", None)
+    if get is None:
+        return {}
+    key = get("processInstanceKey")
+    if isinstance(key, int) and key > 0:
+        return {"processInstanceKey": key}
+    jobs = get("jobs")
+    if jobs:
+        return {"processInstanceKeys": [job.get("processInstanceKey")
+                                        for job in jobs]}
+    return {}
 
 
 _TRACER = Tracer()

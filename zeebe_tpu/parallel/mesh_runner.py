@@ -249,7 +249,9 @@ class MeshKernelRunner:
             for name in ("transitions", "jobs_created", "completed", "overflow"):
                 local_specs[name] = P(BATCH_AXIS)
 
-            def local(dt, state):
+            # named for the kernel: the XLA module is jit_<name>, and a trace
+            # reduction finds the kernel's device time by "run_collect"
+            def run_collect_sharded(dt, state):
                 # shard-local view: scalar counters for the kernel body
                 local_state = dict(state)
                 for name in ("transitions", "jobs_created", "completed",
@@ -263,7 +265,7 @@ class MeshKernelRunner:
                 return new_state, packed
 
             fn = jax.jit(jax.shard_map(
-                local,
+                run_collect_sharded,
                 mesh=self.mesh,
                 in_specs=(
                     DeviceTables(**{

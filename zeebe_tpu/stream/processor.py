@@ -33,6 +33,7 @@ from typing import Callable
 
 from zeebe_tpu.journal.journal import CorruptedJournalError
 from zeebe_tpu.logstreams import LogAppendEntry, LoggedRecord, LogStream
+from zeebe_tpu.observability.profiler import phase_annotation
 from zeebe_tpu.protocol import Record, RecordType, RejectionType, ValueType, rejection
 from zeebe_tpu.state.tiering import ColdCorruptionError
 
@@ -242,17 +243,21 @@ class StreamProcessor:
             "source position of the last replayed batch", ("partition",)
         ).labels(partition_label)
         # pipelined-batch stage histograms: the before/after breakdown of the
-        # host-path gap (decode/admission, device run, burst materialization,
-        # log append, group-commit flush, deferred side effects) — children
-        # pre-resolved, the group loop is hot
+        # host-path gap (decode/admission, array build, device run, burst
+        # materialization, log append, group-commit flush, deferred side
+        # effects) — children pre-resolved, the group loop is hot. The three
+        # device_* stages are the parts of `device` on a single-device group
+        # (jit calls with their uploads, device→host fetches, host decode);
+        # a mesh group has no such parts, so their counts may be lower.
         self._m_pipeline = {
             stage: REGISTRY.histogram(
                 f"stream_processor_pipeline_{stage}",
                 f"seconds per kernel group in the {stage} stage of the "
                 "pipelined batch-execution path",
                 ("partition",)).labels(partition_label)
-            for stage in ("decode", "device", "materialize", "append",
-                          "flush", "side_effects")
+            for stage in ("decode", "build", "device", "device_dispatch",
+                          "device_fetch", "device_unpack", "materialize",
+                          "append", "flush", "side_effects")
         }
         # dispatch-overlap receipt (ISSUE 13): fraction of a kernel group's
         # wall time during which the host did useful work (the previous
@@ -687,34 +692,35 @@ class StreamProcessor:
                 if self._speculation_enabled:
                     spec_next = self._maybe_speculate(cmds[-1].position + 1)
                 t_append = _time.perf_counter()
-                try:
+                with phase_annotation("append"):
+                    try:
+                        for cmd, result in zip(cmds, builders):
+                            if isinstance(result, PreparedBurst):
+                                if result.count:
+                                    self.last_written_position = self.writer.append_prepatched(
+                                        result.buf, result.pos_offsets,
+                                        result.ts_offsets, result.count,
+                                        has_pending_commands=result.has_pending_commands,
+                                    )
+                                continue
+                            entries = [
+                                LogAppendEntry(f.record, f.processed) for f in result.follow_ups
+                            ]
+                            if entries:
+                                self.last_written_position = self.writer.try_write(
+                                    entries, source_position=cmd.position
+                                )
+                    except Exception:
+                        write_failed = True
+                        raise
+                    self.last_processed_position = cmds[-1].position
+                    self._store_last_processed(self.last_processed_position)
                     for cmd, result in zip(cmds, builders):
                         if isinstance(result, PreparedBurst):
                             if result.count:
-                                self.last_written_position = self.writer.append_prepatched(
-                                    result.buf, result.pos_offsets,
-                                    result.ts_offsets, result.count,
-                                    has_pending_commands=result.has_pending_commands,
-                                )
-                            continue
-                        entries = [
-                            LogAppendEntry(f.record, f.processed) for f in result.follow_ups
-                        ]
-                        if entries:
-                            self.last_written_position = self.writer.try_write(
-                                entries, source_position=cmd.position
-                            )
-                except Exception:
-                    write_failed = True
-                    raise
-                self.last_processed_position = cmds[-1].position
-                self._store_last_processed(self.last_processed_position)
-                for cmd, result in zip(cmds, builders):
-                    if isinstance(result, PreparedBurst):
-                        if result.count:
-                            self._note_burst_dedupe(cmd, result)
-                    else:
-                        self._note_live_dedupe(cmd, result.follow_ups)
+                                self._note_burst_dedupe(cmd, result)
+                        else:
+                            self._note_live_dedupe(cmd, result.follow_ups)
                 append_dur = _time.perf_counter() - t_append
                 pipeline["append"].observe(append_dur)
         except _STORAGE_CORRUPTION:
@@ -753,11 +759,17 @@ class StreamProcessor:
             (self.last_written_position, builders,
              notes if self._ack_gated else None))
         t_flush = _time.perf_counter()
-        self._group_commit_point()
+        with phase_annotation("flush"):
+            self._group_commit_point()
         flush_dur = _time.perf_counter() - t_flush
         pipeline["flush"].observe(flush_dur)
         pipeline["decode"].observe(pending.t_admit)
+        pipeline["build"].observe(pending.t_build)
         pipeline["device"].observe(pending.device_elapsed)
+        if not pending.mesh:
+            pipeline["device_dispatch"].observe(pending.t_dispatch)
+            pipeline["device_fetch"].observe(pending.t_fetch)
+            pipeline["device_unpack"].observe(pending.t_unpack)
         pipeline["materialize"].observe(pending.t_materialize)
         self._m_batched.inc(len(cmds))
         elapsed = _time.perf_counter() - group_start
@@ -780,11 +792,19 @@ class StreamProcessor:
             if spec_dispatched_at:
                 self._trace_speculative(cmds[0].position, spec_dispatched_at,
                                         "consumed")
-            self._trace_group(cmds, elapsed, {
-                "decode": pending.t_admit, "device": pending.device_elapsed,
+            stages = {
+                "decode": pending.t_admit, "build": pending.t_build,
+                "device": pending.device_elapsed,
                 "materialize": pending.t_materialize, "append": append_dur,
                 "flush": flush_dur, "overlap": overlap,
-            }, notes)
+            }
+            if not pending.mesh:
+                # the same three numbers the device_* histograms observed
+                stages.update(device_dispatch=pending.t_dispatch,
+                              device_fetch=pending.t_fetch,
+                              device_unpack=pending.t_unpack)
+            self._trace_group(cmds, elapsed, stages, notes,
+                              device_get=pending.t_device_get)
         return len(cmds)
 
     def _trace_speculative(self, first_pos: int, t_disp: float,
@@ -945,7 +965,8 @@ class StreamProcessor:
 
     def _trace_group(self, cmds: list[LoggedRecord], elapsed: float,
                      stages: dict[str, float],
-                     notes: list[tuple] | None) -> None:
+                     notes: list[tuple] | None,
+                     device_get: float = 0.0) -> None:
         """Spans for one kernel group: a group span with one child per
         pipeline stage (the per-trace view of the stream_processor_pipeline_*
         histograms), a backlog-wait span per sampled command (append → wave
@@ -973,8 +994,12 @@ class StreamProcessor:
                                "firstPosition": cmds[0].position,
                                "lastPosition": cmds[-1].position})
             for stage, dur in stages.items():
+                # the fetch's time inside jax.device_get alone (the rest is
+                # the watchdog's thread hop) rides its span, in no histogram
+                attrs = ({"deviceGetUs": int(device_get * 1e6)}
+                         if stage == "device_fetch" else None)
                 tracer.emit(group_trace, f"processor.stage.{stage}", dur, pid,
-                            parent="processor.kernel_group")
+                            parent="processor.kernel_group", attrs=attrs)
         share = elapsed / len(cmds)
         by_position = ({note[1]: note for note in notes} if notes else {})
         for cmd in cmds:
@@ -1051,34 +1076,35 @@ class StreamProcessor:
         the whole queue unless a journal flush_interval gates acks on the
         covering group-commit fsync)."""
         dq = self._deferred_effects
-        if not dq:
+        acked = self._acked_position
+        if not dq or dq[0][0] > acked:
             return
         import time as _time
 
         from zeebe_tpu.engine.burst_templates import PreparedBurst
 
         t0 = _time.perf_counter()
-        acked = self._acked_position
         in_txn = self.db.in_transaction
         emitted = 0
-        while dq and dq[0][0] <= acked:
-            if in_txn and any(
-                not isinstance(b, PreparedBurst) and b.post_commit_tasks
-                for b in dq[0][1]
-            ):
-                # post-commit tasks are an API allowed to open their own db
-                # transaction — they only run at out-of-transaction drain
-                # points (FIFO preserved: the queue stops at the first
-                # task-bearing group; responses never overtake it)
-                break
-            _position, builders, notes = dq.pop(0)
-            self._emit_group_effects(builders)
-            if notes:
-                # gated ack release: the covering fsync succeeded (this drain
-                # only runs past an advanced acked position), so the
-                # append→ack observation and ack/fsync-wait spans are real
-                self._release_acks(notes)
-            emitted += 1
+        with phase_annotation("side_effects"):
+            while dq and dq[0][0] <= acked:
+                if in_txn and any(
+                    not isinstance(b, PreparedBurst) and b.post_commit_tasks
+                    for b in dq[0][1]
+                ):
+                    # post-commit tasks are an API allowed to open their own
+                    # db transaction — they only run at out-of-transaction
+                    # drain points (FIFO preserved: the queue stops at the
+                    # first task-bearing group; responses never overtake it)
+                    break
+                _position, builders, notes = dq.pop(0)
+                self._emit_group_effects(builders)
+                if notes:
+                    # gated ack release: the covering fsync succeeded (this
+                    # drain only runs past an advanced acked position), so the
+                    # append→ack observation and ack/fsync-wait spans are real
+                    self._release_acks(notes)
+                emitted += 1
         if emitted:
             # observed only when work happened: the stage breakdown stays a
             # per-group view, not inflated by empty drain attempts
