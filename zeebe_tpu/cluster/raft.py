@@ -46,6 +46,69 @@ SNAPSHOT_CHUNK_BYTES = 512 * 1024
 # way (the documented caveat), and a healthy leader's heartbeats make the
 # window unreachable in normal operation.
 LAST_RESORT_ELECTION_MS = 10 * ELECTION_TIMEOUT_MS
+# bound of the in-memory tail of the log, per replica (ISSUE 27): packed entry
+# bytes plus a flat per-entry overhead; lowest index evicted first
+LOG_TAIL_MAX_BYTES = 4 * 1024 * 1024
+_LOG_TAIL_ENTRY_OVERHEAD = 256
+
+
+class _LogTail:
+    """Write-through cache of the decoded newest entries of one raft journal:
+    a contiguous run of indexes ``first..last`` ending at the journal's last
+    index. The journal is the truth; the tail only saves reading back (seek,
+    CRC, unpack) what was just written. It is cut wherever the journal is
+    cut, and an empty tail is always correct — every reader falls through to
+    the file. Entries are the dicts readers get from the journal path
+    (``index`` included); they are never mutated after ``push``."""
+
+    __slots__ = ("entries", "first", "last", "nbytes")
+
+    def __init__(self) -> None:
+        self.entries: dict[int, dict] = {}
+        self.first = 1
+        self.last = 0  # empty: last < first
+        self.nbytes = 0
+
+    def clear(self) -> None:
+        self.entries.clear()
+        self.first, self.last, self.nbytes = 1, 0, 0
+
+    @staticmethod
+    def _size(entry: dict) -> int:
+        return len(entry.get("data") or b"") + _LOG_TAIL_ENTRY_OVERHEAD
+
+    def push(self, index: int, entry: dict) -> None:
+        if self.entries and index != self.last + 1:
+            self.clear()  # not the next index: never hold a run with a hole
+        if not self.entries:
+            self.first = index
+        self.entries[index] = entry
+        self.last = index
+        self.nbytes += self._size(entry)
+        while self.nbytes > LOG_TAIL_MAX_BYTES:
+            self._drop(self.first)
+            self.first += 1
+
+    def _drop(self, index: int) -> None:
+        self.nbytes -= self._size(self.entries.pop(index))
+
+    def cut_after(self, index: int) -> None:
+        """Forget every entry above ``index`` (journal truncated there)."""
+        if index < self.first:
+            self.clear()
+            return
+        while self.last > index:
+            self._drop(self.last)
+            self.last -= 1
+
+    def cut_before(self, index: int) -> None:
+        """Forget every entry below ``index`` (journal compacted to there)."""
+        if index > self.last:
+            self.clear()
+            return
+        while self.first < index:
+            self._drop(self.first)
+            self.first += 1
 
 
 class RaftRole(enum.Enum):
@@ -142,6 +205,12 @@ class RaftNode:
             "deferred_append_count_total",
             "appends acked before fsync (delayed flush policy)",
             ("partition",)).labels(pid)
+        log_reads = REGISTRY.counter(
+            "raft_log_reads_total",
+            "raft log reads by where the answer came from: the in-memory "
+            "tail of the log, or the journal file", ("partition", "source"))
+        self._m_reads_tail = log_reads.labels(pid, "tail")
+        self._m_reads_journal = log_reads.labels(pid, "journal")
         self._election_started_ms: int | None = None
         self._leader_since_ms: int | None = None
         self.members = sorted(members)
@@ -162,6 +231,9 @@ class RaftNode:
 
         self.journal = SegmentedJournal(self.directory / "raft-log",
                                         max_unflushed_bytes=max_unflushed_bytes)
+        # decoded newest entries, written through by _append_local; empty
+        # after open (the first reads after a restart go to the file)
+        self._tail = _LogTail()
         # "immediate": fsync before acking appends / advancing own match —
         # the reference's default (journal flush-before-ack, SURVEY §2.2);
         # "delayed": fsync on the next tick (reference DelayedFlusher);
@@ -365,6 +437,7 @@ class RaftNode:
                 # re-elects; this node re-converges as a follower.
                 self._flushed_index = min(self._flushed_index,
                                           self.journal.last_index)
+                self._tail.cut_after(self.journal.last_index)
                 self._flush_dirty = False
                 self._last_flush_perf = _perf_counter()
                 self._note_storage_error(exc)
@@ -378,9 +451,10 @@ class RaftNode:
 
     def _truncate_after(self, index: int) -> None:
         had_config_after = any(
-            e.get("config") for e in self._entries_from(index + 1)
+            e.get("config") for e in self._read_entries(index + 1)
         )
         self.journal.truncate_after(index)
+        self._tail.cut_after(index)
         # conflicting entries re-appended on top of a truncation must be
         # fsynced again even when the log lands back on the old flushed index
         self._flushed_index = min(self._flushed_index, index)
@@ -391,23 +465,16 @@ class RaftNode:
             self._last_config_index = config_index
             self._apply_config(members)
 
-    def _entries_from(self, from_index: int) -> list[dict]:
-        out = []
-        for rec in self.journal.read_from(from_index):
-            entry = unpackb(rec.data)
-            entry["index"] = rec.index
-            out.append(entry)
-        return out
-
     def _latest_logged_config(self) -> tuple[list[str], int]:
         latest, index = self._config_base, 0
-        for entry in self._entries_from(self.snapshot_index + 1):
+        for entry in self._read_entries(self.snapshot_index + 1):
             if entry.get("config"):
                 latest, index = entry["config"], entry["index"]
         return latest, index
 
     def _reset_journal(self, next_index: int) -> None:
         self.journal.reset(next_index)
+        self._tail.clear()
         self._flushed_index = min(self._flushed_index, next_index - 1)
         # the log prefix (and any config entries in it) is gone: the current
         # membership becomes the configuration base for rollbacks
@@ -444,6 +511,7 @@ class RaftNode:
             return evidence
         self._last_repair_perf = now
         evidence = self.journal.repair_corruption()
+        self._tail.cut_after(self.journal.last_index)
         self._flushed_index = min(self._flushed_index, self.journal.last_index)
         if (len(self.members) <= 1
                 and self.journal.last_index < self.commit_index):
@@ -504,11 +572,39 @@ class RaftNode:
     def _last_log_index(self) -> int:
         return max(self.journal.last_index, self.snapshot_index)
 
+    def _tail_for(self, index: int) -> _LogTail | None:
+        """The in-memory tail if it answers for ``index`` and every index
+        above it, else None (the caller reads the journal file). The one
+        place that holds the tail's invariant: it never knows more than the
+        journal — no index above ``journal.last_index``, none below what
+        was compacted away, and what it holds ends where the journal ends.
+        Every cut of the journal cuts the tail beside it; a cut that reached
+        the journal some other way (a segment roll's failed fsync inside
+        ``journal.append``) is caught up with here, before anything is
+        answered."""
+        tail = self._tail
+        journal = self.journal
+        if tail.last > journal.last_index:
+            tail.cut_after(journal.last_index)
+        elif tail.last < journal.last_index:
+            tail.clear()  # cannot happen (one writer); never answer short
+        if tail.first < journal.first_index:
+            tail.cut_before(journal.first_index)
+        if not tail.entries or index < tail.first:
+            self._m_reads_journal.inc()
+            return None
+        self._m_reads_tail.inc()
+        return tail
+
     def _entry_term(self, index: int) -> int:
         if index == 0:
             return 0
         if index == self.snapshot_index:
             return self.snapshot_term
+        tail = self._tail_for(index)
+        if tail is not None:
+            entry = tail.entries.get(index)
+            return -1 if entry is None else entry["term"]
         rec = self.journal.read_entry(index)
         if rec is None:
             return -1
@@ -517,13 +613,26 @@ class RaftNode:
     def _last_log_term(self) -> int:
         return self._entry_term(self._last_log_index())
 
-    def _read_entries(self, from_index: int, limit: int) -> list[dict]:
-        out = []
+    def _read_entries(self, from_index: int, limit: int | None = None,
+                      upto: int | None = None) -> list[dict]:
+        """Entries from ``from_index`` on, in order: at most ``limit`` of
+        them, none above ``upto``. Each is the reader's own dict (the
+        loopback network hands it to the follower as it is)."""
+        tail = self._tail_for(from_index)
+        if tail is not None:
+            stop = tail.last if upto is None else min(tail.last, upto)
+            if limit is not None:
+                stop = min(stop, from_index + limit - 1)
+            entries = tail.entries
+            return [dict(entries[i]) for i in range(from_index, stop + 1)]
+        out: list[dict] = []
         for rec in self.journal.read_from(from_index):
+            if upto is not None and rec.index > upto:
+                break
             entry = unpackb(rec.data)
             entry["index"] = rec.index
             out.append(entry)
-            if len(out) >= limit:
+            if limit is not None and len(out) >= limit:
                 break
         return out
 
@@ -855,10 +964,20 @@ class RaftNode:
 
         start = _time.perf_counter()
         asqn = entry.get("asqn", -1)
+        # the node's own copy, without the sender's "index": the loopback
+        # network hands a follower the very dict the leader read
+        stored = {k: v for k, v in entry.items() if k != "index"}
+        data = stored.get("data")
+        if data is not None and type(data) is not bytes:
+            stored["data"] = bytes(data)  # as unpackb returns it
         rec = self.journal.append(
-            packb({k: v for k, v in entry.items() if k != "index"}),
+            packb(stored),
             asqn=asqn if asqn is not None and asqn >= 0 else -1,  # ASQN_IGNORE
         )
+        # write-through, after the journal took the entry: durability and
+        # acknowledgement stay with _after_local_append / _flush_journal
+        stored["index"] = rec.index
+        self._tail.push(rec.index, stored)
         self._m_append_latency.observe(_time.perf_counter() - start)
         self._m_append_index.set(rec.index)
         return rec.index
@@ -1020,9 +1139,12 @@ class RaftNode:
         self.snapshot_term = term
         self._snapshot_bytes = data
         self.journal.compact(index + 1)
+        self._tail.cut_before(self.journal.first_index)
 
     def entry_term(self, index: int) -> int:
-        """Term of the entry at ``index`` (snapshot boundary aware)."""
+        """Term of the entry at ``index`` (snapshot boundary aware). Answered
+        from the in-memory tail of the log when ``index`` is in it (no file
+        read, no CRC check), from the journal file otherwise; -1 if absent."""
         return self._entry_term(index)
 
     def _send_snapshot(self, member: str) -> None:
@@ -1127,11 +1249,4 @@ class RaftNode:
 
     def committed_entries(self, from_index: int) -> list[dict]:
         """Entries up to the commit index (application entries only carry data)."""
-        out = []
-        for rec in self.journal.read_from(from_index):
-            if rec.index > self.commit_index:
-                break
-            entry = unpackb(rec.data)
-            entry["index"] = rec.index
-            out.append(entry)
-        return out
+        return self._read_entries(from_index, upto=self.commit_index)
