@@ -4,6 +4,7 @@ localhost against an in-process broker cluster runtime."""
 
 from __future__ import annotations
 
+import threading
 import time
 
 import grpc
@@ -202,6 +203,75 @@ class TestJobWorker:
             assert worker.failed_count >= 1
         finally:
             worker.stop()
+
+    @pytest.mark.parametrize("threads, side_by_side", [(1, False), (4, True)])
+    def test_jobs_of_one_activation_share_the_handler_threads(
+            self, stack, threads, side_by_side):
+        """Four jobs that one poll activates: on four handler threads they
+        are held side by side, on one thread one after another."""
+        client, _ = stack
+        job_type = f"wc{threads}_work"
+        client.deploy_resource((f"wc{threads}.bpmn",
+                                one_task(f"wc{threads}", job_type)))
+        for _ in range(4):
+            client.create_instance(f"wc{threads}")
+        lock = threading.Lock()
+        holding, most = [0], [0]
+
+        def hold(job):
+            with lock:
+                holding[0] += 1
+                most[0] = max(most[0], holding[0])
+            time.sleep(0.2)
+            with lock:
+                holding[0] -= 1
+            return {}
+
+        worker = JobWorker(client, job_type, hold, poll_interval_s=0.02)
+        worker.HANDLER_THREADS = threads
+        worker.start()
+        try:
+            deadline = time.time() + 15
+            while worker.handled_count < 4 and time.time() < deadline:
+                time.sleep(0.02)
+            assert worker.handled_count == 4
+            assert (most[0] > 1) == side_by_side
+            assert most[0] <= threads
+        finally:
+            worker.stop()
+
+    def test_worker_holds_at_most_max_jobs_active(self, stack):
+        """The poller asks for the room ``max_jobs_active`` leaves, so the
+        jobs activated for the worker and not yet finished never pass it."""
+        client, _ = stack
+        client.deploy_resource(("wm.bpmn", one_task("wm", "wm_work")))
+        for _ in range(6):
+            client.create_instance("wm")
+        asked, activate = [], client.activate_jobs
+
+        def counting(job_type, max_jobs=32, **kw):
+            jobs = activate(job_type, max_jobs=max_jobs, **kw)
+            asked.append((max_jobs, len(jobs), worker._active))
+            return jobs
+
+        def hold(job):
+            time.sleep(0.1)
+            return {}
+
+        worker = JobWorker(client, "wm_work", hold, poll_interval_s=0.02,
+                           max_jobs_active=2)
+        client.activate_jobs = counting
+        try:
+            worker.start()
+            deadline = time.time() + 15
+            while worker.handled_count < 6 and time.time() < deadline:
+                time.sleep(0.02)
+            assert worker.handled_count == 6
+            assert all(1 <= room <= 2 and got <= room and got + active <= 2
+                       for room, got, active in asked), asked
+        finally:
+            worker.stop()
+            del client.activate_jobs
 
 
 class TestEvaluateDecision:

@@ -35,6 +35,15 @@ class TestMetricsRegistry:
         assert "zeebe_latency_count 1" in text
         assert "# TYPE zeebe_records_total counter" in text
 
+    def test_observe_many_keeps_sum_and_count_exact(self):
+        reg = MetricsRegistry()
+        child = reg.histogram("per_record", buckets=(0.001, 0.01)).labels()
+        child.observe_many(0.02, 10)     # ten records at a mean of 2 ms
+        child.observe_many(0.5, 0)       # an empty pass observes nothing
+        child.observe(0.0005)
+        assert (child.count, child.bucket_counts) == (11, [1, 10, 0])
+        assert child.sum == pytest.approx(0.0205)
+
     def test_same_name_returns_same_metric(self):
         reg = MetricsRegistry()
         assert reg.counter("x") is reg.counter("x")
@@ -129,8 +138,60 @@ class TestBackpressure:
         assert limit.limit > 20
         grown = limit.limit
         for _ in range(50):
-            limit.on_sample(1000.0, 10, dropped=False)  # huge queueing
+            limit.on_sample(1000.0, 2, dropped=False)  # slow, but far from
+        assert limit.limit == grown                    # the limit: no queue
+        for _ in range(50):
+            limit.on_sample(1000.0, limit.limit, dropped=False)  # huge queueing
         assert limit.limit < grown
+
+    def test_vegas_is_fed_a_window_at_a_time(self):
+        # the reference wraps its vegas in a WindowedLimit: one sample a
+        # second, the window's mean RTT and the most it saw in flight
+        now = [0]
+        limiter = CommandRateLimiter("vegas", clock_millis=lambda: now[0])
+        seen = []
+        sample = limiter.algorithm.on_sample
+        limiter.algorithm.on_sample = lambda *a, **kw: (seen.append((a, kw)),
+                                                        sample(*a, **kw))
+        position = 0
+        for rtt in [1, 7] * 6:               # twelve samples in 48 ms
+            position += 1
+            limiter.on_appended(position)
+            now[0] += rtt
+            limiter.on_processed(position)
+        assert seen == []                    # enough samples, too young
+        now[0] += 1000
+        limiter.on_appended(99)
+        limiter.on_appended(100)
+        now[0] += 4
+        limiter.on_processed(99)
+        assert seen == [((4.0, 2), {"dropped": False})]   # 52 ms over 13
+        limiter.on_processed(100)
+        assert len(seen) == 1                # a new window has opened
+
+    def test_commands_of_unequal_cost_do_not_shrink_an_idle_partitions_limit(self):
+        # one empty ActivateJobs took 1 ms, a partition's other commands
+        # take 3 to 9: single samples against the fastest ever seen read
+        # that as queueing
+        costs = (3, 7, 5, 9)
+        single = VegasLimit(initial=20)
+        single.on_sample(1.0, single.limit, dropped=False)
+        for i in range(400):
+            single.on_sample(float(costs[i % 4]), single.limit, dropped=False)
+        assert single.limit <= 8
+        now = [0]
+        limiter = CommandRateLimiter("vegas", clock_millis=lambda: now[0])
+        for position in range(4000):
+            limiter.on_appended(position)
+            if position % 50 == 0:           # now and then three in flight
+                limiter.on_appended(-position - 1)
+                limiter.on_appended(-position - 2)
+            now[0] += 1 if position == 0 else costs[position % 4]
+            limiter.on_processed(position)
+            limiter.on_processed(-position - 1)
+            limiter.on_processed(-position - 2)
+        assert limiter.limit == 20
+        assert limiter.try_acquire(_cmd())
 
 
 class TestConfigBinding:

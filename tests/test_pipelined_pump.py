@@ -403,7 +403,7 @@ class TestPhaseAnnotations:
 
         assert not get_tracer().enabled
         assert PHASE_PREFIX == "zeebe.kernel_chunk."
-        assert len(PHASES) <= 9 and len(set(PHASES)) == len(PHASES)
+        assert len(PHASES) <= 10 and len(set(PHASES)) == len(PHASES)
         for phase in PHASES:
             with phase_annotation(phase) as annotation:
                 assert isinstance(annotation, jax.profiler.TraceAnnotation)
@@ -440,7 +440,7 @@ class TestPhaseAnnotations:
         finally:
             h.close()
 
-    def test_a_group_is_annotated_from_build_to_side_effects(self, monkeypatch):
+    def test_a_group_is_annotated_from_admit_to_side_effects(self, monkeypatch):
         h = EngineHarness(use_kernel_backend=True)
         try:
             h.deploy(one_task())
@@ -452,6 +452,6 @@ class TestPhaseAnnotations:
         finally:
             h.close()
         first = [p for i, p in enumerate(seen) if p not in seen[:i]]
-        assert first[:7] == ["build", "dispatch", "fetch", "unpack",
+        assert first[:8] == ["admit", "build", "dispatch", "fetch", "unpack",
                              "materialize", "append", "flush"]
         assert "side_effects" in seen

@@ -9,7 +9,8 @@ in-flight work and drain load.
 Implemented limiters: fixed, AIMD (additive increase on success below the
 limit, multiplicative decrease on timeout), and vegas (latency-gradient:
 queue estimate = limit * (1 - minRTT/sampleRTT), grow when small, shrink when
-large) — the reference's default.
+large; a sample taken with fewer than half the limit in flight moves
+nothing) — the reference's default.
 """
 
 from __future__ import annotations
@@ -57,6 +58,15 @@ class AimdLimit:
 class VegasLimit:
     """Latency-gradient limit (the reference default, vegas windowed)."""
 
+    #: CommandRateLimiter hands this algorithm a window's samples at a time
+    #: (their mean RTT, the most in flight), as the reference's WindowedLimit
+    #: does for its vegas (``useWindowed``, on by default): a partition's
+    #: commands differ in cost tenfold (an empty ActivateJobs beside a
+    #: create), and the gradient of single samples against the fastest one
+    #: ever seen reads that difference as queueing and shrinks the limit to
+    #: 5-8 on an idle partition
+    windowed = True
+
     def __init__(self, initial: int = 20, min_limit: int = 1,
                  max_limit: int = 1000) -> None:
         self.limit = initial
@@ -71,6 +81,12 @@ class VegasLimit:
         if rtt_ms <= 0:
             return
         self._min_rtt = min(self._min_rtt, rtt_ms)
+        if in_flight * 2 < self.limit:
+            # not close to the limit (the reference's VegasLimit makes the
+            # same cut): a sample taken with the partition far from its
+            # limit says how long its commands take, not that the limit
+            # queues
+            return
         queue = self.limit * (1 - self._min_rtt / rtt_ms)
         alpha = 3 * math.log10(self.limit) + 1
         beta = 6 * math.log10(self.limit) + 1
@@ -81,6 +97,12 @@ class VegasLimit:
 
 
 LIMITS = {"fixed": FixedLimit, "aimd": AimdLimit, "vegas": VegasLimit}
+
+
+#: a window of samples is handed to a ``windowed`` algorithm once it is this
+#: old and holds this many (the reference's WindowedLimit: 1 s, 10 samples)
+WINDOW_MS = 1000
+WINDOW_SAMPLES = 10
 
 
 class CommandRateLimiter:
@@ -105,6 +127,14 @@ class CommandRateLimiter:
         self.timeout_ms = (timeout_ms if timeout_ms is not None
                            else getattr(self.algorithm, "timeout_ms", 10_000))
         self.in_flight: dict[int, int] = {}  # position → acquire time ms
+        # the open window of a windowed algorithm: samples, their RTTs'
+        # sum, the most in flight, whether one timed out; and when it opened
+        self._windowed = getattr(self.algorithm, "windowed", False)
+        self._window_samples = 0
+        self._window_rtt_sum = 0.0
+        self._window_max_in_flight = 0
+        self._window_dropped = False
+        self._window_opened = self.clock_millis()
         self.dropped_total = 0
         from zeebe_tpu.utils.metrics import REGISTRY
 
@@ -163,10 +193,30 @@ class CommandRateLimiter:
     def on_processed(self, position: int) -> None:
         started = self.in_flight.pop(position, None)
         if started is not None:
-            rtt = self.clock_millis() - started
+            now = self.clock_millis()
+            rtt = now - started
             # drop samples come only from in-flight RTTs exceeding the timeout
-            self.algorithm.on_sample(rtt, len(self.in_flight),
-                                     dropped=rtt > self.timeout_ms)
+            dropped = rtt > self.timeout_ms
+            if self._windowed:
+                self._window_samples += 1
+                self._window_rtt_sum += rtt
+                self._window_max_in_flight = max(self._window_max_in_flight,
+                                                 len(self.in_flight) + 1)
+                self._window_dropped = self._window_dropped or dropped
+                if (self._window_samples < WINDOW_SAMPLES
+                        or now - self._window_opened < WINDOW_MS):
+                    return
+                self.algorithm.on_sample(
+                    self._window_rtt_sum / self._window_samples,
+                    self._window_max_in_flight, dropped=self._window_dropped)
+                self._window_samples = 0
+                self._window_rtt_sum = 0.0
+                self._window_max_in_flight = 0
+                self._window_dropped = False
+                self._window_opened = now
+            else:
+                self.algorithm.on_sample(rtt, len(self.in_flight),
+                                         dropped=dropped)
             # the adaptive limit only moves on samples — update gauges here,
             # off the per-command ingress path
             self._m_limit.set(self.algorithm.limit)

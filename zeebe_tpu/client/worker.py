@@ -7,6 +7,7 @@ worker (clients/go/pkg/worker/jobPoller.go, jobDispatcher.go).
 
 from __future__ import annotations
 
+import queue
 import threading
 import time
 import traceback
@@ -41,6 +42,13 @@ class JobClient:
 class JobWorker:
     """Background polling worker with exponential empty-poll backoff.
 
+    Jobs are handled on ``HANDLER_THREADS`` threads of the worker's own
+    (reference: the Go worker's default ``Concurrency``; the Java client's
+    ``numJobWorkerExecutionThreads``), so a job does not wait behind the
+    handler of another job of the same activation; the poller asks for as
+    many jobs as ``max_jobs_active`` leaves room for and polls again at once
+    when a job was finished.
+
     ``auto_complete``: a handler return (no exception) completes the job with
     the handler's returned dict (or {}); an exception fails it with
     retries-1 (the Java client's default error behavior).
@@ -48,6 +56,8 @@ class JobWorker:
     ``stream_enabled``: use the StreamActivatedJobs push path instead of the
     ActivateJobs poll loop (reference: JobWorkerBuilderStep1.streamEnabled —
     jobs arrive as the broker creates them, no polling)."""
+
+    HANDLER_THREADS = 4
 
     def __init__(
         self,
@@ -74,6 +84,11 @@ class JobWorker:
         self.stream_enabled = stream_enabled
         self._running = False
         self._thread: threading.Thread | None = None
+        self._handlers: list[threading.Thread] = []
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        # guards the three counts; notified whenever a job was finished
+        self._finished = threading.Condition()
+        self._active = 0        # activated for this worker, not yet finished
         self.handled_count = 0
         self.failed_count = 0
 
@@ -82,42 +97,75 @@ class JobWorker:
         target = self._stream_loop if self.stream_enabled else self._poll_loop
         self._thread = threading.Thread(target=target, daemon=True,
                                         name=f"worker-{self.job_type}")
-        self._thread.start()
+        self._handlers = [
+            threading.Thread(target=self._handler_loop, daemon=True,
+                             name=f"worker-{self.job_type}-handler-{i}")
+            for i in range(self.HANDLER_THREADS)]
+        for t in (self._thread, *self._handlers):
+            t.start()
         return self
 
     def stop(self) -> None:
+        """Jobs a handler has in hand are finished (waited for up to 5 s);
+        jobs still queued are left to their activation timeout."""
         self._running = False
         call = getattr(self, "_call", None)
         if call is not None:
             call.cancel()
-        if self._thread is not None:
-            self._thread.join(timeout=5)
+        with self._finished:
+            self._finished.notify_all()
+        for _ in self._handlers:
+            self._jobs.put(None)
+        deadline = time.monotonic() + 5
+        for t in (self._thread, *self._handlers):
+            if t is not None:
+                t.join(timeout=max(0.0, deadline - time.monotonic()))
 
     def _poll_loop(self) -> None:
         backoff = self.poll_interval_s
-        job_client = JobClient(self.client)
         while self._running:
+            with self._finished:
+                room = self.max_jobs_active - self._active
+                if room <= 0:
+                    self._finished.wait(self.max_backoff_s)
+                    continue
+                finished = self.handled_count + self.failed_count
             try:
                 jobs = self.client.activate_jobs(
-                    self.job_type, max_jobs=self.max_jobs_active,
+                    self.job_type, max_jobs=room,
                     worker=self.worker_name, timeout_ms=self.timeout_ms,
                 )
             except Exception:
-                time.sleep(backoff)
-                backoff = min(backoff * 2, self.max_backoff_s)
+                jobs = []
+            if jobs:
+                backoff = self.poll_interval_s
+                self._accept(jobs)
                 continue
-            if not jobs:
-                time.sleep(backoff)
-                backoff = min(backoff * 2, self.max_backoff_s)
-                continue
-            backoff = self.poll_interval_s
-            for job in jobs:
-                if not self._running:
-                    return
+            # an empty (or refused) poll backs off, unless a job is finished
+            # meanwhile: the instance's next job is usually there by then
+            with self._finished:
+                woken = self._finished.wait_for(
+                    lambda: not self._running or finished
+                    != self.handled_count + self.failed_count, backoff)
+            backoff = (self.poll_interval_s if woken
+                       else min(backoff * 2, self.max_backoff_s))
+
+    def _accept(self, jobs) -> None:
+        with self._finished:
+            self._active += len(jobs)
+        for job in jobs:
+            self._jobs.put(job)
+
+    def _handler_loop(self) -> None:
+        job_client = JobClient(self.client)
+        while (job := self._jobs.get()) is not None:
+            if self._running:
                 self._dispatch(job_client, job)
+            with self._finished:
+                self._active -= 1
+                self._finished.notify_all()
 
     def _stream_loop(self) -> None:
-        job_client = JobClient(self.client)
         while self._running:
             try:
                 self._call, jobs = self.client.open_job_stream(
@@ -131,7 +179,7 @@ class JobWorker:
                 for job in jobs:
                     if not self._running:
                         return
-                    self._dispatch(job_client, job)
+                    self._accept([job])
             except Exception:
                 if not self._running:
                     return
@@ -144,9 +192,11 @@ class JobWorker:
                 job_client.complete(job, result if isinstance(result, dict) else {})
             else:
                 self.handler(job_client, job)
-            self.handled_count += 1
+            with self._finished:
+                self.handled_count += 1
         except Exception as exc:  # handler error → fail with retries-1
-            self.failed_count += 1
+            with self._finished:
+                self.failed_count += 1
             try:
                 job_client.fail(job, error_message=(
                     f"{type(exc).__name__}: {exc}\n{traceback.format_exc(limit=5)}"

@@ -33,6 +33,7 @@ float32 rounding exists anywhere on the device path.
 
 from __future__ import annotations
 
+import itertools
 import logging
 from collections import Counter
 from dataclasses import dataclass, field
@@ -1249,6 +1250,9 @@ class _PendingGroup:
     # device_elapsed by construction. t_build is the array build, which
     # runs after admission closed and before the first dispatch.
     t_admit: float = 0.0
+    # perf_counter when admission closed: the processor subtracts the moment
+    # each command became readable on the log (admit_wait)
+    admitted_at: float = 0.0
     t_build: float = 0.0
     device_elapsed: float = 0.0
     t_dispatch: float = 0.0
@@ -2785,20 +2789,23 @@ class KernelBackend:
         # over state nothing mutates until materialization, so everything it
         # derives from state alone is stable for the whole wave
         wave: dict = {}
-        head_cmd = None
-        for cmd in cmds:
-            if head_cmd is None:
-                head_cmd = cmd
-            adm = self._admit(cmd, instances, admitted_pis, wave)
-            if adm is None:
-                break
-            instances[adm.inst.idx] = adm.inst
-            if adm.inst.pi_key is not None and adm.inst.pi_key >= 0:
-                admitted_pis.add(adm.inst.pi_key)
-            admitted_pis.update(adm.inst.family_pis)
-            admitted.append(adm)
-            if len(admitted) >= self.max_group:
-                break
+        cmds = iter(cmds)
+        head_cmd = next(cmds, None)
+        if head_cmd is not None:
+            # only a probe that found a command is annotated: the pump's
+            # empty probes run every millisecond and would flood a trace
+            with phase_annotation("admit"):
+                for cmd in itertools.chain((head_cmd,), cmds):
+                    adm = self._admit(cmd, instances, admitted_pis, wave)
+                    if adm is None:
+                        break
+                    instances[adm.inst.idx] = adm.inst
+                    if adm.inst.pi_key is not None and adm.inst.pi_key >= 0:
+                        admitted_pis.add(adm.inst.pi_key)
+                    admitted_pis.update(adm.inst.family_pis)
+                    admitted.append(adm)
+                    if len(admitted) >= self.max_group:
+                        break
         if not admitted:
             if canary:
                 # the claimed canary slot never dispatched: un-claim it so
@@ -2829,7 +2836,8 @@ class KernelBackend:
             return None
         pg = _PendingGroup(admitted)
         pg.canary = canary
-        pg.t_admit = _time.perf_counter() - t0
+        pg.admitted_at = _time.perf_counter()
+        pg.t_admit = pg.admitted_at - t0
         self._start_kernel(pg)
         return pg
 

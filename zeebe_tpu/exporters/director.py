@@ -353,6 +353,19 @@ class ExporterDirector:
         self._m_events = REGISTRY.counter(
             "exporter_events_total", "records visited by the director",
             ("partition",)).labels(pid)
+        # always on: the director's time for the records of the shared head
+        # pass (read from the stream, offered to every exporter, bookkeeping),
+        # observed once a pass as that many records at the pass's mean.
+        # Named into the partition pipeline's family, whose stages a
+        # deployment's dashboards and the benchmark read side by side
+        self._m_export = REGISTRY.histogram(
+            "stream_processor_pipeline_export",
+            "seconds per record the exporter director spent in its head "
+            "pass (the read from the stream, every exporter's export(), the "
+            "bookkeeping); a pass's records are observed at their mean",
+            ("partition",),
+            buckets=(0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005,
+                     0.001, 0.0025, 0.005, 0.01, 0.1, 1.0)).labels(pid)
         # exporter_last_exported_position is owned by the broker metrics
         # (node+partition labels) — not re-registered here
         self._m_last_updated = REGISTRY.gauge(
@@ -454,6 +467,7 @@ class ExporterDirector:
         eligible = [c for c in self.containers
                     if not c.paused and c.next_position >= self._next_position]
         count = 0
+        t_pass = _time_mod.perf_counter()
         for logged in self.stream.new_reader(self._next_position):
             if limit is not None and logged.position > limit:
                 break
@@ -466,6 +480,7 @@ class ExporterDirector:
             count += 1
             if count >= max_records:
                 break
+        self._m_export.observe_many(_time_mod.perf_counter() - t_pass, count)
         if count or max_catch_up:
             self._m_last_updated.set(
                 min((c.position for c in self.containers), default=-1))

@@ -512,6 +512,10 @@ class LogStream:
         # parallel arrays: batch first positions (sorted) and journal indexes
         self._batch_positions: list[int] = []
         self._batch_indexes: list[int] = []
+        # third parallel array: ``time.perf_counter()`` at which the batch
+        # became readable here (None for batches found on disk at open) —
+        # what the stream processor's admit_wait histogram subtracts
+        self._batch_readable_at: list[float | None] = []
         # decoded-batch LRU keyed by journal index: the processing reader, the
         # kernel group scanner, and exporters all walk the same recent suffix
         # interleaved, so a single-slot cache thrashes (every read re-decodes
@@ -532,12 +536,14 @@ class LogStream:
         (call after external journal mutation, e.g. Raft truncation)."""
         self._batch_positions.clear()
         self._batch_indexes.clear()
+        self._batch_readable_at.clear()
         self._batch_cache.clear()
         self._batch_has_commands.clear()
         for index, asqn in self.journal.entries_meta():
             if asqn >= 0:
                 self._batch_positions.append(asqn)
                 self._batch_indexes.append(index)
+                self._batch_readable_at.append(None)
         if self._batch_positions:
             last_batch = self._read_batch_at(self._batch_indexes[-1])
             self._next_position = last_batch[-1].position + 1
@@ -563,6 +569,7 @@ class LogStream:
     def _on_appended(self, first_position: int, journal_index: int) -> None:
         self._batch_positions.append(first_position)
         self._batch_indexes.append(journal_index)
+        self._batch_readable_at.append(time.perf_counter())
 
     def _cache_batch(self, journal_index: int, batch: list[LoggedRecord]) -> None:
         _evict_oldest_half(self._batch_cache, self._batch_cache_limit)
@@ -636,6 +643,13 @@ class LogStream:
         from bisect import bisect_right
 
         return bisect_right(self._batch_positions, position) - 1
+
+    def readable_at(self, position: int) -> float | None:
+        """``time.perf_counter()`` at which the batch holding ``position``
+        was appended to this stream in this process; None for a batch that
+        was already on disk at open (replay has no such moment)."""
+        slot = self._batch_slot_for(position)
+        return self._batch_readable_at[slot] if slot >= 0 else None
 
     def read_at_or_after(self, position: int) -> LoggedRecord | None:
         """First record with record.position >= position, or None."""

@@ -374,8 +374,8 @@ def observe_compile(bucket: str, seconds: float) -> str:
 # ``unattributed`` they fit the ten lines the benchmark's idle-gap table
 # keeps, and its trace reader takes host events by this prefix.
 PHASE_PREFIX = "zeebe.kernel_chunk."
-PHASES = ("build", "dispatch", "fetch", "unpack", "materialize", "append",
-          "flush", "side_effects", "shadow")
+PHASES = ("admit", "build", "dispatch", "fetch", "unpack", "materialize",
+          "append", "flush", "side_effects", "shadow")
 _PHASE_NAMES = {phase: PHASE_PREFIX + phase for phase in PHASES}
 
 
@@ -383,9 +383,10 @@ def phase_annotation(phase: str):
     """``jax.profiler.TraceAnnotation`` over one phase of a kernel group, so
     that any ``jax.profiler`` capture — the tracer on or off — shows what the
     pump thread was in beside the device's own events. Inert (one atomic
-    read in the profiler) while no capture is recording. Only work that
-    follows an admitted group is annotated: the pump's empty admission
-    probes run every millisecond and would flood a trace."""
+    read in the profiler) while no capture is recording. Only a group's
+    own work is annotated, from ``admit`` (an admission probe that found a
+    command) on: the pump's empty probes run every millisecond and would
+    flood a trace."""
     import jax
 
     return jax.profiler.TraceAnnotation(_PHASE_NAMES[phase])

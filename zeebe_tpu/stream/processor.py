@@ -259,6 +259,24 @@ class StreamProcessor:
                           "device_fetch", "device_unpack", "materialize",
                           "append", "flush", "side_effects")
         }
+        # the backlog nothing else measures: one observation a COMMAND (not
+        # a group) of how long it lay readable on this partition's log
+        # before a group admitted it
+        self._m_admit_wait = REGISTRY.histogram(
+            "stream_processor_pipeline_admit_wait",
+            "seconds per command between the moment it was readable on the "
+            "partition's log and the close of the admission of the kernel "
+            "group that took it",
+            ("partition",)).labels(partition_label)
+        # which buckets a deployment reaches: kernel groups by padded size
+        # (instances x tokens) and commands admitted; children resolved on
+        # first use, the set of buckets is small and closed
+        self._m_groups_by_bucket = REGISTRY.counter(
+            "kernel_groups_by_bucket_total",
+            "committed kernel groups by device bucket (I<instances>xT<tokens>"
+            ", mesh for a group the mesh runner ran) and commands admitted",
+            ("partition", "bucket", "commands"))
+        self._groups_by_bucket: dict = {}
         # dispatch-overlap receipt (ISSUE 13): fraction of a kernel group's
         # wall time during which the host did useful work (the previous
         # group's deferred side effects) while a dispatched device chunk was
@@ -771,6 +789,7 @@ class StreamProcessor:
             pipeline["device_fetch"].observe(pending.t_fetch)
             pipeline["device_unpack"].observe(pending.t_unpack)
         pipeline["materialize"].observe(pending.t_materialize)
+        self._observe_admission(pending, cmds)
         self._m_batched.inc(len(cmds))
         elapsed = _time.perf_counter() - group_start
         self._m_latency.observe(elapsed)
@@ -804,8 +823,29 @@ class StreamProcessor:
                               device_fetch=pending.t_fetch,
                               device_unpack=pending.t_unpack)
             self._trace_group(cmds, elapsed, stages, notes,
-                              device_get=pending.t_device_get)
+                              device_get=pending.t_device_get,
+                              admitted_at=pending.admitted_at)
         return len(cmds)
+
+    def _observe_admission(self, pending, cmds) -> None:
+        """Always on, for one committed group: every command's wait on the
+        log before admission into ``stream_processor_pipeline_admit_wait``
+        and the group into ``kernel_groups_by_bucket_total``. A command that
+        was on disk at open has no readable moment and is not observed."""
+        admitted_at = pending.admitted_at
+        readable_at = self.log_stream.readable_at
+        observe = self._m_admit_wait.observe
+        for cmd in cmds:
+            readable = readable_at(cmd.position)
+            if readable is not None:
+                observe(max(0.0, admitted_at - readable))
+        key = (pending.I, pending.T, len(cmds))
+        child = self._groups_by_bucket.get(key)
+        if child is None:
+            bucket = "mesh" if pending.mesh else f"I{pending.I}xT{pending.T}"
+            child = self._groups_by_bucket[key] = self._m_groups_by_bucket.labels(
+                str(self.log_stream.partition_id), bucket, str(len(cmds)))
+        child.inc()
 
     def _trace_speculative(self, first_pos: int, t_disp: float,
                            outcome: str) -> None:
@@ -966,7 +1006,8 @@ class StreamProcessor:
     def _trace_group(self, cmds: list[LoggedRecord], elapsed: float,
                      stages: dict[str, float],
                      notes: list[tuple] | None,
-                     device_get: float = 0.0) -> None:
+                     device_get: float = 0.0,
+                     admitted_at: float = 0.0) -> None:
         """Spans for one kernel group: a group span with one child per
         pipeline stage (the per-trace view of the stream_processor_pipeline_*
         histograms), a backlog-wait span per sampled command (append → wave
@@ -1000,6 +1041,18 @@ class StreamProcessor:
                          if stage == "device_fetch" else None)
                 tracer.emit(group_trace, f"processor.stage.{stage}", dur, pid,
                             parent="processor.kernel_group", attrs=attrs)
+            # the numbers the admit_wait histogram observed, one span a
+            # command, each at its real interval (it ended when the group's
+            # admission closed)
+            admitted_us = anchor_us - int((now - admitted_at) * 1e6)
+            for cmd in cmds:
+                readable = self.log_stream.readable_at(cmd.position)
+                if readable is not None:
+                    wait = max(0.0, admitted_at - readable)
+                    tracer.emit(group_trace, "processor.stage.admit_wait",
+                                wait, pid, parent="processor.kernel_group",
+                                attrs={"position": cmd.position},
+                                start_us=admitted_us - int(wait * 1e6))
         share = elapsed / len(cmds)
         by_position = ({note[1]: note for note in notes} if notes else {})
         for cmd in cmds:

@@ -171,6 +171,17 @@ class Histogram(_Metric):
             self.sum += value
             self.count += 1
 
+        def observe_many(self, total: float, n: int) -> None:
+            """``n`` observations that took ``total`` together, each counted
+            at their mean: one write for a loop too hot to time item by
+            item. Sum and count stay exact; the buckets see the mean."""
+            if n <= 0:
+                return
+            idx = bisect.bisect_left(self.parent.buckets, total / n)
+            self.bucket_counts[idx] += n
+            self.sum += total
+            self.count += n
+
     def _child_cls(self):
         return Histogram.Child
 
