@@ -130,6 +130,24 @@ def _compile_run_collect(set_name, instances, table_sets, one_chip, mesh):
                              config=tables.kernel_config).compile()
 
 
+def _compile_run_collect_packed(set_name, instances, table_sets, one_chip, mesh):
+    """The served path's entry: the group's state as one int32 buffer, the
+    geometry static (``KernelBackend._run_chunk``)."""
+    import jax
+
+    from zeebe_tpu.ops.automaton import packed_state_layout, run_collect_packed
+
+    tables = table_sets[set_name]
+    dt, state = _one_chip_args(tables, instances, one_chip)
+    geometry = (instances, state["elem"].shape[0], tables.num_slots,
+                tables.max_elements)
+    packed = jax.ShapeDtypeStruct((packed_state_layout(geometry)[1],), np.int32,
+                                  sharding=one_chip)
+    return run_collect_packed.lower(dt, packed, geometry=geometry,
+                                    n_steps=CHUNK_STEPS,
+                                    config=tables.kernel_config).compile()
+
+
 def _compile_step(set_name, instances, table_sets, one_chip, mesh):
     from zeebe_tpu.ops.automaton import step
 
@@ -217,6 +235,14 @@ def _compile_mesh_collect(set_name, instances, table_sets, one_chip, mesh):
                  id="run_collect-mixed9-I64"),
     pytest.param(_compile_run_collect, "mixed9", MAX_GROUP,
                  id="run_collect-mixed9-I2048"),
+    pytest.param(_compile_run_collect_packed, "one_task", SMALL_GROUP,
+                 id="run_collect_packed-one_task-I64"),
+    pytest.param(_compile_run_collect_packed, "one_task", MAX_GROUP,
+                 id="run_collect_packed-one_task-I2048"),
+    pytest.param(_compile_run_collect_packed, "mixed9", SMALL_GROUP,
+                 id="run_collect_packed-mixed9-I64"),
+    pytest.param(_compile_run_collect_packed, "mixed9", MAX_GROUP,
+                 id="run_collect_packed-mixed9-I2048"),
     pytest.param(_compile_step, "one_task", MAX_GROUP,
                  id="step-one_task-I2048"),
     pytest.param(_compile_run_to_completion, "one_task", MAX_GROUP,

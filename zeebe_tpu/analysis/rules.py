@@ -804,6 +804,7 @@ class StorageIoDisciplineRule(Rule):
 _KERNEL_RESULT_CALLS = (
     "zeebe_tpu.ops.automaton.unpack_events",
     "zeebe_tpu.ops.automaton.run_collect",
+    "zeebe_tpu.ops.automaton.run_collect_packed",
     "jax.device_get",
 )
 
@@ -811,11 +812,12 @@ _KERNEL_RESULT_CALLS = (
 class KernelResultCommitDisciplineRule(Rule):
     """Kernel group results may only enter the group transaction through
     the validation/shadow seam (ISSUE 15): inside ``engine/`` and
-    ``stream/`` the device-result primitives — ``run_collect`` dispatch,
-    ``jax.device_get`` fetch, ``unpack_events`` decode — are legal ONLY in
-    the registered seam functions of ``engine/kernel_backend.py``
-    (``_dispatch_first_chunk`` / ``_dispatch_chunk`` /
-    ``_complete_device_run`` / ``_fetch_rows`` / ``_shadow_execute``), whose
+    ``stream/`` the device-result primitives — ``run_collect`` /
+    ``run_collect_packed`` dispatch, ``jax.device_get`` fetch,
+    ``unpack_events`` decode — are legal ONLY in the registered seam
+    functions of ``engine/kernel_backend.py`` (``_run_chunk``, the one call
+    of the program, under ``_dispatch_first_chunk`` / ``_dispatch_chunk`` /
+    ``_shadow_execute``; ``_complete_device_run`` / ``_fetch_rows``), whose
     results flow to materialization
     exclusively via ``finish_group``'s shadow-verification gate. A direct
     fetch+decode anywhere else is a path for silently-corrupted device
@@ -832,8 +834,7 @@ class KernelResultCommitDisciplineRule(Rule):
     DEFAULT_SCOPE_PREFIXES = ("zeebe_tpu/engine/", "zeebe_tpu/stream/")
     SEAM_MODULE = "zeebe_tpu/engine/kernel_backend.py"
     DEFAULT_SEAM_SCOPES = (
-        "KernelBackend._dispatch_first_chunk",
-        "KernelBackend._dispatch_chunk",
+        "KernelBackend._run_chunk",
         "KernelBackend._complete_device_run",
         "KernelBackend._fetch_rows",
         "KernelBackend._shadow_execute",

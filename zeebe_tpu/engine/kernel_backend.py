@@ -1218,11 +1218,17 @@ class _PendingGroup:
     # chunk-count field); mesh groups report 0 (the runner owns chunking)
     chunks_run: int = 0
     mesh: bool = False
+    # the group's host-filled state: one flat int32 buffer (what the
+    # single-device path uploads) and the named views into it (what the
+    # mesh runner takes)
+    packed: Any = None
     arrays: dict | None = None
+    # host (numpy) leaves handed to the first chunk's jit call, one
+    # host→device transfer each (stream_processor_pipeline_device_uploads)
+    uploads: int = 0
     I: int = 0
     T: int = 0
     tables: Any = None
-    config: Any = None
     dt: Any = None
     dev: Any = None
     bucket: Any = None
@@ -1243,9 +1249,9 @@ class _PendingGroup:
     corrupt_tokens: list = field(default_factory=list)
     # stage wall times (seconds), observed by the stream processor.
     # Single-device groups split device_elapsed into its three parts:
-    # t_dispatch (every run_collect call: the jit call converts and uploads
-    # the host-filled arrays), t_fetch (device→host, the watchdog's thread
-    # hop included; t_device_get is the part inside jax.device_get alone)
+    # t_dispatch (every run_collect_packed call: the first converts and
+    # uploads the host-filled buffer), t_fetch (device→host, the watchdog's
+    # thread hop included; t_device_get is the part inside jax.device_get alone)
     # and t_unpack (the host decode between them) — they add up to
     # device_elapsed by construction. t_build is the array build, which
     # runs after admission closed and before the first dispatch.
@@ -1343,9 +1349,6 @@ class KernelBackend:
         #: ran: the device holding each first chunk's result → group count
         self.groups_by_device: Counter = Counter()
         self.shadow_by_device: Counter = Counter()
-        # per-I-bucket cached zero planes for _dispatch_first_chunk (jax
-        # arrays are immutable, so sharing across groups is safe)
-        self._zero_state: dict = {}
         # compile seam (observability/profiler.py): (bucket, device) pairs
         # whose first dispatch — the one that traces + lowers + compiles (or
         # loads the persistent-cache executable) — was already timed into
@@ -2129,11 +2132,19 @@ class KernelBackend:
         return p
 
     def _build_group_arrays(self, admitted: list[_Admitted]):
-        """Host (numpy) arrays for one admitted group, padded to the shape
-        bucket: (arrays dict, I, T), or None when the geometry exceeds the
-        event-packing bounds. Shared by the single-device path and the
-        mesh-runner path (which treats the group as one shard block)."""
-        from zeebe_tpu.ops.automaton import PACK_MAX_ELEMENTS, PACK_MAX_TOKENS
+        """One admitted group's state on the host, padded to the shape
+        bucket: (packed, arrays dict, I, T), or None when the geometry
+        exceeds the event-packing bounds. ``packed`` is the ONE flat int32
+        buffer the single-device path uploads (``run_collect_packed``);
+        ``arrays`` are its planes as named views into it (``done`` as 0/1),
+        of which the mesh-runner path takes the eight the host fills (it
+        treats the group as one shard block)."""
+        from zeebe_tpu.ops.automaton import (
+            PACK_MAX_ELEMENTS,
+            PACK_MAX_TOKENS,
+            packed_state_layout,
+            packed_state_views,
+        )
 
         tables = self.registry.tables
         insts = [a.inst for a in admitted]
@@ -2172,15 +2183,15 @@ class KernelBackend:
                            "bounds; falling back", T, E)
             return None
 
-        elem = np.full(T, -1, np.int32)
-        phase = np.zeros(T, np.int32)
-        inst_arr = np.zeros(T, np.int32)
-        def_of = np.zeros(I, np.int32)
-        var_slots = np.zeros((I, S, 2), np.int32)
-        join_counts = np.zeros((I, E), np.int32)
-        mi_left = np.zeros((I, E), np.int32)
-        done = np.zeros(I, np.bool_)
-        done[n_real:] = True  # padding rows must never report newly_done
+        geometry = (I, T, S, E)
+        _planes, length = packed_state_layout(geometry)
+        packed = np.zeros(length, np.int32)
+        views = packed_state_views(packed, geometry)
+        elem, phase, inst_arr = views["elem"], views["phase"], views["inst"]
+        def_of, var_slots = views["def_of"], views["var_slots"]
+        join_counts, mi_left = views["join_counts"], views["mi_left"]
+        elem[:] = -1
+        views["done"][n_real:] = 1  # padding rows must never report newly_done
 
         slot = 0
         for i in insts:
@@ -2205,12 +2216,7 @@ class KernelBackend:
                     phase[slot] = tok.phase
                     inst_arr[slot] = i.idx
                     slot += 1
-        arrays = {
-            "elem": elem, "phase": phase, "inst": inst_arr, "def_of": def_of,
-            "var_slots": var_slots, "join_counts": join_counts,
-            "mi_left": mi_left, "done": done,
-        }
-        return arrays, I, T
+        return packed, views, I, T
 
     def _start_kernel(self, pg: "_PendingGroup") -> None:
         """Stage 1 of the split device run: build the group arrays and
@@ -2228,7 +2234,7 @@ class KernelBackend:
             pg.failed = True
             pg.fail_reason = "geometry-bounds"
             return
-        pg.arrays, pg.I, pg.T = built
+        pg.packed, pg.arrays, pg.I, pg.T = built
         pg.tables = self.registry.tables
         if self.mesh_runner is not None:
             pg.mesh = True
@@ -2365,56 +2371,17 @@ class KernelBackend:
         except Exception:  # noqa: BLE001
             pass
 
-    def _group_state(self, pg: "_PendingGroup", dev) -> dict:
-        """The group's initial kernel state dict: the host-filled arrays
-        plus cached zero planes. Must be called inside ``_device_ctx(dev)``
-        — the zero planes must materialize in the placement context, or a
-        routed accelerator's cache entry would hold default-device arrays
-        and pay the transfer the cache exists to eliminate.
-
-        Fresh per-group zero planes are IDENTICAL every group: cache the
-        immutable device constants per (device, I) bucket — each jnp.zeros
-        call otherwise costs a dispatch (~0.1ms × 5 per group adds up at
-        small group sizes); the key carries the device because the link
-        router alternates a bucket between host and accelerator and planes
-        cached on one device must not leak into a group running on the
-        other. The real (host-filled) arrays convert inside the jit call
-        itself. Shared by the dispatch path and the shadow oracle — both
-        must start from byte-identical state."""
-        import jax.numpy as jnp
-
-        I = pg.I
-        arrays = pg.arrays
-        zeros = self._zero_state.get((dev, I))
-        if zeros is None:
-            zeros = {
-                "incident": jnp.zeros(I, jnp.bool_),
-                "transitions": jnp.zeros((), jnp.int32),
-                "jobs_created": jnp.zeros((), jnp.int32),
-                "completed": jnp.zeros((), jnp.int32),
-                "overflow": jnp.zeros((), jnp.bool_),
-            }
-            self._zero_state[(dev, I)] = zeros
-        return {
-            "elem": arrays["elem"],
-            "phase": arrays["phase"],
-            "inst": arrays["inst"],
-            "def_of": arrays["def_of"],
-            "var_slots": arrays["var_slots"],
-            "join_counts": arrays["join_counts"],
-            "mi_left": arrays["mi_left"],
-            "done": arrays["done"],
-            **zeros,
-        }
-
     def _dispatch_first_chunk(self, pg: "_PendingGroup") -> None:
-        from zeebe_tpu.ops.automaton import run_collect
+        import jax
 
-        dev, I = pg.dev, pg.I
-        pg.config = pg.tables.kernel_config
+        dev = pg.dev
         pg.dt = self.registry.device_tables_for(dev)
+        args = (pg.dt, pg.packed)
+        # what the jit call below converts and uploads, one transfer a leaf:
+        # the host (numpy) leaves among its arguments, counted, not assumed
+        pg.uploads = sum(isinstance(leaf, np.ndarray)
+                         for leaf in jax.tree_util.tree_leaves(args))
         with _device_ctx(dev):
-            state = self._group_state(pg, dev)
             # JAX async dispatch: the call returns with the device still
             # computing; the first host transfer (in _complete_device_run)
             # is the synchronization point
@@ -2430,25 +2397,33 @@ class KernelBackend:
                 import time as _time
 
                 t_compile = _time.perf_counter()
-            pg.run = run_collect(pg.dt, state, n_steps=self.chunk_steps,
-                                 config=pg.config)
+            pg.run = self._run_chunk(pg, *args)
             if first_dispatch:
                 self._compiles_seen.add(compile_key)
                 self._observe_compile(pg.I, pg.T,
                                       _time.perf_counter() - t_compile)
         self.groups_by_device[_device_of(pg.run[1])] += 1
 
+    def _run_chunk(self, pg: "_PendingGroup", dt, packed):
+        """One chunk of the group's program on the device ``dt`` lives on:
+        (carry, event rows). ``packed`` is the host-filled buffer (first
+        chunk: the group's one upload) or the previous chunk's carry."""
+        from zeebe_tpu.ops.automaton import run_collect_packed
+
+        tables = pg.tables
+        return run_collect_packed(
+            dt, packed,
+            geometry=(pg.I, pg.T, tables.num_slots, tables.max_elements),
+            n_steps=self.chunk_steps, config=tables.kernel_config)
+
     def _dispatch_chunk(self, pg: "_PendingGroup", state):
         """One further chunk off the device-side carry (prefetched or next),
         its call timed into ``t_dispatch``."""
         import time as _time
 
-        from zeebe_tpu.ops.automaton import run_collect
-
         t0 = _time.perf_counter()
         with _device_ctx(pg.dev), phase_annotation("dispatch"):
-            run = run_collect(pg.dt, state, n_steps=self.chunk_steps,
-                              config=pg.config)
+            run = self._run_chunk(pg, pg.dt, state)
         pg.t_dispatch += _time.perf_counter() - t0
         return run
 
@@ -2620,7 +2595,7 @@ class KernelBackend:
         corruption model), which is what the gate proves."""
         import jax
 
-        from zeebe_tpu.ops.automaton import run_collect, unpack_events
+        from zeebe_tpu.ops.automaton import unpack_events
 
         from zeebe_tpu.utils import backend
 
@@ -2632,7 +2607,6 @@ class KernelBackend:
         if host_dev == backend.devices()[0]:
             host_dev = None
         dt = self.registry.device_tables_for(host_dev)
-        config = pg.tables.kernel_config
         chunk = self.chunk_steps
         T, I = pg.T, pg.I
         FO = pg.tables.out_target.shape[2]
@@ -2640,8 +2614,7 @@ class KernelBackend:
         rows: list = []
         max_chunks = max(1, self.max_steps // chunk)
         with _device_ctx(host_dev):
-            state = self._group_state(pg, host_dev)
-            run = run_collect(dt, state, n_steps=chunk, config=config)
+            run = self._run_chunk(pg, dt, pg.packed)
         self.shadow_by_device[_device_of(run[1])] += 1
         for k in range(max_chunks):
             carry, packed = run
@@ -2657,7 +2630,7 @@ class KernelBackend:
                 return steps, rows
             if k + 1 < max_chunks:
                 with _device_ctx(host_dev):
-                    run = run_collect(dt, carry, n_steps=chunk, config=config)
+                    run = self._run_chunk(pg, dt, carry)
         # the oracle did not quiesce: the group is genuinely pathological —
         # raise so the caller abandons it (sequential host re-execution)
         raise RuntimeError(

@@ -38,7 +38,8 @@ job-worker path drives the kernel.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import math
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -121,9 +122,11 @@ class DeviceTables:
         )
 
 
+# every jit call flattens its tables: the field names are read once, here
+_TABLE_FIELDS = tuple(f.name for f in dataclasses.fields(DeviceTables))
 jax.tree_util.register_pytree_node(
     DeviceTables,
-    lambda t: (tuple(getattr(t, f.name) for f in dataclasses.fields(t)), None),
+    lambda t: (tuple(getattr(t, name) for name in _TABLE_FIELDS), None),
     lambda _, children: DeviceTables(*children),
 )
 
@@ -768,6 +771,80 @@ def run_collect(tables: DeviceTables, state: dict, n_steps: int = 16, config=Non
     state, packed, _, _ = jax.lax.while_loop(
         cond, body, (state, out0, jnp.int32(0), jnp.bool_(True)))
     return state, packed
+
+
+#: a group's state planes in the order they ride its packed int32 buffer
+#: (shape letters: I instances, T tokens, S variable slots, E elements)
+_PACKED_PLANES = (
+    ("elem", "T"), ("phase", "T"), ("inst", "T"), ("def_of", "I"),
+    ("var_slots", "IS2"), ("join_counts", "IE"), ("mi_left", "IE"),
+    ("done", "I"), ("incident", "I"), ("transitions", ""),
+    ("jobs_created", ""), ("completed", ""), ("overflow", ""),
+)
+#: bool in the kernel state, 0/1 in the buffer. A tuple: the casts are traced
+#: in this order, and a set's order changes with the process's hash seed, the
+#: program's text with it, and with that its key in the persistent cache
+_PACKED_BOOL_PLANES = ("done", "incident", "overflow")
+
+
+@lru_cache(maxsize=64)  # a deployment reaches a handful of geometries
+def packed_state_layout(geometry: tuple) -> tuple:
+    """((name, start, end, shape) of every plane, total int32 words) of one
+    group's packed state; ``geometry`` is (instances, tokens, variable
+    slots, elements)."""
+    sizes = dict(zip("ITSE", geometry), **{"2": 2})
+    planes, offset = [], 0
+    for name, letters in _PACKED_PLANES:
+        shape = tuple(sizes[letter] for letter in letters)
+        end = offset + math.prod(shape)
+        planes.append((name, offset, end, shape))
+        offset = end
+    return tuple(planes), offset
+
+
+def packed_state_views(buffer, geometry: tuple) -> dict:
+    """Every state plane as a named, shaped slice of the flat int32
+    ``buffer``, offsets fixed by ``geometry``. On a numpy buffer the slices
+    are views (the host fills a group's state through them, no copy); on a
+    traced array they are static slices inside the compiled program — one
+    function, so the two sides cannot disagree about an offset."""
+    planes, length = packed_state_layout(geometry)
+    if buffer.shape != (length,):
+        raise ValueError(f"packed state of shape {buffer.shape} does not fit "
+                         f"geometry {geometry} ({length} words)")
+    # a 1-D plane is its slice: the reshape would be a second view a plane
+    # on the host's build path, once a group
+    return {name: (buffer[start:end] if len(shape) == 1
+                   else buffer[start:end].reshape(shape))
+            for name, start, end, shape in planes}
+
+
+def unpack_state(packed: jax.Array, geometry: tuple) -> dict:
+    """The kernel state dict a packed buffer holds (bool planes cast back)."""
+    state = packed_state_views(packed, geometry)
+    for name in _PACKED_BOOL_PLANES:
+        state[name] = state[name].astype(jnp.bool_)
+    return state
+
+
+def pack_state(state: dict) -> jax.Array:
+    """Inverse of :func:`unpack_state`: one flat int32 buffer."""
+    return jnp.concatenate([state[name].astype(jnp.int32).reshape(-1)
+                            for name, _ in _PACKED_PLANES])
+
+
+@partial(jax.jit, static_argnames=("geometry", "n_steps", "config"))
+def run_collect_packed(tables: DeviceTables, packed: jax.Array, geometry: tuple,
+                       n_steps: int = 16, config=None):
+    """:func:`run_collect` for the served path, with the group's whole state
+    as ONE int32 buffer on both sides of the call: the first chunk's buffer
+    is host-filled (one upload where the state dict's leaves were one each),
+    a further chunk's is the previous call's carry, still on the device — the
+    same compiled program either way, and two output buffers a call instead
+    of fourteen. Returns (packed', events); events as ``run_collect``'s."""
+    state, events = run_collect(tables, unpack_state(packed, geometry),
+                                n_steps=n_steps, config=config)
+    return pack_state(state), events
 
 
 @partial(jax.jit, static_argnames=("max_steps", "auto_jobs", "config"))

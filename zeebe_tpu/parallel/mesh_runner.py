@@ -171,7 +171,8 @@ class MeshKernelRunner:
         join_counts = shard_arrays("join_counts", 0)
         mi_left = shard_arrays("mi_left", 0)
         # padding instances are done upfront so they never report newly_done
-        done = shard_arrays("done", True)
+        # (a backend's ``done`` plane is a 0/1 int32 view of its packed buffer)
+        done = shard_arrays("done", True).astype(np.bool_)
 
         mesh = self.mesh
         specs = state_specs()

@@ -259,6 +259,15 @@ class StreamProcessor:
                           "device_fetch", "device_unpack", "materialize",
                           "append", "flush", "side_effects")
         }
+        # not a time: the host→device transfers a single-device group's
+        # first jit call made (its numpy arguments); further chunks run off
+        # the device-side carry and upload nothing
+        self._m_device_uploads = REGISTRY.histogram(
+            "stream_processor_pipeline_device_uploads",
+            "host (numpy) arrays handed to the first device call of a kernel "
+            "group, one host-to-device transfer each; single-device groups "
+            "only",
+            ("partition",), buckets=(0, 1, 2, 4, 8, 16)).labels(partition_label)
         # the backlog nothing else measures: one observation a COMMAND (not
         # a group) of how long it lay readable on this partition's log
         # before a group admitted it
@@ -788,6 +797,7 @@ class StreamProcessor:
             pipeline["device_dispatch"].observe(pending.t_dispatch)
             pipeline["device_fetch"].observe(pending.t_fetch)
             pipeline["device_unpack"].observe(pending.t_unpack)
+            self._m_device_uploads.observe(pending.uploads)
         pipeline["materialize"].observe(pending.t_materialize)
         self._observe_admission(pending, cmds)
         self._m_batched.inc(len(cmds))

@@ -3,7 +3,7 @@
 The automaton kernel is ONE XLA program; *where* a group of commands runs is
 a deployment decision that depends on the host↔accelerator link as much as
 on the program: every group pays a fixed number of small transfers (its
-arrays up, its packed event rows back), and for a serving-sized group the
+packed state up, its packed event rows back), and for a serving-sized group the
 per-transfer latency floor, not the bandwidth, is what counts against the
 same program compiled for the host XLA backend.
 
@@ -36,11 +36,11 @@ class BackendRouter:
     times back so the host-cost model tracks reality.
     """
 
-    #: transfers per group on the accelerator path: the group arrays upload
-    #: (elem/phase/inst/def_of/var_slots/join_counts/done) plus the typical
-    #: two chunk fetches of the packed event tensor
-    UPLOADS_PER_GROUP = 7
-    FETCHES_PER_GROUP = 2
+    #: transfers per group on the accelerator path: the group's state goes
+    #: up as one packed buffer (``run_collect_packed``) and its event rows
+    #: come back in one fetch (a served group quiesces inside its first chunk)
+    UPLOADS_PER_GROUP = 1
+    FETCHES_PER_GROUP = 1
     #: below this predicted link cost the accelerator is effectively local
     #: and wins by default (host EMA not yet seated)
     LOCAL_LINK_S = 2e-3
