@@ -11,8 +11,8 @@ public gRPC client (``ZeebeTpuClient``, ``JobWorker``):
      CPU was asked for explicitly — a rehearsal, which never ends with a result)
   2. one_task: 10,000 instances (BASELINE.json configs[0]) created from several
      client threads, every job completed by a JobWorker
-  3. mixed9: bench.mixed_definitions() plus an embedded sub-process, deployed
-     together, 250 instances each with ``x`` drawn from ``--seed``
+  3. mixed9: ``workloads.mixed_definitions()`` plus an embedded sub-process,
+     deployed together, 250 instances each with ``x`` drawn from ``--seed``
   4. verdict: every kernel group was shadow-verified against the host (CPU)
      oracle with zero mismatches, none failed or was contained, the health
      ladder never left HEALTHY, every group ran on the TPU, and a fresh
@@ -84,9 +84,9 @@ def mixed_definitions() -> list:
     """The nine definitions deployed together: exclusive gateways with FEEL
     conditions (stack VM), two- and three-way fork/joins (sort under cond),
     an embedded sub-process (scope reduction), ragged task chains."""
-    import bench
+    from zeebe_tpu.testing import workloads
 
-    return bench.mixed_definitions() + [embedded_subprocess()]
+    return workloads.mixed_definitions() + [embedded_subprocess()]
 
 
 def job_types_of(models) -> list[str]:
@@ -555,10 +555,10 @@ def served_one_chip(args, out_dir: Path, router, platform: str) -> None:
     retry = Retrying(args.seed)
     served = Served(out_dir / "data", partitions=3, tally=tally)
     try:
-        import bench
+        from zeebe_tpu.testing.workloads import one_task
 
         t0 = time.monotonic()
-        result = run_load(served, [bench.one_task()],
+        result = run_load(served, [one_task()],
                           one_task_plan(args.instances), tally, retry,
                           client_threads=8, workers_per_type=8,
                           timeout_s=args.phase_timeout)
@@ -688,7 +688,7 @@ def mesh_parity(args, n_shards: int) -> None:
 def served_mesh(args, out_dir: Path, n_shards: int) -> None:
     """(B): 1 broker, 4 partitions — the broker builds the mesh runner itself
     from the attached devices."""
-    import bench
+    from zeebe_tpu.testing.workloads import one_task
 
     tally = Tally()
     retry = Retrying(args.seed)
@@ -698,7 +698,7 @@ def served_mesh(args, out_dir: Path, n_shards: int) -> None:
         require(runner is not None and runner.n_shards == n_shards,
                 f"the broker did not build a {n_shards}-shard mesh runner")
         t0 = time.monotonic()
-        result = run_load(served, [bench.one_task()],
+        result = run_load(served, [one_task()],
                           one_task_plan(args.instances), tally, retry,
                           client_threads=8, workers_per_type=8,
                           timeout_s=args.phase_timeout)

@@ -48,7 +48,7 @@ def test_resolver_raises_what_the_backend_raises(monkeypatch):
 
 
 def test_resolver_honours_an_explicit_cpu_request():
-    # tests/conftest.py asked for the CPU in-process, as ZB_BENCH_CPU does
+    # tests/conftest.py asked for the CPU in-process
     assert backend.cpu_requested()
     found = backend.devices()
     assert found and all(d.platform == "cpu" for d in found)
@@ -80,15 +80,6 @@ def test_worker_exits_nonzero_when_its_device_is_taken(monkeypatch, capsys):
                       "--gateway", "gw"])
     assert rc != 0
     assert "no device for this worker" in capsys.readouterr().err
-
-
-def test_bench_refuses_a_cpu_it_was_not_told_to_use(monkeypatch):
-    import bench
-
-    monkeypatch.delenv("ZB_BENCH_CPU", raising=False)
-    monkeypatch.setattr(xla_cache, "enable_persistent_cache", lambda: "")
-    with pytest.raises(SystemExit, match="ZB_BENCH_CPU"):
-        bench._ensure_backend()
 
 
 # -- the compile cache ---------------------------------------------------------

@@ -683,7 +683,7 @@ class TestCliSnapshots:
 
 # ---------------------------------------------------------------------------
 # The soak gate, short mode (slow-marked: the CI soak job runs the full
-# short mode via bench.py --soak --quick)
+# short mode via gates.py soak --quick)
 
 
 @pytest.mark.slow

@@ -282,7 +282,7 @@ class TestTcpSelfDelivery:
 
 
 # ---------------------------------------------------------------------------
-# the full harness (slow; CI runs it via `bench.py --serving --quick`)
+# the full harness (slow; CI runs it via `gates.py serving --quick`)
 
 
 @pytest.mark.slow

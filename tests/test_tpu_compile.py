@@ -71,9 +71,9 @@ def mesh(topo):
 def table_sets():
     """The compiled table sets exactly as a partition's registry builds
     them: deploy + first touch through an engine with the kernel backend."""
-    import bench
     import chip_smoke
     from zeebe_tpu.testing import EngineHarness
+    from zeebe_tpu.testing.workloads import one_task
 
     def registry_tables(models):
         h = EngineHarness(use_kernel_backend=True)
@@ -91,7 +91,7 @@ def table_sets():
     cfg = mixed.kernel_config
     # the point of the mixed set: stack VM, join sort and scope reduction
     assert cfg.has_conditions and cfg.has_joins and cfg.has_scopes
-    return {"one_task": registry_tables([bench.one_task()]), "mixed9": mixed}
+    return {"one_task": registry_tables([one_task()]), "mixed9": mixed}
 
 
 def _abstract(tree, sharding, scalars_as=()):
@@ -167,7 +167,7 @@ def _compile_run_to_completion(set_name, instances, table_sets, one_chip, mesh):
 
 
 def _compile_decision(_set_name, contexts, table_sets, one_chip, mesh):
-    """``ops/decision._evaluate_batch`` over bench.run_dmn_batch's table."""
+    """``ops/decision._evaluate_batch`` over an eight-rule FIRST-hit table."""
     import jax
 
     from zeebe_tpu.dmn import parse_dmn_xml

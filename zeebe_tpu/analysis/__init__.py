@@ -13,7 +13,7 @@ machine-checks them instead.
 
 Entry points (stdlib-only — the linter must never pull the jax stack):
 
-- ``run_lint(root)``        → list[Finding] over the package + bench.py
+- ``run_lint(root)``        → list[Finding] over the package + gates.py
 - ``python -m zeebe_tpu.cli lint [--check] [--update-baseline]``
 - ``python -m zeebe_tpu.cli knobs-doc [--check]`` (env-knob drift gate)
 

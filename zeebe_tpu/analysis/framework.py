@@ -31,7 +31,7 @@ BASELINE_FILENAME = ".zlint-baseline"
 #: files the lint walk covers, relative to the repo root. Tests are excluded
 #: deliberately: they provoke violations on purpose (fixtures under
 #: tests/fixtures/lint/ are the rule suite's own corpus).
-LINT_GLOBS = ("zeebe_tpu/**/*.py", "bench.py", "__graft_entry__.py")
+LINT_GLOBS = ("zeebe_tpu/**/*.py", "gates.py", "__graft_entry__.py")
 
 _SUPPRESS_RE = re.compile(r"#\s*zlint:\s*disable=([A-Za-z0-9_,\- ]+)")
 

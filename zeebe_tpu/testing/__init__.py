@@ -6,8 +6,9 @@ TestStreams (writes commands directly to the log), ProcessingExporterTransistor
 (feeds every written record into the RecordingExporter), ControlledActorClock
 (deterministic time).
 
-Also the module the bench and the gateway-less demo drive — the reference uses
-EngineRule for its CI perf gate (EngineLargeStatePerformanceTest) the same way.
+Also what the eligibility gate (``gates.py``) and the workload drivers
+(``zeebe_tpu/testing/workloads.py``) drive — the reference uses EngineRule for
+its CI perf gate (EngineLargeStatePerformanceTest) the same way.
 """
 
 from __future__ import annotations
@@ -21,7 +22,7 @@ from zeebe_tpu.exporters.recording import RecordingExporter
 from zeebe_tpu.journal import SegmentedJournal
 from zeebe_tpu.logstreams import LogAppendEntry, LogStream
 from zeebe_tpu.models.bpmn import ProcessModel, to_bpmn_xml
-from zeebe_tpu.protocol import Record, ValueType, command
+from zeebe_tpu.protocol import Record, RecordType, ValueType, command
 from zeebe_tpu.protocol.intent import (
     DeploymentIntent,
     IncidentIntent,
@@ -60,6 +61,7 @@ class EngineHarness:
         clock: ControlledClock | None = None,
         use_kernel_backend: bool = False,
         mesh_runner=None,
+        durable: bool = False,
     ) -> None:
         self._tmp = None
         if directory is None:
@@ -68,7 +70,14 @@ class EngineHarness:
         self.clock = clock or ControlledClock()
         self.journal = SegmentedJournal(Path(directory) / "log")
         self.stream = LogStream(self.journal, partition_id, clock=self.clock)
-        self.db = ZbDb(consistency_checks=consistency_checks)
+        self.durable = durable
+        if durable:
+            from zeebe_tpu.state import DurableZbDb
+
+            self.db = DurableZbDb(Path(directory) / "state",
+                                  consistency_checks=consistency_checks)
+        else:
+            self.db = ZbDb(consistency_checks=consistency_checks)
         self.engine = Engine(self.db, partition_id, clock_millis=self.clock,
                              partition_count=partition_count)
         self.exporter = RecordingExporter()
@@ -113,6 +122,8 @@ class EngineHarness:
 
     def close(self) -> None:
         self.journal.close()
+        if self.durable:
+            self.db.close()
         if self._tmp is not None:
             self._tmp.cleanup()
 
@@ -296,6 +307,60 @@ class EngineHarness:
                     {"scopeKey": scope_key, "variables": variables, "local": local}),
             request_id=request_id,
         )
+
+    # -- bulk drive (whole workloads: write many commands, pump once) ---------
+
+    def inject_creations(self, bpmn_process_id: str, n: int,
+                         variables: dict[str, Any]) -> None:
+        """Append ``n`` creation commands without pumping, so the processor
+        finds a backlog to form kernel groups from."""
+        create = command(
+            ValueType.PROCESS_INSTANCE_CREATION,
+            ProcessInstanceCreationIntent.CREATE,
+            {"bpmnProcessId": bpmn_process_id, "version": -1,
+             "variables": variables},
+        )
+        for _ in range(n):
+            self.stream.writer.try_write([LogAppendEntry(create)])
+
+    def pending_job_keys(self, after_position: int) -> list[tuple[str, int, int]]:
+        """Worker-side job discovery over the log: ``(job type, process
+        instance key, job key)`` of every JOB CREATED after the position."""
+        return [
+            (view.value.get("type", ""),
+             view.value.get("processInstanceKey", -1), view.key)
+            for view in self.stream.scan_filtered(
+                after_position + 1, int(RecordType.EVENT), int(ValueType.JOB),
+                int(JobIntent.CREATED))
+        ]
+
+    def complete_in_type_waves(self, jobs: list[tuple[str, int, int]]) -> None:
+        """Complete jobs one (job type, per-instance job index) wave at a
+        time — one worker per type completing at its own pace. It is also the
+        order groups can form in: batch admission takes one command per
+        instance per group, so adjacent completes of one instance's parallel
+        branches would cut every group down to a single command."""
+        waves: dict[tuple[str, int], list[int]] = {}
+        per_instance: dict[tuple[str, int], int] = {}
+        for job_type, pi_key, key in jobs:
+            idx = per_instance.get((job_type, pi_key), 0)
+            per_instance[(job_type, pi_key)] = idx + 1
+            waves.setdefault((job_type, idx), []).append(key)
+        for wave in sorted(waves):
+            # one append batch per wave, as a gateway's request batching
+            # would write it
+            self.stream.writer.try_write([
+                LogAppendEntry(command(ValueType.JOB, JobIntent.COMPLETE,
+                                       {"variables": {}}, key=key))
+                for key in waves[wave]
+            ])
+            self.pump()
+
+    def count_transitions(self, after_position: int) -> int:
+        """PROCESS_INSTANCE lifecycle events appended after the position."""
+        return sum(1 for _ in self.stream.scan_filtered(
+            after_position + 1, int(RecordType.EVENT),
+            int(ValueType.PROCESS_INSTANCE)))
 
     # -- state helpers -------------------------------------------------------
 

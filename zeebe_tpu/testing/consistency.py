@@ -29,7 +29,7 @@ construction, and a post-drive probe (:func:`_dedupe_replay_probe`) kills a
 leader and resends an already-answered envelope to prove the replicated
 dedupe table replays the stored reply across a process death.
 
-``bench.py --consistency [--quick]`` runs this and writes
+``gates.py consistency [--quick]`` runs this and writes
 ``CONSISTENCY[_quick].json``; the CI ``consistency-smoke`` job gates on it.
 """
 

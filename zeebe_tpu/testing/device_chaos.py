@@ -35,7 +35,7 @@ Gates:
   HEALTHY→SUSPECT→QUARANTINED and return QUARANTINED→HEALTHY through
   verified canaries (evidence: the per-life device-health JSONL).
 
-``bench.py --device-chaos [--quick]`` runs this and writes
+``gates.py device-chaos [--quick]`` runs this and writes
 DEVICE_CHAOS[_quick].json; the CI ``device-chaos-smoke`` job gates on it.
 """
 
@@ -262,7 +262,7 @@ def run_device_chaos(cfg: DeviceChaosConfig, directory: str | Path) -> dict:
     # the whole point: the kernel backend is LIVE in every worker — on the
     # DIRECT dispatch path (the seam under test); mesh dispatch has its own
     # killable probe (PR 7) and would otherwise auto-activate under
-    # bench.py's inherited 8-virtual-device XLA_FLAGS
+    # gates.py's inherited 8-virtual-device XLA_FLAGS
     env["ZEEBE_BROKER_EXPERIMENTAL_KERNELBACKEND"] = "true"
     env["ZEEBE_BROKER_EXPERIMENTAL_KERNELMESHSHARDS"] = "0"
     env["ZEEBE_CHAOS_DEVICE"] = format_spec(plan)

@@ -1,7 +1,6 @@
 """Shared gate-evidence plumbing: flight-dump collection and CI artifact
-preservation — ONE home (the ``_collect_gate_dumps`` consolidation started
-in PR 9, finished here after zlint's drift-copy rule caught the
-``_collect_flight_dumps`` twins in the soak and scale-soak harnesses).
+preservation, in one home (zlint's drift-copy rule caught
+``_collect_flight_dumps`` twins in the soak and scale-soak harnesses once).
 
 Protocols, each used by every chaos gate:
 
@@ -10,7 +9,7 @@ Protocols, each used by every chaos gate:
   recovery event, and track which dumps have been claimed.
 - :func:`collect_gate_dumps` — copy a gate's flight dumps out of its
   about-to-be-deleted work dir into ``<repo>/<NAME>_dumps/`` for CI
-  artifact upload.
+  artifact upload (``gates.py``'s one front-end calls it for all eight).
 - :func:`percentile` — the one shared latency-percentile rule for gate
   reports (the serving gate's SLO math must not drift from any other
   gate's).
@@ -86,8 +85,8 @@ def collect_gate_dumps(dump_paths, dumps_name: str, work_dir: str,
                        repo_dir: str | None = None) -> list:
     """Copy a chaos gate's flight dumps out of its (about-to-be-deleted)
     work dir into ``<repo_dir>/<dumps_name>/`` for CI artifact upload;
-    returns the repo-relative copied paths. Shared by the soak, scale-soak,
-    and consistency gates — one dump-preservation protocol, not three."""
+    returns the repo-relative copied paths. One dump-preservation protocol
+    for every gate ``gates.py`` runs."""
     import shutil
 
     if repo_dir is None:

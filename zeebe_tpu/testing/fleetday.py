@@ -32,7 +32,7 @@ Gates:
   recall is measured, not assumed (on a clean run this is vacuously 100%,
   which the leak arm keeps honest).
 
-``bench.py --fleetday [--quick]`` runs this and writes
+``gates.py fleetday [--quick]`` runs this and writes
 ``FLEETDAY[_quick].json``; the CI ``fleetday-smoke`` job gates on it.
 Honest caveat (docs/fleetday.md): the quick gate is minutes, not hours —
 it proves the composition and the auditor's recall, not day-scale drift.

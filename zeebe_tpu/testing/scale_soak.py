@@ -617,5 +617,5 @@ class ScaleSoakHarness:
 
 def run_scale_soak(cfg: ScaleSoakConfig | None = None,
                    directory: str | Path | None = None) -> dict:
-    """One-call entry point (bench.py --scale-soak, tests)."""
+    """One-call entry point (gates.py scale-soak, tests)."""
     return ScaleSoakHarness(cfg, directory=directory).run()

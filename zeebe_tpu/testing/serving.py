@@ -28,7 +28,7 @@ overload (hot ramp + correlation storm) → ``C`` overload + chaos (worker
 kill). Offline, the workers' journals are read back and every acked
 request must appear exactly once (the PR 9 consistency evidence reused).
 
-``bench.py --serving [--quick]`` runs this and writes
+``gates.py serving [--quick]`` runs this and writes
 ``SERVING[_quick].json``; the CI ``serving-smoke`` job gates on it.
 """
 

@@ -24,7 +24,7 @@ durability pillar the paper promises:
   ``recovery_budget_ms`` (the `recovery_budget_exceeded` alert stays quiet);
 
 and captures every recovery in a flight-recorder dump, so each restart
-leaves a reviewable artifact (``bench.py --soak`` uploads them from CI).
+leaves a reviewable artifact (``gates.py soak`` copies them out; CI uploads them).
 
 Built on the PR 1 chaos harness (seeded, deterministic: a failing run
 replays from its seed) and the PR 4 observability plane (metrics store +
@@ -488,5 +488,5 @@ class SoakHarness:
 
 def run_soak(cfg: SoakConfig | None = None,
              directory: str | Path | None = None) -> dict:
-    """One-call entry point (bench.py --soak, tests)."""
+    """One-call entry point (gates.py soak, tests)."""
     return SoakHarness(cfg, directory=directory).run()

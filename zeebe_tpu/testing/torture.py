@@ -28,7 +28,7 @@ Gates:
   re-converged CRC-identical to the leader's log PAST the corrupted index —
   local corruption degraded into a bounded re-replication event.
 
-``bench.py --torture [--quick]`` runs this and writes TORTURE[_quick].json;
+``gates.py torture [--quick]`` runs this and writes TORTURE[_quick].json;
 the CI ``torture-smoke`` job gates on it.
 """
 
