@@ -396,6 +396,9 @@ class TestScrubberDetectionAndRepair:
             create = _deploy_and_load(cluster, 40)
             leader = cluster.leader(1)
             last_position = leader.stream.last_position
+            # rot is a fault of bytes at rest: committed batches sit in the
+            # journal's write buffer until a drain puts them in the file
+            leader.stream_journal.flush()
             seg_path = leader.stream_journal.segments[0].path
             _flip_byte(seg_path, 200)  # early committed history
             cluster.run(12_000)  # several scrub cycles + the repair

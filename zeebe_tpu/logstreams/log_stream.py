@@ -95,6 +95,26 @@ _M_COMMIT_LATENCY = _METRICS.histogram(
 _METRICS.gauge(
     "sequencer_queue_size",
     "sequenced batches queued for append (synchronous writer: 0)").set(0)
+# where a decoded batch came from (children resolved per stream): `handed`
+# counts the batches seated from a committed payload's own bytes, so
+# `journal` says how often a reader really had to go to the file
+_M_BATCH_READS = _METRICS.counter(
+    "log_stream_batch_reads_total",
+    "decoded batches served by the log stream, by where they came from: "
+    "decoded at append from the committed bytes the stream was handed, the "
+    "decoded-batch cache, or the stream journal's file",
+    ("partition", "source"))
+# always on, one observation a committed entry a replica; named into the
+# partition pipeline's family, which dashboards and the benchmark read side
+# by side (as the exporter director's stage is)
+_M_COMMIT_TO_STREAM = _METRICS.histogram(
+    "stream_processor_pipeline_commit_to_stream",
+    "seconds per committed raft entry a replica spent materializing it into "
+    "its log stream (the stream journal's buffered append, the decode of the "
+    "batch, the index and cache bookkeeping)",
+    ("partition",),
+    buckets=(0.00001, 0.000025, 0.00005, 0.0001, 0.00025, 0.0005,
+             0.001, 0.0025, 0.005, 0.01, 0.1, 1.0))
 
 # append→ack latency stamping: one enabled-check per append when tracing is
 # off (the singleton is mutated in place, never replaced)
@@ -503,6 +523,15 @@ class LogStream:
     appended on write — so position lookups are a bisect + one journal entry
     read instead of a log scan (2 ints per batch; a 1M-batch partition costs
     ~16 MB, and snapshots compact the journal long before that).
+
+    Decoded batches live in a bounded cache keyed by journal index. Two
+    appends seat their batch there as they write it, so the readers that
+    follow (processing scan, replay, exporters) never go to the file for it:
+    ``LogStreamWriter.try_write`` (from the entries in hand) and
+    ``append_committed_payload`` (decoded from the committed bytes it was
+    handed). ``append_prepatched`` seats nothing. Every other batch — one
+    found on disk at open, one evicted, anything after ``rebuild_index`` —
+    is read from the journal on first use, under the frame's CRC.
     """
 
     def __init__(self, journal: SegmentedJournal, partition_id: int, clock=None) -> None:
@@ -516,10 +545,13 @@ class LogStream:
         # became readable here (None for batches found on disk at open) —
         # what the stream processor's admit_wait histogram subtracts
         self._batch_readable_at: list[float | None] = []
-        # decoded-batch LRU keyed by journal index: the processing reader, the
-        # kernel group scanner, and exporters all walk the same recent suffix
-        # interleaved, so a single-slot cache thrashes (every read re-decodes
-        # a batch); 1024 batches ≈ one processing burst window
+        # decoded batches keyed by journal index, oldest half evicted at the
+        # limit: the processing reader, the kernel group scanner, and
+        # exporters all walk the same recent suffix interleaved, so a
+        # single-slot cache would thrash (every read re-decoding a batch).
+        # A batch is decoded once: at append where the bytes are in hand
+        # (try_write, append_committed_payload), else on the first read
+        # that misses, from the journal's file
         self._batch_cache: dict[int, list[LoggedRecord]] = {}
         # sized so one ingress burst window (thousands of single-command
         # batches) plus its follow-up reads stays decoded end-to-end
@@ -528,6 +560,11 @@ class LogStream:
         # unprocessed commands (burst appends): the command scan skips such
         # batches without decoding them. Absent = unknown (must decode).
         self._batch_has_commands: dict[int, bool] = {}
+        pid = str(partition_id)
+        self._m_reads_handed = _M_BATCH_READS.labels(pid, "handed")
+        self._m_reads_cache = _M_BATCH_READS.labels(pid, "cache")
+        self._m_reads_journal = _M_BATCH_READS.labels(pid, "journal")
+        self._m_commit_to_stream = _M_COMMIT_TO_STREAM.labels(pid)
         self.rebuild_index()
         self._writer = LogStreamWriter(self)
 
@@ -554,17 +591,24 @@ class LogStream:
         cache = self._batch_cache
         batch = cache.get(journal_index)
         if batch is not None:
+            self._m_reads_cache.inc()
             return batch
         jrec = self.journal.read_entry(journal_index)
         if jrec is None:
             return []
+        self._m_reads_journal.inc()
         batch = _deserialize_batch(jrec.data, self.partition_id)
+        self._seat_batch(journal_index, batch)
+        return batch
+
+    def _seat_batch(self, journal_index: int, batch: list[LoggedRecord]) -> None:
+        """Cache a freshly decoded batch and, where no writer gave the
+        command-scan skip flag, work it out from the records."""
         self._cache_batch(journal_index, batch)
         if journal_index not in self._batch_has_commands:
             self._batch_has_commands[journal_index] = any(
                 r.record.is_command and not r.processed for r in batch
             )
-        return batch
 
     def _on_appended(self, first_position: int, journal_index: int) -> None:
         self._batch_positions.append(first_position)
@@ -586,22 +630,36 @@ class LogStream:
         at ingress. Used by the broker partition on leaders AND followers — the
         stream journal holds exactly the committed prefix of the Raft log
         (reference: AtomixLogStorage reads committed Raft entries; we
-        materialize them so readers/recovery are identical on every role)."""
+        materialize them so readers/recovery are identical on every role).
+
+        The bytes go into the stream journal's write buffer, and the batch
+        the readers will see is decoded here from ``payload`` itself — the
+        same bytes, the same ``Record.from_bytes``, so it is what a read of
+        the file would give — and seated in the cache. Nothing is read back,
+        so nothing forces the buffer out to the file: it goes at the
+        journal's ``max_unflushed_bytes``, at ``flush``/``close``, or when a
+        reader misses the cache. What that skips: the stream journal's
+        read-side CRC on a batch a replica decodes right after the commit
+        (they are the bytes Raft committed, and its journal checksummed)."""
         if first_position < self._next_position:
             return  # already materialized (e.g. re-delivered commit)
+        start = time.perf_counter()
         jrec = self.journal.append(payload, asqn=first_position)
         self._on_appended(first_position, jrec.index)
         if has_pending_commands is not None:
             # burst batches carry the command-scan skip flag from the leader's
-            # append (absent = unknown = decode on demand)
+            # append (absent = unknown = worked out from the decoded records)
             self._batch_has_commands[jrec.index] = has_pending_commands
-        batch = self._read_batch_at(jrec.index)
+        batch = _deserialize_batch(payload, self.partition_id)
+        self._seat_batch(jrec.index, batch)
+        self._m_reads_handed.inc()
         self._next_position = batch[-1].position + 1 if batch else first_position + 1
         if _TRACER.enabled and batch:
             # the broker materialization path (leader AND follower): register
             # trace roots so processor/exporter spans resolve transitively
             _TRACER.register_batch(self.partition_id, first_position,
                                    len(batch), batch[0].source_position)
+        self._m_commit_to_stream.observe(time.perf_counter() - start)
 
     def serialize_batch(self, entries: list[LogAppendEntry], first_position: int,
                         source_position: int = -1) -> bytes:
