@@ -153,6 +153,19 @@ def job_types(definitions: list) -> list:
                    if "job_type" in n})
 
 
+def jobs_per_instance(d: dict):
+    """How many jobs every instance of ``d`` runs: its service tasks. None
+    where an exclusive split leaves a choice of flows in a definition with
+    tasks, so that the count may depend on ``x``."""
+    leaving: dict = {}
+    for f in d["flows"]:
+        leaving[f["source"]] = leaving.get(f["source"], 0) + 1
+    tasks = sum(1 for n in d["nodes"] if n["type"] == "serviceTask")
+    splits = any(n["type"] == "exclusiveGateway" and leaving.get(n["id"], 0) > 1
+                 for n in d["nodes"])
+    return None if tasks and splits else tasks
+
+
 def max_fanout(definitions: list) -> int:
     """Largest number of flows leaving one element: the ``FO`` of the kernel
     contract's event row (``2 + FO`` int32 a token step)."""

@@ -14,12 +14,15 @@ harness through JSON lines on stdin/stdout:
 
 Every request is stamped with the time it was **due** (``time.monotonic()``,
 which parent and child share on one machine): in the open loop the schedule's
-time, in the closed loop the moment its creator was free to send it.
+time, in the closed loop the moment its creator was free to send it: when its
+last create was acknowledged or, with ``"on": "completion"``, when the
+completion of its last instance's last job was acknowledged to a worker here.
 """
 
 from __future__ import annotations
 
 import argparse
+import inspect
 import json
 import os
 import random
@@ -83,12 +86,62 @@ class Retrying:
             delay = min(delay * 2, 1.0)
 
 
+def worker_arguments(workers: dict) -> dict:
+    """The keyword arguments of every ``JobWorker`` of a mix: the harness's
+    own three, and the mix's ``workers.options`` beside them. An option that
+    ``JobWorker`` does not take is refused by name, and so is one of the
+    harness's own: its handler and its count of acknowledged completions
+    rest on ``auto_complete`` and ``timeout_ms``, and ``max_backoff_s`` is
+    the mix's own key."""
+    from zeebe_tpu.client import JobWorker
+
+    # an activation whose response was lost comes back after this long, not
+    # after the five-minute default
+    own = {"timeout_ms": 60_000, "auto_complete": False,
+           "max_backoff_s": float(workers["max_backoff_s"])}
+    options = workers.get("options", {})
+    taken = set(inspect.signature(JobWorker.__init__).parameters) - {
+        "self", "client", "job_type", "handler", *own}
+    if unknown := sorted(set(options) - taken):
+        raise ValueError(f"workers.options {unknown}: a mix may set "
+                         f"{sorted(taken)} of JobWorker's; {sorted(own)} are "
+                         "the harness's own")
+    return {**own, **options}
+
+
+def jobs_to_wait_for(traffic: dict):
+    """``loop.on`` absent: None, the loop is closed on acknowledgements.
+    ``"completion"``: process id -> the jobs whose acknowledged completions
+    tell a client that its instance is done; a definition whose count is
+    nought or may depend on ``x`` is refused by name."""
+    loop = traffic["loop"]
+    if "on" not in loop:
+        return None
+    if loop["kind"] != "closed" or loop["on"] != "completion":
+        raise ValueError(f"loop.on {loop['on']!r} in a loop of kind "
+                         f"{loop['kind']!r}: known is \"completion\", closed")
+    jobs = {}
+    for spec, d in zip(traffic["definitions"],
+                       defs.build_definitions(traffic["definitions"])):
+        jobs[d["id"]] = defs.jobs_per_instance(d)
+        if not jobs[d["id"]]:
+            raise ValueError(
+                f"definition {d['id']!r} of kind {spec['kind']!r} under a loop "
+                "closed on completions: its instances run no job, or a number "
+                "that may depend on x, so the workers cannot tell when one is done")
+    return jobs
+
+
 class LoadGen:
     def __init__(self, address: str, traffic: dict, partitions: int,
                  seed: int, out_dir: str) -> None:
         from zeebe_tpu.client import ZeebeTpuClient
 
         self.Client = ZeebeTpuClient
+        self.worker_arguments = worker_arguments(traffic["workers"])
+        self.jobs_of = jobs_to_wait_for(traffic)
+        self.jobs_left: dict = {}   # instance key -> jobs still to complete
+        self.done: dict = {}        # instance key -> set once none is left
         self.address = address
         self.traffic = traffic
         self.partitions = partitions
@@ -170,6 +223,8 @@ class LoadGen:
                 self.sheds += sheds
                 if done:        # acknowledged: it has to be durable
                     self.completed_jobs.append(job.key)
+                    if self.jobs_of is not None:
+                        self._job_done(job)
 
         for job_type in defs.job_types(self.definitions):
             for _ in range(int(w["per_job_type"])):
@@ -178,10 +233,7 @@ class LoadGen:
                 self.workers.append(JobWorker(
                     client, job_type,
                     lambda jc, job, client=client: complete(jc, job, client),
-                    # an activation whose response was lost comes back after
-                    # this long, not after the five-minute default
-                    timeout_ms=60_000, auto_complete=False,
-                    max_backoff_s=float(w["max_backoff_s"])).start())
+                    **self.worker_arguments).start())
         return {"definitions": len(resources), "workers": len(self.workers),
                 "payload_bytes": defs.payload_bytes(self.payload)}
 
@@ -194,23 +246,47 @@ class LoadGen:
             raise RuntimeError("a first-touch create was refused")
         return {"keys": [r["key"] for r in recs]}
 
-    def _closed_creator(self, i: int, n: int) -> None:
+    def _job_done(self, job) -> None:
+        """Under ``self.lock``: one job fewer stands between the job's
+        instance and its end."""
+        key = job.process_instance_key
+        left = self.jobs_left.get(key, self.jobs_of[job.bpmn_process_id]) - 1
+        self.jobs_left[key] = left
+        if left == 0:
+            self.done.setdefault(key, threading.Event()).set()
+
+    def _await_completion(self, key: int) -> None:
+        """Until the instance's last job was completed, or the window closed:
+        the harness waits for what is open then, the client need not."""
+        with self.lock:
+            done = self.done.setdefault(key, threading.Event())
+        while not done.is_set():
+            # the window's end is known only once the window was opened
+            left = 0.25 if self.t_end is None else self.t_end - time.monotonic()
+            if left <= 0:
+                break
+            done.wait(left)
+        with self.lock:
+            self.done.pop(key, None)
+            self.jobs_left.pop(key, None)
+
+    def _closed_creator(self, plans: dict) -> None:
         """One closed-loop client: the warm-up's plan until the window opens,
         then its share of the window's plan until the window closes — its
-        next request goes out when the last was answered, with no gap at the
-        change-over."""
-        plans = {phase: defs.request_plan(self.definitions, n * 2000,
-                                          self.payload, seed)[i::n]
-                 for phase, seed in (("warm", self.seed ^ 0xAAAA),
-                                     ("window", self.seed))}
+        next request goes out when the last was answered (``loop.on``
+        ``"completion"``: when the instance it created was done), with no gap
+        at the change-over."""
         for phase in ("warm", "window"):
-            for pid, variables in plans[phase]:
+            for pid, x in plans[phase]:
+                variables = {**x, **self.payload}
                 due = time.monotonic()
                 if phase == "warm" and self.t0 is not None and due >= self.t0:
                     break
                 if phase == "window" and due >= self.t_end:
                     return
-                self.create(pid, variables, due, phase)
+                rec = self.create(pid, variables, due, phase)
+                if self.jobs_of is not None and rec["ok"]:
+                    self._await_completion(rec["key"])
             else:
                 raise RuntimeError("a closed-loop creator ran out of plan")
 
@@ -232,8 +308,16 @@ class LoadGen:
         loop = self.traffic["loop"]
         if loop["kind"] == "closed":
             n = int(loop["clients"])
-            self.warm_threads = [self._spawn(self._closed_creator, i, n)
-                                 for i in range(n)]
+            # drawn once and dealt round the clients, without the payload: n
+            # plans of n x 2000 entries, the payload in each, are tens of
+            # seconds of this process's interpreter from 32 clients on
+            plans = {phase: defs.request_plan(self.definitions, n * 2000, {}, seed)
+                     for phase, seed in (("warm", self.seed ^ 0xAAAA),
+                                         ("window", self.seed))}
+            self.warm_threads = [
+                self._spawn(self._closed_creator,
+                            {phase: plan[i::n] for phase, plan in plans.items()})
+                for i in range(n)]
         else:
             self.pool = ThreadPoolExecutor(max_workers=int(loop["senders"]))
             self.warm_threads = [self._spawn(self._open_warm, loop)]
@@ -261,7 +345,7 @@ class LoadGen:
         while (left := t0 - time.monotonic()) > 0:
             time.sleep(min(0.01, left))
         self.warm_stop.set()
-        rpcs0, sheds0 = self.rpcs, self.sheds
+        rpcs0, sheds0, cpu0 = self.rpcs, self.sheds, time.process_time()
         if loop["kind"] == "closed":
             for t in self.warm_threads:     # they go on into the window's plan
                 t.join()
@@ -281,6 +365,9 @@ class LoadGen:
             for t in self.warm_threads:
                 t.join()
             self.pool.shutdown(wait=True)
+        # this process's cores from the window's opening until its last
+        # request was answered: whether the generator is what bounds a cell
+        cpu_cores = (time.process_time() - cpu0) / (time.monotonic() - t0)
         if self.error is not None:
             raise self.error
         with self.lock:
@@ -291,7 +378,8 @@ class LoadGen:
             for r in records:
                 out.write(json.dumps(r) + "\n")
         return {"records_file": path, "window_requests": len(window),
-                "rpcs": self.rpcs - rpcs0, "sheds": self.sheds - sheds0}
+                "rpcs": self.rpcs - rpcs0, "sheds": self.sheds - sheds0,
+                "cpu_cores": round(cpu_cores, 3)}
 
     def stop(self) -> dict:
         for w in self.workers:

@@ -16,8 +16,9 @@ against the plain reference, and prints one JSON line.
 ``--rehearse-cpu`` (with ``JAX_PLATFORMS=cpu``) walks the same path on the host
 for a rehearsal: it ends with exit code 3 and prints **no** result line.
 ``--fault <name>`` breaks the timed path underneath the comparison — in the
-program's own Raft path (``lying_follower``) or where the harness takes its
-answers (the others) — for the controls and the fault tests
+program's own Raft path (``lying_follower``), on the replicas' disks once the
+cluster has stopped (``torn_snapshot``) or where the harness takes its answers
+(the others) — for the controls and the fault tests
 (benchmarks/tests/); never used by a measurement.
 """
 
@@ -52,7 +53,7 @@ import trace_reduce  # noqa: E402
 REHEARSAL_EXIT = 3
 REFUSED_EXIT = 2
 FAULTS = ("lying_follower", "lose_acked", "at_least_once", "alter_record",
-          "replica_export_differs")
+          "replica_export_differs", "torn_snapshot")
 
 
 class Refused(Exception):
@@ -71,7 +72,8 @@ def say(message: str) -> None:
 
 def load_json(path: Path, what: str) -> dict:
     if not path.is_file():
-        raise Refused(f"unknown {what}: no file {path.relative_to(ROOT)}")
+        shown = path.relative_to(ROOT) if path.is_relative_to(ROOT) else path
+        raise Refused(f"unknown {what}: no file {shown}")
     return json.loads(path.read_text())
 
 
@@ -201,7 +203,7 @@ def decide_correct(numbers: dict) -> bool:
 
 def compare(definitions: list, requests: list, observed_events: dict,
             completed_at: dict, returned: dict, completed_jobs: list,
-            logs: dict, marks: dict) -> dict:
+            logs: dict, marks: dict, position_of: dict | None = None) -> dict:
     """The comparison that decides ``correct``, over every acknowledged
     create of the run: its exported records against the plain reference, its
     completion, and — for it and for every acknowledged job completion — its
@@ -209,7 +211,11 @@ def compare(definitions: list, requests: list, observed_events: dict,
     each other, byte for byte, as far as all had committed. Exact: limits 0.
 
     ``logs``: ``served.replica_logs``; ``marks``: (partition, broker) -> the
-    replica's commit index while the cluster ran."""
+    replica's commit index while the cluster ran; ``position_of``: key -> the
+    log position of the record an acknowledgement rests on, as exported. A
+    replica that compacted its log is held to what it kept: an
+    acknowledgement is missing unless the log on disk holds it or a snapshot
+    on that replica's disk covers its position."""
     by_id = {d["id"]: d for d in definitions}
     acked = [r for r in requests if r["ok"]]
     never = [r["key"] for r in acked if r["key"] not in completed_at]
@@ -219,10 +225,15 @@ def compare(definitions: list, requests: list, observed_events: dict,
     # a key's upper bits name its partition (the protocol's layout)
     keys = {r["key"] for r in acked}
     jobs = set(completed_jobs)
-    missing = differing = 0
+    missing = differing = under_a_snapshot = 0
+    position_of = position_of or {}
     for (pid, _broker), log in logs.items():
-        missing += len({k for k in keys if k >> 51 == pid} - log["created"])
-        missing += len({k for k in jobs if k >> 51 == pid} - log["jobs_completed"])
+        gone = ({k for k in keys if k >> 51 == pid} - log["created"]) | (
+            {k for k in jobs if k >> 51 == pid} - log["jobs_completed"])
+        covered = log.get("snapshot_position", 0)
+        kept = sum(1 for k in gone if 0 < position_of.get(k, math.inf) <= covered)
+        under_a_snapshot += kept
+        missing += len(gone) - kept
     for pid in {pid for pid, _broker in logs}:
         replicas = [log for (p, _b), log in logs.items() if p == pid]
         committed = min(mark for (p, _b), mark in marks.items() if p == pid)
@@ -238,7 +249,8 @@ def compare(definitions: list, requests: list, observed_events: dict,
         "acks_missing_in_a_replica": {"value": missing, "limit": 0},
         "replica_log_entries_differing": {"value": differing, "limit": 0},
     }
-    return {"numbers": numbers, "examples": bad[:3], "never": never[:3]}
+    return {"numbers": numbers, "examples": bad[:3], "never": never[:3],
+            "under_a_snapshot": under_a_snapshot}
 
 
 # ---------------------------------------------------------------------------
@@ -251,6 +263,10 @@ def main(argv=None) -> int:
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--seconds", type=float, required=True)
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    parser.add_argument("--manifest", default=None,
+                        help="a manifest other than BENCHMARK.json: for a "
+                             "cell that is not in the benchmark yet (sweeps, "
+                             "the tests); never used by a measurement")
     parser.add_argument("--rehearse-cpu", action="store_true")
     parser.add_argument("--fault", choices=FAULTS, default=None)
     parser.add_argument("--keep-events", default=None,
@@ -267,7 +283,8 @@ def main(argv=None) -> int:
 
 
 def run(args) -> int:
-    what = resolve_cell(args.workload)
+    what = resolve_cell(args.workload, args.manifest and load_json(
+        Path(args.manifest).resolve(), "manifest"))
     cell, config, traffic = what["cell"], what["config"], what["traffic"]
     readers = {m["name"]: load_reader(m["reader"]) for m in what["per_layer"]}
     try:
@@ -279,8 +296,14 @@ def run(args) -> int:
     from zeebe_tpu.utils import backend
     from zeebe_tpu.utils.xla_cache import enable_persistent_cache
 
+    import loadgen
     import served as srv
 
+    try:    # what the child would refuse, before there is a cluster to start
+        loadgen.worker_arguments(traffic["workers"])
+        loadgen.jobs_to_wait_for(traffic)
+    except ValueError as err:
+        raise Refused(f"traffic mix {cell['traffic']!r}: {err}") from None
     cache_dir = enable_persistent_cache()
     import jax
 
@@ -342,13 +365,21 @@ def run(args) -> int:
         time.sleep(float(setup.get("shadow_warm_s", 2.0)))
         health.cfg.shadow_sample_rate = deployed_shadow_rate
         quiet_s = float(setup.get("quiet_s", 2.0))
+        # a mix that fills a partition's log within a run (the program
+        # snapshots and compacts once the replay debt passes a threshold of
+        # its own) says at what debt its window opens, so that the snapshot
+        # falls well inside every window and not at its edge in some
+        # (a rehearsal on the host never gets there: it does not wait)
+        warm_debt = 0.0 if rehearsal else float(setup.get("warm_debt_records", 0))
         while True:
             now = time.monotonic()
             if (now - warm_start >= float(setup.get("warm_min_s", 5.0))
-                    and now - ledger.last_at >= quiet_s):
+                    and now - ledger.last_at >= quiet_s
+                    and (not warm_debt or system.replay_debt() >= warm_debt)):
                 break
             if now - warm_start > float(setup.get("warm_max_s", 60.0)):
-                say("warm-up: compiles never settled; measuring anyway")
+                say("warm-up: compiles never settled or the replay debt "
+                    f"stayed at {system.replay_debt():.0f}; measuring anyway")
                 break
             time.sleep(0.1)
         t0 = time.monotonic() + 0.3
@@ -358,12 +389,15 @@ def run(args) -> int:
         counters0 = system.counters()
         elections0 = system.elections()
         compiles0 = ledger.compiles
+        cpu0, t0_wall = cpu_clocks(), time.time()
         setup_s = t0 - T_PROCESS_START
-        say(f"window opens: setup_s={setup_s:.2f} {ledger.report()}")
+        say(f"window opens: setup_s={setup_s:.2f} {ledger.report()} "
+            f"replay debt {system.replay_debt():.0f} records")
         if args.trace:
             trace_result = traced_stretch(jax, data_dir, t0, seconds,
                                           args.keep_trace)
         sleep_until(t0 + seconds)
+        cpu = cpu_shares(cpu0, cpu_clocks(), seconds)
         counters1 = system.counters()
         elections_in_window = system.elections() - elections0
         compiles_in_window = ledger.compiles - compiles0
@@ -389,6 +423,9 @@ def run(args) -> int:
         # the program's state is freed; what its replicas hold is read from
         # their files alone
         system.stop()
+        if args.fault == "torn_snapshot":
+            say(f"torn_snapshot: {srv.damage_snapshots(data_dir / 'data')} "
+                "snapshots damaged on the stopped replicas' disks")
         t_check = time.monotonic()
         logs = srv.replica_logs(data_dir / "data", layout)
     finally:
@@ -401,6 +438,7 @@ def run(args) -> int:
     with observed.lock:
         events = dict(observed.events)
         completed_at = dict(observed.completed_at)
+        position_of = dict(observed.position_of)
     window = [r for r in requests if r["phase"] == "window"]
     numbers = window_metrics(window, completed_at, list(completed_at.values()),
                              t0, seconds)
@@ -421,6 +459,11 @@ def run(args) -> int:
     say(f"counts in window: {json.dumps(delta)}")
     say(f"child: window={ {k: v for k, v in reply.items() if k != 'records_file'} } "
         f"stop={stopped} drain_s={drain_s:.2f}")
+    snapshots = sorted(round(log["snapshot_written_at"] - t0_wall, 1)
+                       for log in logs.values() if log["snapshot_written_at"])
+    say(f"cores used in the window: {json.dumps(cpu)}; the generator's "
+        f"process: {reply.get('cpu_cores')}; snapshots a restart would take, "
+        f"persisted at (s after the window opened): {snapshots}")
     say(f"groups by device (whole run): {counters_end['groups_by_device']} "
         f"mesh shards: {counters_end['shard_devices']} "
         f"failures: {counters_end['failures']} shadow: {shadow} "
@@ -450,7 +493,7 @@ def run(args) -> int:
     # ---- correct
     returned = payload if traffic["workers"]["complete_with_payload"] else {}
     verdict = compare(definitions, requests, events, completed_at, returned,
-                      completed_jobs, logs, marks)
+                      completed_jobs, logs, marks, position_of)
     checks = verdict["numbers"]
     checks["exports_differing_at_a_position"] = {"value": observed.differing,
                                                  "limit": 0}
@@ -459,7 +502,9 @@ def run(args) -> int:
     checks["shadow_mismatches"] = {"value": shadow["mismatches"], "limit": 0}
     correct = decide_correct(checks)
     say(f"check took {time.monotonic() - t_check:.2f}s; examples: "
-        f"{verdict['examples']} never completed: {verdict['never']}")
+        f"{verdict['examples']} never completed: {verdict['never']}; "
+        f"acknowledgements held by a snapshot, their log compacted: "
+        f"{verdict['under_a_snapshot']}")
 
     metrics = {}
     if args.trace:
@@ -494,6 +539,33 @@ def run(args) -> int:
         return REHEARSAL_EXIT
     print(json.dumps(result), flush=True)
     return 0
+
+
+def cpu_clocks() -> dict:
+    """CPU seconds so far of this process and of each of its Python threads
+    (by name and id; from ``/proc``, in clock ticks), to tell at a window's
+    close who had the interpreter."""
+    clocks = {"process": time.process_time()}
+    tick = os.sysconf("SC_CLK_TCK")
+    for t in threading.enumerate():
+        try:
+            stat = Path(f"/proc/self/task/{t.native_id}/stat").read_text()
+        except OSError:
+            continue    # the thread ended
+        utime, stime = stat.rsplit(")", 1)[1].split()[11:13]
+        clocks[f"{t.name}#{t.native_id}"] = (int(utime) + int(stime)) / tick
+    return clocks
+
+
+def cpu_shares(before: dict, after: dict, seconds: float, top: int = 6) -> dict:
+    """Cores used over the window: the process, and its busiest threads."""
+    used = {name: (after[name] - before[name]) / seconds
+            for name in after if name in before}
+    threads = sorted(((share, name) for name, share in used.items()
+                      if name != "process"), reverse=True)[:top]
+    return {"process": round(used["process"], 3),
+            "threads": {name.split("#")[0]: round(share, 3)
+                        for share, name in threads}}
 
 
 def sleep_until(t: float) -> None:

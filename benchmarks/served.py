@@ -28,6 +28,9 @@ class Observed:
         self.lock = threading.Lock()
         self.events: dict = {}       # instance key -> [event tuple]
         self.completed_at: dict = {}  # instance key -> time.monotonic()
+        #: instance or job key -> the log position of the record that an
+        #: acknowledgement of its create or completion rests on
+        self.position_of: dict = {}
         self.records = 0
         #: export is at-least-once and every replica exports: a position is
         #: seen several times. The first sighting is kept, under
@@ -40,12 +43,23 @@ class Observed:
         self.fault: str | None = None
         #: ``lying_follower``: appends acknowledged and not stored
         self.lies = 0
+        #: instance key -> its rank among the instances seen under a fault
+        self.ranks: dict = {}
+
+    def broken(self, key: int) -> bool:
+        """Under a fault one instance in ten is broken, counted in the order
+        in which the instances were first seen with the fault on: the first,
+        the eleventh... So a window that holds an instance holds a broken
+        one, whatever keys the run drew."""
+        with self.lock:
+            return self.ranks.setdefault(key, len(self.ranks)) % 10 == 0
 
 
 def _broken(event: tuple, fault: str):
     """The control and the fault tests: what the timed path produced, broken
-    where the harness takes it (one instance in ten). ``lose_acked``: an
-    acknowledged instance's records never arrive (durability broken);
+    where the harness takes it (one instance in ten: ``Observed.broken``).
+    ``lose_acked``: an acknowledged instance's records never arrive
+    (durability broken);
     ``at_least_once``: a job's completion is applied twice (exactly-once
     broken); ``alter_record``: a token is sent down another flow;
     ``replica_export_differs``: as ``alter_record``, but in a later replica's
@@ -66,18 +80,27 @@ def capture_exporter(observed: Observed):
     it. Completion is observed here, where a deployment observes it."""
     from zeebe_tpu.exporters.api import Exporter
     from zeebe_tpu.protocol import ValueType
+    from zeebe_tpu.protocol.intent import (JobIntent,
+                                           ProcessInstanceCreationIntent)
     from zeebe_tpu.protocol.intent import ProcessInstanceIntent as PI
 
     pi_type, job_type, var_type = (ValueType.PROCESS_INSTANCE, ValueType.JOB,
                                    ValueType.VARIABLE)
+    creation_type = ValueType.PROCESS_INSTANCE_CREATION
 
     class CaptureExporter(Exporter):
         def export(self, logged) -> None:
             record = logged.record
             value_type = record.value_type
-            event = key = None
+            event = key = acked = None
             if record.is_event:
                 value = record.value
+                if (value_type == creation_type
+                        and record.intent == ProcessInstanceCreationIntent.CREATED):
+                    acked = value["processInstanceKey"]
+                elif (value_type == job_type
+                      and record.intent == JobIntent.COMPLETED):
+                    acked = record.key
                 if value_type == pi_type:
                     event = ("PI", record.intent.name, value["elementId"],
                              record.key, value["flowScopeKey"])
@@ -93,7 +116,7 @@ def capture_exporter(observed: Observed):
             kept = [] if event is None else [event]
             fault = observed.fault
             where = (record.partition_id, logged.position)
-            if event is not None and fault is not None and (key >> 3) % 10 == 0:
+            if event is not None and fault is not None and observed.broken(key):
                 if fault != "replica_export_differs" or where in observed.seen:
                     kept = _broken(event, fault)
             said = (int(record.record_type), int(value_type),
@@ -105,6 +128,8 @@ def capture_exporter(observed: Observed):
                     observed.differing += first != said
                 else:
                     observed.records += 1
+                    if acked is not None:
+                        observed.position_of[acked] = logged.position
                     if kept:
                         observed.events.setdefault(key, []).extend(kept)
                         if (value_type == pi_type and record.key == key
@@ -179,6 +204,10 @@ class Served:
         if not cfg.base.kernel_backend:
             raise RuntimeError("kernel backend is off in the config")
         self.gateway = None
+        # absent: not passed, the program's own default (every group on one
+        # device); present: the mesh runner shards groups over that many chips
+        mesh = ({"kernel_mesh_shards": int(layout["kernel_mesh_shards"])}
+                if "kernel_mesh_shards" in layout else {})
         self.runtime = ClusterRuntime(
             exporters_factory=lambda: {"bench": capture_exporter(observed)},
             kernel_backend=cfg.base.kernel_backend,
@@ -190,6 +219,7 @@ class Served:
             backpressure_enabled=cfg.backpressure.enabled,
             disk_min_free_bytes=(cfg.disk.min_free_bytes
                                  if cfg.disk.enable_monitoring else 0),
+            **mesh,
         )
         self.runtime.start()
         self.gateway = Gateway(self.runtime, bind="127.0.0.1:0")
@@ -250,6 +280,11 @@ class Served:
                 stage = name.rsplit("_pipeline_", 1)[1]
                 out[f"{stage}_count"] += value[0]
                 out[f"{stage}_seconds"] += value[1]
+            elif kind == "histogram" and name.endswith("snapshot_duration"):
+                # a saturated partition snapshots inside a run, on its pump
+                # thread: how many fell in the window and what they took
+                out["snapshot_count"] += value[0]
+                out["snapshot_seconds"] += value[1]
         runner = self.mesh_runner()
         if runner is not None:
             out["mesh_dispatches"] = runner.dispatches
@@ -261,6 +296,18 @@ class Served:
                 "shard_devices": sorted(device_name(d)
                                         for d in runner.shard_devices)
                 if runner is not None else []}
+
+    def replay_debt(self) -> float:
+        """The program's ``snapshot_replay_debt_records``, the largest of the
+        partitions': records appended since a partition's last snapshot. Its
+        scheduler snapshots (and compacts the log) once that debt threatens
+        the recovery budget; it sets the gauge once a second."""
+        from zeebe_tpu.utils.metrics import REGISTRY
+
+        return max((value for name, kind, _labels, value in REGISTRY.snapshot()
+                    if kind == "gauge"
+                    and name.endswith("snapshot_replay_debt_records")),
+                   default=0.0)
 
     def raft_marks(self) -> dict:
         """(partition, broker) -> the replica's commit index, read while the
@@ -284,13 +331,22 @@ def replica_logs(data_dir: Path, layout: dict) -> dict:
     read once the cluster is stopped and with none of its memory: ``entries``
     (raft index -> the entry's bytes), ``created`` (the instance keys whose
     creation it holds) and ``jobs_completed`` (the job keys whose completion
-    it holds). The durability side of an acknowledgement."""
+    it holds). The durability side of an acknowledgement. A saturated
+    partition snapshots within a run and compacts its log behind the
+    snapshot: ``snapshot_position`` is the processed position of the newest
+    snapshot on the replica's disk that the program itself would recover
+    from (0: none) and stands for the entries that the log no longer holds.
+    It counts only if the program's own store finds its chain valid (every
+    file against the CRC in its manifest, every delta's parent present) and
+    ``load_chain_db`` builds a state from it; ``snapshot_written_at`` is
+    when it was persisted (``time.time()``)."""
     from zeebe_tpu.journal.journal import read_only_records
     from zeebe_tpu.logstreams.log_stream import _deserialize_batch
     from zeebe_tpu.protocol import ValueType
     from zeebe_tpu.protocol.intent import (JobIntent,
                                            ProcessInstanceCreationIntent)
     from zeebe_tpu.protocol.msgpack import unpackb
+    from zeebe_tpu.state.snapshot import FileBasedSnapshotStore, load_chain_db
 
     out = {}
     for b in range(int(layout["brokers"])):
@@ -316,9 +372,41 @@ def replica_logs(data_dir: Path, layout: dict) -> dict:
                     elif (record.value_type == ValueType.JOB
                           and record.intent == JobIntent.COMPLETED):
                         jobs.add(record.key)
+            covered, written_at = 0, None
+            store = data_dir / name / f"partition-{pid}" / "snapshots"
+            if store.is_dir():
+                # as a restart would: the store drops what its manifest does
+                # not bear out and recovery takes the newest chain that loads
+                chain = FileBasedSnapshotStore(store).latest_valid_chain()
+                try:
+                    if chain is not None and load_chain_db(chain) is not None:
+                        covered = chain[-1].id.processed_position
+                        written_at = chain[-1].path.stat().st_mtime
+                except Exception:  # noqa: BLE001 — it does not load: not held
+                    pass
             out[(pid, name)] = {"entries": entries, "created": created,
-                                "jobs_completed": jobs}
+                                "jobs_completed": jobs,
+                                "snapshot_position": covered,
+                                "snapshot_written_at": written_at}
     return out
+
+
+def damage_snapshots(data_dir: Path) -> int:
+    """The fault ``torn_snapshot``: once the cluster has stopped, every
+    snapshot on every replica's disk loses the second half of its largest
+    file, as a crash between the write and the fsync leaves it. What a log
+    compacted behind such a snapshot no longer holds is then held by nothing.
+    Returns the number of snapshots damaged."""
+    damaged = 0
+    for snapshot in data_dir.glob("broker-*/partition-*/snapshots/snapshots/*"):
+        files = [p for p in snapshot.iterdir()
+                 if p.is_file() and p.name != "CHECKSUM.sfv"]
+        if files:
+            victim = max(files, key=lambda p: p.stat().st_size)
+            with victim.open("r+b") as f:
+                f.truncate(victim.stat().st_size // 2)
+            damaged += 1
+    return damaged
 
 
 def plant_lying_follower(observed: Observed, victim: str = "broker-2"):

@@ -138,3 +138,49 @@ def test_every_replica_has_to_hold_every_acknowledgement_and_the_same_bytes():
     assert numbers({**logs, (1, "broker-1"): other}) == (0, 1)
     short = {**log, "entries": {1: b"a"}}
     assert numbers({**logs, (1, "broker-1"): short}) == (0, 0)
+    # a replica that compacted its log behind a snapshot on its disk: what the
+    # snapshot covers, by the position the record was exported at, is held;
+    # a key past it, or one the exporter never saw, is missing as before
+    compacted = {**behind, "snapshot_position": 40}
+    for position_of, missing in (({key: 30, job: 40}, 0), ({key: 30, job: 41}, 1),
+                                 ({key: 30}, 1), ({}, 2)):
+        n = run.compare([], [request], {}, {}, {}, [job],
+                        {**logs, (1, "broker-1"): compacted}, marks, position_of)
+        assert n["numbers"]["acks_missing_in_a_replica"]["value"] == missing
+    assert run.compare([], [request], {}, {}, {}, [job],
+                       {**logs, (1, "broker-1"): behind}, marks, {key: 30, job: 40}
+                       )["numbers"]["acks_missing_in_a_replica"]["value"] == 2
+
+
+def test_a_snapshot_holds_what_its_log_compacted_only_if_a_restart_would_take_it(tmp_path):
+    """``served.replica_logs`` through the program's own store: a snapshot
+    counts with its manifest borne out and its state loading; torn (the
+    fault ``torn_snapshot``), it covers nothing."""
+    import served
+    from zeebe_tpu.state.db import ZbDb
+    from zeebe_tpu.state.snapshot import STATE_FILE, FileBasedSnapshotStore
+
+    partition = tmp_path / "broker-0" / "partition-1"
+    (partition / "raft" / "raft-log").mkdir(parents=True)
+    layout = {"brokers": 1, "partitions": 1, "replication_factor": 1}
+    assert served.replica_logs(tmp_path, layout)[(1, "broker-0")][
+        "snapshot_position"] == 0
+    transient = FileBasedSnapshotStore(partition / "snapshots"
+                                       ).new_transient_snapshot(10, 1, 500, 480)
+    transient.write_file(STATE_FILE, ZbDb().to_snapshot_bytes())
+    transient.persist()
+    log = served.replica_logs(tmp_path, layout)[(1, "broker-0")]
+    assert log["snapshot_position"] == 500 and log["snapshot_written_at"] > 0
+    assert served.damage_snapshots(tmp_path) == 1
+    log = served.replica_logs(tmp_path, layout)[(1, "broker-0")]
+    assert log["snapshot_position"] == 0 and log["snapshot_written_at"] is None
+
+
+def test_cores_used_over_a_window_are_the_clocks_differences():
+    before = {"process": 10.0, "pump#7": 4.0, "serve#8": 1.0, "gone#9": 2.0}
+    after = {"process": 19.0, "pump#7": 8.5, "serve#8": 2.0, "new#10": 0.5}
+    assert run.cpu_shares(before, after, 9.0, top=1) == {
+        "process": 1.0, "threads": {"pump": 0.5}}
+    clocks = run.cpu_clocks()       # this thread is in it, under its name
+    assert clocks["process"] > 0 and any(
+        name.startswith("MainThread#") for name in clocks)

@@ -49,23 +49,24 @@ def test_unknown_names_are_refused():
 
 
 def test_a_cell_is_added_as_one_entry_over_files_that_are_there():
-    # default3x3.one_task_closed (PERF.md, Open questions): the deployment
-    # that is there under a mix that is there, with a metric whose file is
-    # there and which no cell reports yet — entries only, no file edited
+    # the single-node deployment under the default deployment's capacity mix:
+    # files that are there, and a metric whose file is there and which no
+    # cell reports yet — entries only, no file edited
     more = json.loads(json.dumps(MANIFEST))
-    more["workloads"].append({"name": "default3x3.one_task_closed",
-                              "config": "zeebe-default-3x3",
-                              "traffic": "one_task_closed", "chips": 1,
-                              "why": "capacity of the default deployment"})
+    name = "single1x1.one_task_capacity"
+    more["workloads"].append({"name": name, "config": "zeebe-single-node-1x1",
+                              "traffic": "one_task_capacity", "chips": 1,
+                              "why": "capacity of one partition, short instances"})
     shared = next(m for m in more["per_layer"] if m["name"] == "commands_per_group")
-    shared["workloads"].append("default3x3.one_task_closed")
-    more["per_layer"].append({**shared, "name": "gateway_shed_share", "unit": "%",
-                              "layer": "gateway",
-                              "workloads": ["default3x3.one_task_closed"]})
-    what = run.resolve_cell("default3x3.one_task_closed", more)
-    assert what["traffic"]["loop"]["kind"] == "closed"
+    shared["workloads"].append(name)
+    more["per_layer"].append({**shared, "name": "mesh_coalesced_share",
+                              "unit": "%", "layer": "mesh runner",
+                              "workloads": [name]})
+    what = run.resolve_cell(name, more)
+    assert what["traffic"]["loop"] == {**what["traffic"]["loop"],
+                                       "kind": "closed", "on": "completion"}
     assert [(m["name"], m["reader"]) for m in what["per_layer"]] == [
-        ("commands_per_group", "ratio"), ("gateway_shed_share", "ratio")]
+        ("commands_per_group", "ratio"), ("mesh_coalesced_share", "ratio")]
     assert {m["name"] for m in what["end_to_end"]} == {"completed_per_s", "setup_s"}
 
 

@@ -16,6 +16,7 @@ LAYOUT_KEYS = {"brokers", "partitions", "replication_factor", "chips",
                "processes"}
 TRAFFIC_KEYS = {"loop", "definitions", "payload", "workers", "give_up_s",
                 "setup"}
+#: what the cell must report; later PRs add metrics and cells beside these
 PER_LAYER = {
     "generator_late_p95_ms", "append_ms_per_group", "raft_elections_in_window",
     "commands_per_group", "device_stage_ms_per_group", "run_collect_roofline",
@@ -70,14 +71,14 @@ def test_every_metric_the_cell_reports_lists_it():
     what = run.resolve_cell(CELL, MANIFEST)
     assert {m["name"] for m in what["end_to_end"]} == {
         "completed_per_s", "completion_p50_ms", "setup_s"}
-    assert {m["name"] for m in what["per_layer"]} == PER_LAYER
+    assert {m["name"] for m in what["per_layer"]} >= PER_LAYER
     for m in MANIFEST["per_layer"]:
         assert CELL in m["workloads"], m["name"]
         assert m["moves"] in {"completed_per_s", "completion_p50_ms"}
     # the two that read a histogram of this PR are read in both cells
     for name in ("admit_wait_ms_per_command", "export_ms_per_record"):
         m = next(m for m in MANIFEST["per_layer"] if m["name"] == name)
-        assert m["workloads"] == ["default3x3.one_task_steady", CELL]
+        assert {"default3x3.one_task_steady", CELL} <= set(m["workloads"])
 
 
 def test_a_program_without_the_histograms_leaves_the_metrics_out():
