@@ -17,14 +17,17 @@ against the plain reference, and prints one JSON line.
 for a rehearsal: it ends with exit code 3 and prints **no** result line.
 ``--fault <name>`` breaks the timed path underneath the comparison — in the
 program's own Raft path (``lying_follower``), on the replicas' disks once the
-cluster has stopped (``torn_snapshot``) or where the harness takes its answers
-(the others) — for the controls and the fault tests
-(benchmarks/tests/); never used by a measurement.
+cluster has stopped (``torn_snapshot``), in the state a deployment is seeded
+with (``lose_parked``), in the running partition's state store as the window
+opens (``forget_parked``) or where the harness takes its answers (the others) —
+for the controls and the fault tests (benchmarks/tests/); never used by a
+measurement.
 """
 
 from __future__ import annotations
 
 import argparse
+import gc
 import importlib.util
 import json
 import math
@@ -48,12 +51,14 @@ import definitions as defs  # noqa: E402
 import reference  # noqa: E402
 import roofline  # noqa: E402
 import schedule  # noqa: E402
+import seed_state  # noqa: E402
 import trace_reduce  # noqa: E402
 
 REHEARSAL_EXIT = 3
 REFUSED_EXIT = 2
 FAULTS = ("lying_follower", "lose_acked", "at_least_once", "alter_record",
-          "replica_export_differs", "torn_snapshot")
+          "replica_export_differs", "torn_snapshot", "lose_parked",
+          "forget_parked")
 
 
 class Refused(Exception):
@@ -253,6 +258,47 @@ def compare(definitions: list, requests: list, observed_events: dict,
             "under_a_snapshot": under_a_snapshot}
 
 
+def parked_checks(seeded: dict, replicas: int, running: dict, logs: dict,
+                  exported: int) -> dict:
+    """A seeded deployment's numbers (``state.parked``), limit 0 each, read
+    twice: from every replica's **running** state once the drain is over
+    (``running``: ``Served.parked_now``, the store the timed path wrote to),
+    and from what every replica's **disk** recovers to after the stop
+    (``logs``: ``served.replica_logs``, the newest snapshot a restart would
+    load and the log behind it).
+    ``parked_missing`` / ``parked_missing_on_disk``: replica by replica, how
+    far the instances it holds are from the count seeded a partition, more or
+    fewer (one replica's loss does not cancel against another's extra); a
+    replica that is not there, or whose disk loads no state, holds none.
+    ``parked_touched``: the exported records that name the parked definition
+    (its job type has no worker) and the instances a running replica holds
+    that no longer wait as they were parked, row for row (``parked_in``);
+    ``parked_touched_on_disk``: those of a recovered state, and the records
+    behind the seed in a replica's log that name the definition (a replay
+    would apply them)."""
+    each = seeded["per_partition"]
+
+    def missing(held: list) -> int:
+        return sum(abs(each - h) for h in held) + each * (replicas - len(held))
+
+    return {
+        "parked_missing": {
+            "value": missing([held for held, _waiting in running.values()]),
+            "limit": 0},
+        "parked_touched": {
+            "value": exported + sum(held - waiting
+                                    for held, waiting in running.values()),
+            "limit": 0},
+        "parked_missing_on_disk": {
+            "value": missing([log["parked_held"] for log in logs.values()]),
+            "limit": 0},
+        "parked_touched_on_disk": {
+            "value": sum(log["parked_in_log"] + log["parked_held"]
+                         - log["parked_waiting"] for log in logs.values()),
+            "limit": 0},
+    }
+
+
 # ---------------------------------------------------------------------------
 # the run
 
@@ -304,6 +350,14 @@ def run(args) -> int:
         loadgen.jobs_to_wait_for(traffic)
     except ValueError as err:
         raise Refused(f"traffic mix {cell['traffic']!r}: {err}") from None
+    state = config.get("state")
+    try:
+        if state is not None:
+            seed_state.refuse_clash(state, traffic["definitions"])
+        elif args.fault in ("lose_parked", "forget_parked"):
+            raise ValueError(f"--fault {args.fault}: no state is seeded")
+    except ValueError as err:
+        raise Refused(f"configuration {cell['config']!r}: {err}") from None
     cache_dir = enable_persistent_cache()
     import jax
 
@@ -344,7 +398,30 @@ def run(args) -> int:
     observed = srv.Observed()
     if args.fault == "lying_follower":
         srv.plant_lying_follower(observed)
-    system = srv.Served(layout, data_dir / "data", observed)
+    try:
+        seeded = None
+        if state is not None:
+            # the deployment starts with state: it is put on the directory
+            # through the program's own doors, and the cluster below
+            # recovers it as a restart would (seed_state.py)
+            seeded = seed_state.seed(layout, state, data_dir / "data",
+                                     srv.EXPORTER_ID,
+                                     lose=args.fault == "lose_parked")
+            observed.parked_id = seeded["id"]
+            say(f"seeded {seeded['instances']} instances in "
+                f"{seeded['seconds']:.2f}s (a cohort of {seeded['cohort']} a "
+                f"partition created by the engine in "
+                f"{seeded['cohort_seconds']:.2f}s, the rest their clones; "
+                f"{seeded['rows']} rows in a partition's snapshot)")
+        recover_start = time.monotonic()
+        system = srv.Served(layout, data_dir / "data", observed)
+    except BaseException:
+        shutil.rmtree(data_dir, ignore_errors=True)
+        raise
+    if seeded is not None:
+        say(f"the cluster recovered the seeded directory in "
+            f"{time.monotonic() - recover_start:.2f}s: {system.state_keys()} "
+            "keys in the largest partition's state")
     child = None
     trace_result = None
     try:
@@ -360,6 +437,19 @@ def run(args) -> int:
         touched = child.ask("first_touch", 300.0)["keys"]
         wait_completed(observed, touched, 120.0, "first touches")
         say(f"first touches done: {ledger.report()}")
+        if seeded is not None:
+            # a replica loads its snapshot as a follower and again on
+            # becoming leader, and the first copy, with the processor that
+            # held it, is garbage in cycles: over a million objects that only
+            # a full collection frees. Left to the allocation counts it came
+            # during the warm-up, a second or three before the window, held
+            # every thread for 1.6 s and opened the window on a backlog of 64
+            # instances (PERF.md, PR 33). It is made here, at a fixed point
+            # of set-up with no request open
+            collect_start = time.monotonic()
+            freed = gc.collect()
+            say(f"the recovery's garbage collected: {freed} objects in "
+                f"{time.monotonic() - collect_start:.2f}s")
         child.ask("warm", 30.0)
         warm_start = time.monotonic()
         time.sleep(float(setup.get("shadow_warm_s", 2.0)))
@@ -382,6 +472,10 @@ def run(args) -> int:
                     f"stayed at {system.replay_debt():.0f}; measuring anyway")
                 break
             time.sleep(0.1)
+        keys_before = system.state_keys() if seeded is not None else 0
+        if args.fault == "forget_parked":
+            say("forget_parked: the running state lost parked instance "
+                f"{system.forget_parked(seeded)}")
         t0 = time.monotonic() + 0.3
         child.send("window", t0=t0, seconds=seconds)
         sleep_until(t0)
@@ -420,6 +514,17 @@ def run(args) -> int:
         shadow = {"checks": health.shadow_checks,
                   "mismatches": health.shadow_mismatches,
                   "state": str(health.state)}
+        running = None
+        if seeded is not None:
+            # the state store the window wrote to, read while it still runs
+            t_read = time.monotonic()
+            running = system.parked_now(seeded)
+            say(f"parked in the running state, (held, waiting) a replica: "
+                f"{sorted(running.values())}, read in "
+                f"{time.monotonic() - t_read:.2f}s; keys in the largest "
+                f"partition's state: {keys_before} as the window opened, "
+                f"{system.state_keys()} now, by family "
+                f"{system.state_keys_by_family()}")
         # the program's state is freed; what its replicas hold is read from
         # their files alone
         system.stop()
@@ -427,7 +532,7 @@ def run(args) -> int:
             say(f"torn_snapshot: {srv.damage_snapshots(data_dir / 'data')} "
                 "snapshots damaged on the stopped replicas' disks")
         t_check = time.monotonic()
-        logs = srv.replica_logs(data_dir / "data", layout)
+        logs = srv.replica_logs(data_dir / "data", layout, seeded)
     finally:
         if child is not None:
             child.close()
@@ -500,6 +605,10 @@ def run(args) -> int:
     checks["device_failures"] = {
         "value": sum(counters_end["failures"].values()), "limit": 0}
     checks["shadow_mismatches"] = {"value": shadow["mismatches"], "limit": 0}
+    if seeded is not None:
+        checks.update(parked_checks(
+            seeded, system.partitions * int(layout["replication_factor"]),
+            running, logs, observed.parked_records))
     correct = decide_correct(checks)
     say(f"check took {time.monotonic() - t_check:.2f}s; examples: "
         f"{verdict['examples']} never completed: {verdict['never']}; "

@@ -18,6 +18,10 @@ DEVICE_FAILURES = ("device-dispatch-error", "device-wedged", "device-quarantined
                    "geometry-bounds", "no-quiesce", "token-overflow",
                    "group-error", "mesh-dispatch-error", "mesh-no-quiesce",
                    "mesh-token-overflow")
+#: the id the benchmark's exporter is attached under: a seeded deployment
+#: (``seed_state.py``) acknowledges its own records under it, so that this
+#: exporter starts behind them
+EXPORTER_ID = "bench"
 
 
 class Observed:
@@ -45,6 +49,10 @@ class Observed:
         self.lies = 0
         #: instance key -> its rank among the instances seen under a fault
         self.ranks: dict = {}
+        #: a seeded deployment's parked definition (``state.parked``), and the
+        #: exported records that name it: none may, its jobs have no worker
+        self.parked_id: str | None = None
+        self.parked_records = 0
 
     def broken(self, key: int) -> bool:
         """Under a fault one instance in ten is broken, counted in the order
@@ -73,6 +81,15 @@ def _broken(event: tuple, fault: str):
         other = "flow_2" if event[2] == "flow_1" else "flow_1"
         return [event[:2] + (other,) + event[3:]]
     return [event]
+
+
+def names_process(record, process_id: str) -> bool:
+    """Whether a record is one of an instance of that process (or a batch of
+    activated jobs with one of its jobs in it)."""
+    value = record.value
+    return (value.get("bpmnProcessId") == process_id
+            or any(job.get("bpmnProcessId") == process_id
+                   for job in value.get("jobs") or ()))
 
 
 def capture_exporter(observed: Observed):
@@ -121,6 +138,8 @@ def capture_exporter(observed: Observed):
                     kept = _broken(event, fault)
             said = (int(record.record_type), int(value_type),
                     int(record.intent), record.key, tuple(kept))
+            parked = (observed.parked_id is not None
+                      and names_process(record, observed.parked_id))
             with observed.lock:
                 first = observed.seen.setdefault(where, said)
                 if first is not said:
@@ -128,6 +147,7 @@ def capture_exporter(observed: Observed):
                     observed.differing += first != said
                 else:
                     observed.records += 1
+                    observed.parked_records += parked
                     if acked is not None:
                         observed.position_of[acked] = logged.position
                     if kept:
@@ -209,7 +229,7 @@ class Served:
         mesh = ({"kernel_mesh_shards": int(layout["kernel_mesh_shards"])}
                 if "kernel_mesh_shards" in layout else {})
         self.runtime = ClusterRuntime(
-            exporters_factory=lambda: {"bench": capture_exporter(observed)},
+            exporters_factory=lambda: {EXPORTER_ID: capture_exporter(observed)},
             kernel_backend=cfg.base.kernel_backend,
             broker_count=int(layout["brokers"]),
             partition_count=self.partitions,
@@ -285,6 +305,23 @@ class Served:
                 # thread: how many fell in the window and what they took
                 out["snapshot_count"] += value[0]
                 out["snapshot_seconds"] += value[1]
+            elif kind == "histogram" and name.endswith(
+                    "stream_processor_batch_processing_duration"):
+                # a kernel group from its admission to its flush, or one
+                # command on the sequential path: the transaction's commit is
+                # inside it and inside no stage (the state store's own work
+                # on every key a group wrote or deleted)
+                out["group_count"] += value[0]
+                out["group_seconds"] += value[1]
+            elif kind == "histogram" and name.endswith(
+                    "stream_processor_processing_duration"):
+                # the sequential path alone (ActivateJobs, a deployment)
+                out["sequential_count"] += value[0]
+                out["sequential_seconds"] += value[1]
+        # the first histogram observes both paths, the second the sequential
+        # one: their difference is the kernel groups'
+        out["group_count"] -= out["sequential_count"]
+        out["group_seconds"] -= out["sequential_seconds"]
         runner = self.mesh_runner()
         if runner is not None:
             out["mesh_dispatches"] = runner.dispatches
@@ -296,6 +333,37 @@ class Served:
                 "shard_devices": sorted(device_name(d)
                                         for d in runner.shard_devices)
                 if runner is not None else []}
+
+    def state_keys(self) -> int:
+        """Committed keys in the largest partition's state, over all
+        replicas: what a seeded deployment starts its window with."""
+        return max(replica.db.key_count
+                   for pid in range(1, self.partitions + 1)
+                   for replica in self.replicas(pid))
+
+    def state_keys_by_family(self) -> dict:
+        """The same state's committed keys by column family."""
+        return max((replica.db for pid in range(1, self.partitions + 1)
+                    for replica in self.replicas(pid)),
+                   key=lambda db: db.key_count).key_counts_by_cf()
+
+    def parked_now(self, parked: dict) -> dict:
+        """(partition, broker) -> ``parked_in`` over that replica's **running**
+        state, under its partition's guard: what the window left in the store
+        the timed path wrote to, read before anything is stopped."""
+        out = {}
+        for name, broker in self.runtime.brokers.items():
+            for pid, replica in broker.partitions.items():
+                with self.runtime._partition_guard(pid):
+                    out[(pid, name)] = parked_in(replica.db, parked["id"],
+                                                 parked["variables"])
+        return out
+
+    def forget_parked(self, parked: dict) -> int:
+        """The fault: partition 1's leader forgets one parked instance."""
+        with self.runtime._partition_guard(1):
+            return forget_parked(self.runtime._leader_partition(1).db,
+                                 parked["id"])
 
     def replay_debt(self) -> float:
         """The program's ``snapshot_replay_debt_records``, the largest of the
@@ -326,7 +394,81 @@ class Served:
                        if kind == "counter" and name.endswith("raft_elections_total")))
 
 
-def replica_logs(data_dir: Path, layout: dict) -> dict:
+def parked_in(db, process_id: str, variables: dict | None = None) -> tuple:
+    """(held, waiting): the instances of that process which a state holds (a
+    running replica's, read under its partition's guard, or one a snapshot
+    loads to), and those of them that wait as they were parked, row for row:
+    every active child of the instance an element with a job and the
+    instance's entry for it in the parent-child index, the job there,
+    activatable and in its type's index of activatable jobs, and each of
+    ``variables`` on the instance's scope at its value."""
+    from zeebe_tpu.engine.engine_state import JOB_ACTIVATABLE
+    from zeebe_tpu.state import ColumnFamilyCode as CF
+
+    with db.transaction():
+        roots, children = {}, {}
+        for _key, row in db.column_family(CF.ELEMENT_INSTANCE_KEY).items():
+            value = row["value"]
+            if value["bpmnProcessId"] != process_id:
+                continue
+            if value["flowScopeKey"] < 0:
+                roots[row["key"]] = row["activeChildren"]
+            elif row["jobKey"] > 0:
+                children.setdefault(value["flowScopeKey"], []).append(
+                    (row["key"], row["jobKey"]))
+        job_cf, states = db.column_family(CF.JOBS), db.column_family(CF.JOB_STATES)
+        index = db.column_family(CF.JOB_ACTIVATABLE)
+        family = db.column_family(CF.ELEMENT_INSTANCE_PARENT_CHILD)
+        scoped = db.column_family(CF.VARIABLES)
+        wanted = list((variables or {}).items())
+        waiting = 0
+        for instance, active in roots.items():
+            mine = children.get(instance, ())
+            waiting += active > 0 and len(mine) == active and all(
+                family.exists((instance, child))
+                and (job := job_cf.get((key,))) is not None
+                and states.get((key,)) == JOB_ACTIVATABLE
+                and index.exists((job["type"], job.get("tenantId", "<default>"),
+                                  key))
+                for child, key in mine) and all(
+                scoped.get((instance, name)) == value for name, value in wanted)
+    return len(roots), waiting
+
+
+def forget_parked(db, process_id: str) -> int:
+    """The fault ``forget_parked``: the first parked instance in key order
+    leaves a running replica's state with every row of its own, as a state
+    store that dropped them would leave it; the snapshot on the disk still
+    has them. Called under the partition's guard. Returns the instance's
+    key."""
+    from zeebe_tpu.state import ColumnFamilyCode as CF
+    from zeebe_tpu.state.db import decode_key
+
+    with db.transaction():
+        instances = db.column_family(CF.ELEMENT_INSTANCE_KEY)
+        root = next(row["key"] for _key, row in instances.items()
+                    if row["value"]["bpmnProcessId"] == process_id
+                    and row["value"]["flowScopeKey"] < 0)
+        family = db.column_family(CF.ELEMENT_INSTANCE_PARENT_CHILD)
+        scoped = db.column_family(CF.VARIABLES)
+        for key, _none in list(family.items((root,))):
+            child = decode_key(key)[1][1]
+            job_key = instances.get((child,))["jobKey"]
+            job = db.column_family(CF.JOBS).get((job_key,))
+            listed = (job["type"], job.get("tenantId", "<default>"), job_key)
+            if db.column_family(CF.JOB_ACTIVATABLE).exists(listed):
+                db.column_family(CF.JOB_ACTIVATABLE).delete(listed)
+            db.column_family(CF.JOB_STATES).delete((job_key,))
+            db.column_family(CF.JOBS).delete((job_key,))
+            instances.delete((child,))
+            family.delete((root, child))
+        for key, _value in list(scoped.items((root,))):
+            scoped.delete(decode_key(key)[1])
+        instances.delete((root,))
+    return root
+
+
+def replica_logs(data_dir: Path, layout: dict, parked: dict | None = None) -> dict:
     """(partition, broker) -> what that replica's Raft log holds **on disk**,
     read once the cluster is stopped and with none of its memory: ``entries``
     (raft index -> the entry's bytes), ``created`` (the instance keys whose
@@ -339,7 +481,16 @@ def replica_logs(data_dir: Path, layout: dict) -> dict:
     It counts only if the program's own store finds its chain valid (every
     file against the CRC in its manifest, every delta's parent present) and
     ``load_chain_db`` builds a state from it; ``snapshot_written_at`` is
-    when it was persisted (``time.time()``)."""
+    when it was persisted (``time.time()``).
+
+    ``parked`` (a seeded deployment: what ``seed_state.seed`` returned) adds
+    what the replica's disk recovers to for the parked instances (the running
+    state is ``Served.parked_now``'s to read, before the stop):
+    ``parked_held`` and ``parked_waiting`` (``parked_in`` over the state that
+    snapshot loads to; 0 where none loads) and ``parked_in_log``, the records
+    behind the seed's last position that name the parked definition: with
+    none, a replay of the log leaves the parked rows as the snapshot has
+    them."""
     from zeebe_tpu.journal.journal import read_only_records
     from zeebe_tpu.logstreams.log_stream import _deserialize_batch
     from zeebe_tpu.protocol import ValueType
@@ -355,7 +506,7 @@ def replica_logs(data_dir: Path, layout: dict) -> dict:
             log_dir = data_dir / name / f"partition-{pid}" / "raft" / "raft-log"
             if not log_dir.is_dir():
                 continue
-            entries, created, jobs = {}, set(), set()
+            entries, created, jobs, in_log = {}, set(), set(), 0
             for journal_record in read_only_records(log_dir):
                 entry = unpackb(journal_record.data)
                 data = entry.get("data")
@@ -364,6 +515,10 @@ def replica_logs(data_dir: Path, layout: dict) -> dict:
                 entries[journal_record.index] = bytes(data)
                 for logged in _deserialize_batch(data, pid):
                     record = logged.record
+                    if (parked is not None
+                            and logged.position > parked["end_position"][pid]
+                            and names_process(record, parked["id"])):
+                        in_log += 1
                     if not record.is_event:
                         continue
                     if (record.value_type == ValueType.PROCESS_INSTANCE_CREATION
@@ -372,22 +527,30 @@ def replica_logs(data_dir: Path, layout: dict) -> dict:
                     elif (record.value_type == ValueType.JOB
                           and record.intent == JobIntent.COMPLETED):
                         jobs.add(record.key)
-            covered, written_at = 0, None
+            covered, written_at, db = 0, None, None
             store = data_dir / name / f"partition-{pid}" / "snapshots"
             if store.is_dir():
                 # as a restart would: the store drops what its manifest does
                 # not bear out and recovery takes the newest chain that loads
                 chain = FileBasedSnapshotStore(store).latest_valid_chain()
                 try:
-                    if chain is not None and load_chain_db(chain) is not None:
+                    if (chain is not None
+                            and (db := load_chain_db(chain)) is not None):
                         covered = chain[-1].id.processed_position
                         written_at = chain[-1].path.stat().st_mtime
                 except Exception:  # noqa: BLE001 — it does not load: not held
-                    pass
+                    db = None
+            held, waiting = ((0, 0) if parked is None or db is None
+                             else parked_in(db, parked["id"], parked["variables"]))
+            del db      # a seeded state is a million rows: one at a time
             out[(pid, name)] = {"entries": entries, "created": created,
                                 "jobs_completed": jobs,
                                 "snapshot_position": covered,
                                 "snapshot_written_at": written_at}
+            if parked is not None:
+                out[(pid, name)].update(parked_held=held,
+                                        parked_waiting=waiting,
+                                        parked_in_log=in_log)
     return out
 
 
