@@ -71,7 +71,7 @@ def _fingerprint(h):
 
 def _state_image(h):
     db = h.engine.state.db
-    return {k: db._data[k] for k in db._sorted_keys}
+    return {k: db._data[k] for k in db._index}
 
 
 def _run(scenario, mode):

@@ -446,3 +446,92 @@ class TestNativeEncodeKey:
                 D.encode_key(D.ColumnFamilyCode.JOBS, bad)
             with pytest.raises(exc):
                 D._encode_key_py(D.ColumnFamilyCode.JOBS, bad)
+
+
+class TestNativeKeyIndexPasses:
+    """codec.c commit_overlay and iterate_snapshot on the blocked key index
+    (state/db.BlockedKeyIndex) vs the Python loops in state/db.py, their
+    spec: the same dict, the same blocks and maxima, the same merge."""
+
+    @staticmethod
+    def _twins(load, monkeypatch):
+        from zeebe_tpu.state import db as D
+
+        if D._commit_overlay is None or D._iterate_snapshot is None:
+            pytest.skip("native codec unavailable")
+        monkeypatch.setattr(D, "LOAD", load)
+        native, pure = D.ZbDb(), D.ZbDb()
+        pure._native_commit = pure._native_iterate = None
+        return D, native, pure
+
+    @pytest.mark.parametrize("load", [1, 3, 16])
+    def test_commit_overlay_leaves_the_blocks_the_python_loop_leaves(
+            self, load, monkeypatch):
+        D, native, pure = self._twins(load, monkeypatch)
+        rng = random.Random(load)
+        shapes_changed = 0
+        for _ in range(400):
+            writes = {}
+            for _ in range(rng.randint(0, 10)):
+                key = bytes([rng.randrange(3), rng.randrange(40)])
+                writes[key] = (D._DELETED if rng.random() < 0.45
+                               else rng.randrange(100))
+            before = native._index.lists
+            shape = [list(before[0]), [id(b) for b in before[1]]]
+            for store in (native, pure):
+                with store.transaction() as txn:
+                    for key, val in writes.items():
+                        if val is D._DELETED:
+                            txn.delete(key)
+                        else:
+                            txn.put(key, val)
+            assert native._data == pure._data
+            assert native._index.lists == pure._index.lists
+            if native._index.lists is not before:
+                # a split or a drop: a new pair, and the one a reader may
+                # still hold keeps its blocks, as objects and in number
+                shapes_changed += 1
+                assert len(before[0]) == len(before[1]) == len(shape[0])
+                assert [id(b) for b in before[1]] == shape[1]
+            else:
+                assert len(before[1]) == len(shape[1])
+        assert shapes_changed > 2
+
+    def test_iterate_snapshot_merges_as_the_python_loop_does(self, monkeypatch):
+        D, native, pure = self._twins(2, monkeypatch)
+        rng = random.Random(9)
+        keys = [bytes([p, s]) for p in range(4) for s in range(0, 60, 3)]
+        for store in (native, pure):
+            with store.transaction() as txn:
+                for key in keys:
+                    txn.put(key, {"k": list(key)})
+        assert native.index_block_count > 20
+        for _ in range(60):
+            overlay = [(bytes([rng.randrange(5), rng.randrange(64)]),
+                        rng.random() < 0.4) for _ in range(rng.randint(0, 8))]
+            got = []
+            for store in (native, pure):
+                with store.transaction() as txn:
+                    for key, delete in overlay:
+                        if delete:
+                            txn.delete(key)
+                        else:
+                            txn.put(key, "new")
+                    got.append([list(txn.iterate(prefix)) for prefix in
+                                (b"", b"\x00", b"\x01", b"\x03", b"\x04",
+                                 b"\x02\x09", b"\xff", b"\x01\x1e")])
+                    txn.rollback()
+            assert got[0] == got[1]
+
+    def test_bad_index_arguments_are_refused(self):
+        from zeebe_tpu.state import db as D
+
+        if D._commit_overlay is None:
+            pytest.skip("native codec unavailable")
+        for lists in (([], [[]]), ([b"a"], [(b"a",)]), [[], []], ([],)):
+            with pytest.raises(TypeError):
+                D._commit_overlay({b"a": 1}, {}, lists, 4, D._DELETED)
+            with pytest.raises(TypeError):
+                D._iterate_snapshot(lists, {}, b"a", [], {}, D._DELETED, {})
+        with pytest.raises(TypeError):
+            D._commit_overlay({b"a": 1}, {}, ([], []), 0, D._DELETED)

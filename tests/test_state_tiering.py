@@ -105,8 +105,8 @@ class TestBulkLoad:
             else:
                 final.pop(key, None)
         assert dict(incr._data) == final
-        assert incr._sorted_keys == sorted(incr._data)
-        assert bulk._sorted_keys == sorted(bulk._data)
+        assert list(incr._index) == sorted(incr._data)
+        assert list(bulk._index) == sorted(bulk._data)
 
     def test_delta_bulk_path_parity(self):
         """apply_delta_bytes takes the bulk path on large deltas and the
@@ -130,7 +130,7 @@ class TestBulkLoad:
         for i in (0, 999, 1999):
             key = encode_key(CF.VARIABLES, (i, "v"))
             assert big._data[key] == {"i": i} == small._data[key]
-        assert list(big._sorted_keys) == sorted(big._data)
+        assert list(big._index) == sorted(big._data)
 
     def test_load_snapshot_bytes_roundtrip(self):
         db = ZbDb()
@@ -141,7 +141,7 @@ class TestBulkLoad:
         fresh = ZbDb()
         assert fresh.load_snapshot_bytes(db.to_snapshot_bytes()) == 500
         assert fresh.content_equals(db)
-        assert list(fresh._sorted_keys) == sorted(fresh._data)
+        assert list(fresh._index) == sorted(fresh._data)
 
 
 # ---------------------------------------------------------------------------
@@ -411,7 +411,7 @@ class TestTieredZbDb:
         dst = TieredZbDb(tmp_path)
         dst.load_snapshot_bytes(raw)
         assert dst.content_equals(src)
-        assert list(dst._sorted_keys) == sorted(dst._data)
+        assert list(dst._index) == sorted(dst._data)
         dst.close()
 
     def test_key_counts_by_cf(self, tmp_path):

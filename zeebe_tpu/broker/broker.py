@@ -299,6 +299,11 @@ class Broker:
                 "state_keys",
                 "committed state keys per column family",
                 ("node", "partition", "cf")),
+            "state_index_blocks": REGISTRY.gauge(
+                "state_index_blocks",
+                "blocks in the committed-key index of a partition's state "
+                "(state/db.py BlockedKeyIndex: a commit moves one of them)",
+                ("node", "partition")),
             "tier_bytes": REGISTRY.gauge(
                 "state_tier_bytes",
                 "state bytes per tier (hot = estimated packed size of "
@@ -875,6 +880,8 @@ class Broker:
                     self._metrics["state_keys"].labels(
                         node, label, cf_name).set(float(count))
                 seen.update(counts)
+                self._metrics["state_index_blocks"].labels(node, label).set(
+                    float(db.index_block_count))
                 stats = (db.tier_stats() if hasattr(db, "tier_stats")
                          else None)
                 if stats is not None:

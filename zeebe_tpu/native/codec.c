@@ -1268,80 +1268,261 @@ static int insort_bytes(PyObject *list, PyObject *key)
     return PyList_Insert(list, lo, key);
 }
 
-/* commit_overlay(writes, data, sorted_keys, deleted):
- * Transaction.commit's apply loop, natively — for each (key, val) in the
- * overlay dict: a deleted-sentinel val removes the key from the committed
- * dict and its sorted-keys list; any other val upserts (insort on first
- * insert). Mirrors ZbDb._put_committed/_delete_committed exactly. */
-static PyObject *codec_commit_overlay(PyObject *self, PyObject *args)
+/* The committed-key index (state/db.py BlockedKeyIndex) as its pair of plain
+ * lists: `blocks`, ascending lists of keys, and `maxes`, each block's last
+ * key. The rule of db.py holds here too: a key goes into or out of its block
+ * in place, but a split or a drop never changes the shape of a pair a reader
+ * on another thread may hold: it works on fresh copies of the two lists,
+ * which commit_overlay hands back as a new pair. */
+typedef struct {
+    PyObject *maxes, *blocks; /* borrowed from the pair until `fresh` */
+    int fresh;                /* both lists are private copies, owned */
+} KeyIndex;
+
+static int index_open(PyObject *lists, KeyIndex *ix)
 {
-    PyObject *writes, *data, *sorted_keys, *deleted;
-    if (!PyArg_ParseTuple(args, "OOOO", &writes, &data, &sorted_keys, &deleted))
-        return NULL;
-    if (!PyDict_CheckExact(writes) || !PyDict_CheckExact(data)
-        || !PyList_CheckExact(sorted_keys)) {
-        PyErr_SetString(PyExc_TypeError,
-                        "commit_overlay(dict, dict, list, obj) expected");
+    if (!PyTuple_CheckExact(lists) || PyTuple_GET_SIZE(lists) != 2)
+        goto bad;
+    ix->maxes = PyTuple_GET_ITEM(lists, 0);
+    ix->blocks = PyTuple_GET_ITEM(lists, 1);
+    ix->fresh = 0;
+    if (PyList_CheckExact(ix->maxes) && PyList_CheckExact(ix->blocks)
+        && PyList_GET_SIZE(ix->maxes) == PyList_GET_SIZE(ix->blocks))
+        return 0;
+bad:
+    PyErr_SetString(PyExc_TypeError,
+                    "key index: a pair of lists of one length expected");
+    return -1;
+}
+
+/* before a change of shape: private copies of the two lists, once */
+static int index_unshare(KeyIndex *ix)
+{
+    if (ix->fresh)
+        return 0;
+    Py_ssize_t n = PyList_GET_SIZE(ix->blocks);
+    PyObject *maxes = PyList_GetSlice(ix->maxes, 0, n);
+    PyObject *blocks = maxes ? PyList_GetSlice(ix->blocks, 0, n) : NULL;
+    if (!blocks) {
+        Py_XDECREF(maxes);
+        return -1;
+    }
+    ix->maxes = maxes;
+    ix->blocks = blocks;
+    ix->fresh = 1;
+    return 0;
+}
+
+/* blocks[i] as an exact list (borrowed), or NULL with an error set */
+static PyObject *index_block(PyObject *blocks, Py_ssize_t i)
+{
+    PyObject *block = PyList_GET_ITEM(blocks, i);
+    if (!PyList_CheckExact(block)) {
+        PyErr_SetString(PyExc_TypeError, "key index: a block is not a list");
         return NULL;
     }
+    return block;
+}
+
+/* position of the first key >= `key`: its block in *bi, its slot in *ki;
+ * *bi == len(blocks) and *ki == 0 when every key is smaller */
+static int index_locate(const KeyIndex *ix, PyObject *key,
+                        Py_ssize_t *bi, Py_ssize_t *ki)
+{
+    *bi = bisect_left_bytes(ix->maxes, key);
+    *ki = 0;
+    if (*bi < 0)
+        return -1;
+    if (*bi < PyList_GET_SIZE(ix->blocks)) {
+        PyObject *block = index_block(ix->blocks, *bi);
+        if (!block || (*ki = bisect_left_bytes(block, key)) < 0)
+            return -1;
+    }
+    return 0;
+}
+
+/* BlockedKeyIndex.add: `key` is not in the index */
+static int index_add(KeyIndex *ix, PyObject *key, Py_ssize_t load)
+{
+    Py_ssize_t n = PyList_GET_SIZE(ix->maxes);
+    Py_ssize_t i = bisect_left_bytes(ix->maxes, key);
+    if (i < 0)
+        return -1;
+    PyObject *block;
+    if (i == n && n == 0) {
+        block = PyList_New(1);
+        if (!block)
+            return -1;
+        Py_INCREF(key);
+        PyList_SET_ITEM(block, 0, key);
+        int rc = index_unshare(ix);
+        if (rc == 0)
+            rc = PyList_Append(ix->blocks, block);
+        Py_DECREF(block);
+        return rc < 0 ? -1 : PyList_Append(ix->maxes, key);
+    }
+    if (i == n) {
+        i = n - 1;
+        if (!(block = index_block(ix->blocks, i))
+            || PyList_Append(block, key) < 0)
+            return -1;
+        Py_INCREF(key);
+        if (PyList_SetItem(ix->maxes, i, key) < 0)
+            return -1;
+    } else {
+        if (!(block = index_block(ix->blocks, i))
+            || insort_bytes(block, key) < 0)
+            return -1;
+    }
+    Py_ssize_t len = PyList_GET_SIZE(block);
+    if (len <= 2 * load)
+        return 0;
+    /* split: two new halves take the block's place, the left half's last
+     * key joins the maxima; the old block is left as it is */
+    Py_ssize_t half = len >> 1;
+    PyObject *left = PyList_GetSlice(block, 0, half);
+    PyObject *right = PyList_GetSlice(block, half, len);
+    PyObject *halves = (left && right) ? PyList_New(2) : NULL;
+    if (!halves) {
+        Py_XDECREF(left);
+        Py_XDECREF(right);
+        return -1;
+    }
+    PyObject *left_max = PyList_GET_ITEM(left, half - 1);
+    Py_INCREF(left_max);
+    PyList_SET_ITEM(halves, 0, left);
+    PyList_SET_ITEM(halves, 1, right);
+    int rc = index_unshare(ix);
+    if (rc == 0)
+        rc = PyList_SetSlice(ix->blocks, i, i + 1, halves);
+    if (rc == 0)
+        rc = PyList_Insert(ix->maxes, i, left_max);
+    Py_DECREF(halves);
+    Py_DECREF(left_max);
+    return rc;
+}
+
+/* BlockedKeyIndex.discard */
+static int index_discard(KeyIndex *ix, PyObject *key)
+{
+    Py_ssize_t i, j;
+    if (index_locate(ix, key, &i, &j) < 0)
+        return -1;
+    if (i == PyList_GET_SIZE(ix->blocks))
+        return 0;
+    PyObject *block = PyList_GET_ITEM(ix->blocks, i);
+    if (j == PyList_GET_SIZE(block))
+        return 0;
+    int eq = PyObject_RichCompareBool(PyList_GET_ITEM(block, j), key, Py_EQ);
+    if (eq <= 0)
+        return eq;
+    if (PySequence_DelItem(block, j) < 0)
+        return -1;
+    Py_ssize_t len = PyList_GET_SIZE(block);
+    if (len == 0) {
+        if (index_unshare(ix) < 0 || PySequence_DelItem(ix->maxes, i) < 0)
+            return -1;
+        return PySequence_DelItem(ix->blocks, i);
+    }
+    if (j == len) {
+        PyObject *last = PyList_GET_ITEM(block, len - 1);
+        Py_INCREF(last);
+        return PyList_SetItem(ix->maxes, i, last);
+    }
+    return 0;
+}
+
+/* commit_overlay(writes, data, lists, load, deleted) -> lists:
+ * Transaction.commit's apply loop, natively — for each (key, val) in the
+ * overlay dict: a deleted-sentinel val removes the key from the committed
+ * dict and the key index; any other val upserts (an index insert on first
+ * insert; a block splits past 2 * load keys). Mirrors
+ * ZbDb._put_committed/_delete_committed and BlockedKeyIndex.add/discard
+ * exactly. Returns the index's pair: the one it was handed, or a new one
+ * when a block split or was dropped. */
+static int commit_overlay_apply(PyObject *writes, PyObject *data,
+                                KeyIndex *ix, Py_ssize_t load,
+                                PyObject *deleted)
+{
     PyObject *key, *val;
     Py_ssize_t pos = 0;
     while (PyDict_Next(writes, &pos, &key, &val)) {
         int present = PyDict_Contains(data, key);
         if (present < 0)
-            return NULL;
+            return -1;
         if (val == deleted) {
             if (!present)
                 continue;
-            if (PyDict_DelItem(data, key) < 0)
-                return NULL;
-            /* locate the key in the sorted list (bisect_left + equality) */
-            Py_ssize_t lo = bisect_left_bytes(sorted_keys, key);
-            if (lo < 0)
-                return NULL;
-            if (lo < PyList_GET_SIZE(sorted_keys)) {
-                int eq = PyObject_RichCompareBool(
-                    PyList_GET_ITEM(sorted_keys, lo), key, Py_EQ);
-                if (eq < 0)
-                    return NULL;
-                if (eq && PySequence_DelItem(sorted_keys, lo) < 0)
-                    return NULL;
-            }
+            if (PyDict_DelItem(data, key) < 0 || index_discard(ix, key) < 0)
+                return -1;
         } else {
-            if (!present && insort_bytes(sorted_keys, key) < 0)
-                return NULL;
+            if (!present && index_add(ix, key, load) < 0)
+                return -1;
             if (PyDict_SetItem(data, key, val) < 0)
-                return NULL;
+                return -1;
         }
     }
-    Py_RETURN_NONE;
+    return 0;
 }
 
-/* iterate_snapshot(sorted_keys, data, prefix, sorted_writes, writes,
- *                  deleted, reads_cache):
+static PyObject *codec_commit_overlay(PyObject *self, PyObject *args)
+{
+    PyObject *writes, *data, *lists, *deleted;
+    Py_ssize_t load;
+    KeyIndex ix;
+    if (!PyArg_ParseTuple(args, "OOOnO", &writes, &data, &lists, &load,
+                          &deleted))
+        return NULL;
+    if (!PyDict_CheckExact(writes) || !PyDict_CheckExact(data) || load < 1) {
+        PyErr_SetString(PyExc_TypeError,
+                        "commit_overlay(dict, dict, (list, list), int >= 1, "
+                        "obj) expected");
+        return NULL;
+    }
+    if (index_open(lists, &ix) < 0)
+        return NULL;
+    PyObject *out = NULL;
+    if (commit_overlay_apply(writes, data, &ix, load, deleted) == 0)
+        out = ix.fresh ? PyTuple_Pack(2, ix.maxes, ix.blocks)
+                       : Py_NewRef(lists);
+    if (ix.fresh) {
+        Py_DECREF(ix.maxes);
+        Py_DECREF(ix.blocks);
+    }
+    return out;
+}
+
+/* iterate_snapshot(lists, data, prefix, sorted_writes, writes, deleted,
+ *                  reads_cache):
  * Transaction.iterate's merge, natively — one pass building the ordered
- * committed-union-overlay snapshot list for a prefix range. Committed
- * values go through the same defensive-copy-and-cache discipline as
- * Transaction._committed_read (dict/list values are shallow-copied once
- * per transaction via reads_cache); overlay values are returned verbatim
- * with deleted-sentinel entries dropped. Both inputs are sorted, so the
- * output merges in order with no final sort. */
+ * committed-union-overlay snapshot list for a prefix range over the key
+ * index's pair `lists`. Committed values go through the same
+ * defensive-copy-and-cache discipline as Transaction._committed_read
+ * (dict/list values are shallow-copied once per transaction via
+ * reads_cache); overlay values are returned verbatim with deleted-sentinel
+ * entries dropped. Both inputs are sorted, so the output merges in order
+ * with no final sort. */
 static PyObject *codec_iterate_snapshot(PyObject *self, PyObject *args)
 {
-    PyObject *sorted_keys, *data, *prefix, *sorted_writes, *writes, *deleted,
+    PyObject *lists, *data, *prefix, *sorted_writes, *writes, *deleted,
         *reads;
-    if (!PyArg_ParseTuple(args, "OOOOOOO", &sorted_keys, &data, &prefix,
+    KeyIndex ix;
+    if (!PyArg_ParseTuple(args, "OOOOOOO", &lists, &data, &prefix,
                           &sorted_writes, &writes, &deleted, &reads))
         return NULL;
-    if (!PyList_CheckExact(sorted_keys) || !PyDict_CheckExact(data)
+    if (!PyDict_CheckExact(data)
         || !PyBytes_CheckExact(prefix) || !PyList_CheckExact(sorted_writes)
         || !PyDict_CheckExact(writes) || !PyDict_CheckExact(reads)) {
         PyErr_SetString(PyExc_TypeError,
-                        "iterate_snapshot(list, dict, bytes, list, dict, obj, "
-                        "dict) expected");
+                        "iterate_snapshot((list, list), dict, bytes, list, "
+                        "dict, obj, dict) expected");
         return NULL;
     }
-    /* range bounds: [prefix, successor(prefix)) on both sorted lists */
+    if (index_open(lists, &ix) < 0)
+        return NULL;
+    PyObject *blocks = ix.blocks;
+    /* range bounds: [prefix, successor(prefix)) on the index and the
+     * overlay's sorted list */
     Py_ssize_t plen = PyBytes_GET_SIZE(prefix);
     PyObject *end = NULL; /* NULL = unbounded */
     {
@@ -1350,54 +1531,73 @@ static PyObject *codec_iterate_snapshot(PyObject *self, PyObject *args)
         while (n > 0 && (unsigned char)p[n - 1] == 0xFF)
             n--;
         if (n > 0) {
-            end = PyBytes_FromStringAndSize(p, n);
+            /* built in a buffer of its own: FromStringAndSize(p, 1) hands
+             * out the interpreter's shared one-byte objects */
+            end = PyBytes_FromStringAndSize(NULL, n);
             if (!end)
                 return NULL;
+            memcpy(PyBytes_AS_STRING(end), p, (size_t)n);
             ((unsigned char *)PyBytes_AS_STRING(end))[n - 1]++;
         }
     }
-    Py_ssize_t clo = bisect_left_bytes(sorted_keys, prefix);
-    Py_ssize_t chi = end ? bisect_left_bytes(sorted_keys, end)
-                         : PyList_GET_SIZE(sorted_keys);
+    /* the committed cursor: block cb, slot ck, up to block eb, slot ek */
+    Py_ssize_t nblocks = PyList_GET_SIZE(blocks);
+    Py_ssize_t cb, ck, eb = nblocks, ek = 0;
+    int located = index_locate(&ix, prefix, &cb, &ck);
+    if (located == 0 && end)
+        located = index_locate(&ix, end, &eb, &ek);
     Py_ssize_t wlo = bisect_left_bytes(sorted_writes, prefix);
     Py_ssize_t whi = end ? bisect_left_bytes(sorted_writes, end)
                          : PyList_GET_SIZE(sorted_writes);
     Py_XDECREF(end);
-    if (clo < 0 || chi < 0 || wlo < 0 || whi < 0)
+    if (located < 0 || wlo < 0 || whi < 0)
         return NULL;
+    for (Py_ssize_t i = cb; i < nblocks && i <= eb; i++)
+        if (!index_block(blocks, i))
+            return NULL;
     PyObject *out = PyList_New(0);
     if (!out)
         return NULL;
-    Py_ssize_t ci = clo, wi = wlo;
-    while (ci < chi || wi < whi) {
+    Py_ssize_t wi = wlo;
+    for (;;) {
+        /* step the committed cursor over block ends (and empty blocks) */
+        int committed_left;
+        while ((committed_left = cb < nblocks
+                                 && (cb < eb || (cb == eb && ck < ek)))
+               && ck >= PyList_GET_SIZE(PyList_GET_ITEM(blocks, cb))) {
+            cb++;
+            ck = 0;
+        }
+        if (!committed_left && wi >= whi)
+            break;
         PyObject *key;
         PyObject *val;
         int from_overlay;
         if (wi >= whi) {
             from_overlay = 0;
-            key = PyList_GET_ITEM(sorted_keys, ci);
-            ci++;
-        } else if (ci >= chi) {
+            key = PyList_GET_ITEM(PyList_GET_ITEM(blocks, cb), ck);
+            ck++;
+        } else if (!committed_left) {
             from_overlay = 1;
             key = PyList_GET_ITEM(sorted_writes, wi);
             wi++;
         } else {
-            PyObject *ck = PyList_GET_ITEM(sorted_keys, ci);
+            PyObject *ckey = PyList_GET_ITEM(PyList_GET_ITEM(blocks, cb), ck);
             PyObject *wk = PyList_GET_ITEM(sorted_writes, wi);
             int cmp;
-            if (PyBytes_CheckExact(ck) && PyBytes_CheckExact(wk)) {
-                Py_ssize_t cl = PyBytes_GET_SIZE(ck), wl = PyBytes_GET_SIZE(wk);
+            if (PyBytes_CheckExact(ckey) && PyBytes_CheckExact(wk)) {
+                Py_ssize_t cl = PyBytes_GET_SIZE(ckey), wl = PyBytes_GET_SIZE(wk);
                 Py_ssize_t n = cl < wl ? cl : wl;
-                int c = memcmp(PyBytes_AS_STRING(ck), PyBytes_AS_STRING(wk),
+                int c = memcmp(PyBytes_AS_STRING(ckey), PyBytes_AS_STRING(wk),
                                (size_t)n);
                 cmp = c != 0 ? c : (cl < wl ? -1 : (cl > wl ? 1 : 0));
             } else {
-                int lt = PyObject_RichCompareBool(ck, wk, Py_LT);
+                int lt = PyObject_RichCompareBool(ckey, wk, Py_LT);
                 if (lt < 0)
                     goto fail;
                 cmp = lt ? -1 : 1;
                 if (!lt) {
-                    int eq = PyObject_RichCompareBool(ck, wk, Py_EQ);
+                    int eq = PyObject_RichCompareBool(ckey, wk, Py_EQ);
                     if (eq < 0)
                         goto fail;
                     if (eq)
@@ -1406,8 +1606,8 @@ static PyObject *codec_iterate_snapshot(PyObject *self, PyObject *args)
             }
             if (cmp < 0) {
                 from_overlay = 0;
-                key = ck;
-                ci++;
+                key = ckey;
+                ck++;
             } else if (cmp > 0) {
                 from_overlay = 1;
                 key = wk;
@@ -1416,7 +1616,7 @@ static PyObject *codec_iterate_snapshot(PyObject *self, PyObject *args)
                 /* overlay supersedes the committed entry */
                 from_overlay = 1;
                 key = wk;
-                ci++;
+                ck++;
                 wi++;
             }
         }

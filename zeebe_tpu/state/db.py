@@ -9,7 +9,11 @@ keyspace via an enum prefix — but host-memory-resident: the data set a
 partition owns is bounded by snapshot size, the durability story is the log +
 snapshots (state is always recomputable by replay), so an LSM on disk buys
 nothing on the hot path. The store is an ordered map from encoded
-``(cf, *key_parts)`` tuples to msgpack-able values, with:
+``(cf, *key_parts)`` tuples to msgpack-able values: a dict for the values and,
+for the order, one blocked sorted index of the committed keys
+(``BlockedKeyIndex``), shared by this store and the durable and tiered
+backends built on it, so a commit moves one block of pointers however many
+keys the partition holds. With:
 
 - order-preserving key encoding (ints sign-flipped big-endian, strings
   NUL-terminated) so prefix iteration matches RocksDB iterator semantics;
@@ -26,6 +30,7 @@ import enum
 import struct
 import zlib
 from bisect import bisect_left, insort
+from itertools import chain
 from typing import Any, Callable, Iterator
 
 from zeebe_tpu.native import codec_fn as _codec_fn
@@ -278,6 +283,131 @@ def _prefix_successor(prefix: bytes) -> bytes | None:
     return bytes(p)
 
 
+#: keys a block of the committed-key index is built with; it splits past twice
+#: that. 512: a full block is 8 KiB of pointers, one cheap memmove an insert,
+#: and 10^6 keys still need only ~2,000 block maxima to bisect.
+LOAD = 512
+
+
+class BlockedKeyIndex:
+    """The committed keys in sorted order, in blocks of bounded length.
+
+    ``lists`` is the pair ``(maxes, blocks)``: ``blocks`` a list of ascending
+    lists of keys, ``maxes[i]`` the last key of ``blocks[i]``. A key is
+    located by a bisect over ``maxes`` and one over its block, so an insert
+    or a removal moves at most one block (at most ``2 * LOAD`` pointers)
+    whatever the store holds. A block splits in half past ``2 * LOAD`` keys;
+    an emptied block is dropped; blocks are never merged. A store under one
+    block is a flat list plus one comparison.
+
+    One thread writes (the partition's); ``between``, ``count`` and iteration
+    may run on others without a lock (a gateway's long-poll peek, the
+    metrics cadence). For them the SHAPE of a pair never changes: a key is
+    inserted into or deleted from its block in place, but a split or a drop
+    builds new lists and publishes them as a new pair in one assignment, and
+    the block it replaced is never written again. A reader takes
+    ``self.lists`` once, so it never raises and never skips a block or sees
+    one twice. What it is not promised: between its bisect in the first or
+    the last block of a range and its slice of that block the writer may
+    insert or delete there, so at either edge it may be off by the keys
+    written meanwhile. The native passes (codec.c ``commit_overlay``,
+    ``iterate_snapshot``) keep the same rule.
+    """
+
+    __slots__ = ("lists",)
+
+    def __init__(self, sorted_keys: list[bytes] = ()) -> None:
+        """O(n) from an ALREADY-SORTED list of distinct keys."""
+        blocks = [sorted_keys[i:i + LOAD]
+                  for i in range(0, len(sorted_keys), LOAD)]
+        self.lists = ([block[-1] for block in blocks], blocks)
+
+    def add(self, key: bytes) -> None:
+        """Insert a key that is NOT in the index (the store's dict knows)."""
+        maxes, blocks = self.lists
+        i = bisect_left(maxes, key)
+        if i == len(maxes):
+            if not maxes:
+                self.lists = ([key], [[key]])
+                return
+            i -= 1
+            block = blocks[i]
+            block.append(key)
+            maxes[i] = key
+        else:
+            block = blocks[i]
+            insort(block, key)
+        if len(block) > 2 * LOAD:
+            half = len(block) >> 1
+            self.lists = (
+                maxes[:i] + [block[half - 1]] + maxes[i:],
+                blocks[:i] + [block[:half], block[half:]] + blocks[i + 1:])
+
+    def discard(self, key: bytes) -> None:
+        maxes, blocks = self.lists
+        i = bisect_left(maxes, key)
+        if i == len(maxes):
+            return
+        block = blocks[i]
+        j = bisect_left(block, key)
+        if j == len(block) or block[j] != key:
+            return
+        del block[j]
+        if not block:
+            self.lists = (maxes[:i] + maxes[i + 1:],
+                          blocks[:i] + blocks[i + 1:])
+        elif j == len(block):
+            maxes[i] = block[-1]
+
+    def between(self, lo: bytes, hi: bytes | None) -> list[bytes]:
+        """The keys in ``[lo, hi)`` (``hi`` None: to the end) as a new list of
+        references: a million keys cost a slice a block, no copies."""
+        maxes, blocks = self.lists
+        i = bisect_left(maxes, lo)
+        if i == len(blocks):
+            return []
+        j = bisect_left(maxes, hi, i) if hi is not None else len(blocks)
+        block = blocks[i]
+        start = bisect_left(block, lo)
+        if i == j:
+            return block[start:bisect_left(block, hi, start)]
+        out = block[start:]
+        for k in range(i + 1, j):
+            out += blocks[k]
+        if j < len(blocks):
+            block = blocks[j]
+            out += block[:bisect_left(block, hi)]
+        return out
+
+    def first(self, lo: bytes, hi: bytes | None) -> bytes | None:
+        """The smallest key in ``[lo, hi)``, or None."""
+        maxes, blocks = self.lists
+        i = bisect_left(maxes, lo)
+        while i < len(blocks):
+            block = blocks[i]
+            j = bisect_left(block, lo)
+            if j < len(block):
+                key = block[j]
+                return key if hi is None or key < hi else None
+            i += 1  # only a lock-free reader: the block just lost these keys
+        return None
+
+    def count(self, lo: bytes, hi: bytes | None) -> int:
+        """How many keys ``[lo, hi)`` holds, by position: two bisects and
+        the lengths of the blocks between."""
+        maxes, blocks = self.lists
+
+        def position(key):  # (block, slot) of the first key at or after
+            i = bisect_left(maxes, key) if key is not None else len(blocks)
+            return (i, bisect_left(blocks[i], key)) if i < len(blocks) else (i, 0)
+
+        (bi, ki), (bj, kj) = position(lo), position(hi)
+        return sum(map(len, blocks[bi:bj])) - ki + kj
+
+    def __iter__(self) -> Iterator[bytes]:
+        return chain.from_iterable(self.lists[1])
+
+
 class Transaction:
     """Pending puts/deletes overlaying the committed store.
 
@@ -365,7 +495,7 @@ class Transaction:
             # semantics to the Python path below, including the defensive
             # copy-and-cache of committed container values
             return iter(db._native_iterate(
-                db._sorted_keys, db._data, prefix, self._sorted_writes,
+                db._index.lists, db._data, prefix, self._sorted_writes,
                 self._writes, _DELETED, self._reads))
         return self.iterate_range(prefix, _prefix_successor(prefix))
 
@@ -445,9 +575,11 @@ class Transaction:
         db._pre_commit(self._writes)
         if db._native_commit is not None:
             # one native pass (codec.c commit_overlay) applying the overlay
-            # to the committed dict + sorted-keys list — identical semantics
-            # to the per-key loop below
-            db._native_commit(self._writes, db._data, db._sorted_keys, _DELETED)
+            # to the committed dict + key index — identical semantics to the
+            # per-key loop below
+            index = db._index
+            index.lists = db._native_commit(
+                self._writes, db._data, index.lists, LOAD, _DELETED)
         else:
             for key, val in self._writes.items():
                 if val is _DELETED:
@@ -564,15 +696,14 @@ class ZbDb:
 
     def __init__(self, consistency_checks: bool = False) -> None:
         self._data: dict[bytes, Any] = {}
-        self._sorted_keys: list[bytes] = []
+        self._index = BlockedKeyIndex()
         self._txn: Transaction | None = None
         self.consistency_checks = consistency_checks
         self._foreign_key_checkers: dict[ColumnFamilyCode, Callable[["ZbDb", Any], None]] = {}
-        # subclass hooks: the durable backend (state/durable.py) swaps the
-        # native iterate/commit out (its cold values need per-read
-        # resolution and its key index is a blocked sorted structure, not
-        # the flat list the C pass mutates) and journals commit overlays
-        # through _pre_commit
+        # subclass hooks: the durable and tiered backends swap the native
+        # iterate/commit out (their cold values need per-read resolution,
+        # which the C passes cannot do) and journal or release per key
+        # through _pre_commit / _put_committed / _delete_committed
         self._native_iterate = _iterate_snapshot
         self._native_commit = _commit_overlay
         # changed-keys-since-last-snapshot set for incremental snapshots
@@ -605,8 +736,9 @@ class ZbDb:
 
     def key_counts_by_cf(self) -> dict[str, int]:
         """Committed key count per (non-empty) column family — one boundary
-        bisect per CF over the sorted index, O(cfs × log n): cheap enough
-        for the metrics cadence (``zeebe_state_keys{cf=…}``)."""
+        bisect per CF over the sorted index and the lengths of the blocks
+        between, O(cfs × log n + blocks): cheap enough for the metrics
+        cadence (``zeebe_state_keys{cf=…}``)."""
         out: dict[str, int] = {}
         for code, prefix in _CF_PREFIX.items():
             end = _prefix_successor(prefix)
@@ -616,10 +748,7 @@ class ZbDb:
         return out
 
     def _count_key_range(self, lo: bytes, hi: bytes | None) -> int:
-        i = bisect_left(self._sorted_keys, lo)
-        j = (bisect_left(self._sorted_keys, hi) if hi is not None
-             else len(self._sorted_keys))
-        return j - i
+        return self._index.count(lo, hi)
 
     def committed_keys_of(self, code: ColumnFamilyCode,
                           prefix_parts: tuple = ()) -> list[bytes]:
@@ -627,7 +756,7 @@ class ZbDb:
         key-part prefix) without opening a transaction or materializing
         values — the timer-wheel rebuild and tiering scans read key indexes
         only. The returned list holds references into the sorted index, so a
-        million keys cost one slice, not a million tuples."""
+        million keys cost a slice a block, not a million tuples."""
         pfx = (encode_key(code, prefix_parts) if prefix_parts
                else _CF_PREFIX[code])
         return self._keys_with_prefix(pfx)
@@ -645,32 +774,22 @@ class ZbDb:
 
     def _put_committed(self, key: bytes, value: Any) -> None:
         if key not in self._data:
-            insort(self._sorted_keys, key)
+            self._index.add(key)
         self._data[key] = value
 
     def _delete_committed(self, key: bytes) -> None:
         if key in self._data:
             del self._data[key]
-            i = bisect_left(self._sorted_keys, key)
-            if i < len(self._sorted_keys) and self._sorted_keys[i] == key:
-                self._sorted_keys.pop(i)
+            self._index.discard(key)
 
     def _keys_with_prefix(self, prefix: bytes) -> list[bytes]:
         return self._keys_in_range(prefix, _prefix_successor(prefix))
 
     def _keys_in_range(self, lo: bytes, hi: bytes | None) -> list[bytes]:
-        i = bisect_left(self._sorted_keys, lo)
-        j = bisect_left(self._sorted_keys, hi) if hi is not None else len(self._sorted_keys)
-        return self._sorted_keys[i:j]
+        return self._index.between(lo, hi)
 
     def _first_key_at_or_after(self, lo: bytes, hi: bytes | None) -> bytes | None:
-        i = bisect_left(self._sorted_keys, lo)
-        if i >= len(self._sorted_keys):
-            return None
-        key = self._sorted_keys[i]
-        if hi is not None and key >= hi:
-            return None
-        return key
+        return self._index.first(lo, hi)
 
     # -- transactions --------------------------------------------------------
 
@@ -727,7 +846,7 @@ class ZbDb:
         if self.in_transaction:
             raise RuntimeError("cannot snapshot with an open transaction")
         body = msgpack.packb(
-            [[k, v] for k, v in ((k, self._data[k]) for k in self._sorted_keys)]
+            [[k, v] for k, v in ((k, self._data[k]) for k in self._index)]
         )
         crc = zlib.crc32(body) & 0xFFFFFFFF
         return self.SNAPSHOT_MAGIC + struct.pack("<I", crc) + body
@@ -769,10 +888,10 @@ class ZbDb:
 
     def bulk_apply(self, puts: dict[bytes, Any],
                    deletes: "tuple | list | set" = ()) -> None:
-        """Apply many puts/deletes in one pass: dict update + ONE sorted-key
-        rebuild — O(n log n) total where per-key ``insort`` is O(n) each
-        (quadratic on a million-key restore). Semantically identical to the
-        incremental path (tests/test_state.py asserts parity)."""
+        """Apply many puts/deletes in one pass: dict update + ONE index
+        rebuild — one sort where the incremental path pays a bisect and a
+        block's memmove per key. Semantically identical to the incremental
+        path (tests/test_state.py asserts parity)."""
         data = self._data
         for key in deletes:
             data.pop(key, None)
@@ -780,13 +899,12 @@ class ZbDb:
         self._rebuild_sorted_keys()
 
     def _rebuild_sorted_keys(self) -> None:
-        """Rebuild the key index from ``_data`` (hook: the durable backend
-        rebuilds its blocked SortedList here instead of a flat list)."""
-        self._sorted_keys = sorted(self._data)
+        """Rebuild the key index from ``_data``: one sort."""
+        self._install_sorted_keys(sorted(self._data))
 
     def _install_sorted_keys(self, keys: list[bytes]) -> None:
-        """Install an ALREADY-SORTED key list as the index (same hook)."""
-        self._sorted_keys = keys
+        """Install an ALREADY-SORTED key list as the index: O(n), no sort."""
+        self._index = BlockedKeyIndex(keys)
 
     def content_equals(self, other: "ZbDb") -> bool:
         """Deep state equality — the replay≡processing test oracle."""
@@ -817,6 +935,10 @@ class ZbDb:
     @property
     def key_count(self) -> int:
         return len(self._data)
+
+    @property
+    def index_block_count(self) -> int:
+        return len(self._index.lists[1])
 
     def to_delta_bytes(self) -> bytes:
         """Serialize the changed-keys-since-tracking-start as a delta
@@ -853,10 +975,10 @@ class ZbDb:
         if zlib.crc32(body) & 0xFFFFFFFF != crc:
             raise ValueError("state delta checksum mismatch")
         entries = msgpack.unpackb(body)
-        # bulk fast path: insort per key is O(existing) each — a delta the
-        # size of the store (chain recovery of a freshly-parked million
-        # instances) turns quadratic. Sort-once rebuild wins when the delta
-        # is large both absolutely and relative to the resident key set.
+        # bulk fast path: a delta the size of the store (chain recovery of a
+        # freshly-parked million instances) is one sort instead of a bisect
+        # and a block's memmove per key. Sort-once rebuild wins when the
+        # delta is large both absolutely and relative to the resident set.
         if len(entries) >= 1024 and len(entries) * 8 >= len(self._data):
             puts: dict[bytes, Any] = {}
             deletes: list[bytes] = []

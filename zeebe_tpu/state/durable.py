@@ -45,110 +45,7 @@ from typing import Any
 
 from zeebe_tpu.native import codec_fn as _codec_fn
 from zeebe_tpu.protocol import msgpack
-from zeebe_tpu.state.db import ZbDb, _DELETED
-
-try:
-    from sortedcontainers import SortedList
-except ImportError:
-    from bisect import bisect_left, bisect_right, insort
-
-    class SortedList:  # type: ignore[no-redef]
-        """Blocked sorted list fallback for environments without
-        sortedcontainers: the surface this module touches (add / discard /
-        irange / iter / len) with the same O(sqrt n) insert bound — keys live
-        in ≤2·LOAD blocks indexed by a bisect over per-block maxima, so an
-        insert memmoves one block, never the whole key set."""
-
-        __slots__ = ("_lists", "_maxes", "_len")
-
-        LOAD = 512
-
-        def __init__(self, iterable=()) -> None:
-            keys = sorted(iterable)
-            self._lists = [keys[i:i + self.LOAD]
-                           for i in range(0, len(keys), self.LOAD)]
-            self._maxes = [blk[-1] for blk in self._lists]
-            self._len = len(keys)
-
-        def add(self, key) -> None:
-            if not self._lists:
-                self._lists.append([key])
-                self._maxes.append(key)
-                self._len = 1
-                return
-            i = bisect_left(self._maxes, key)
-            if i == len(self._lists):
-                i -= 1
-            blk = self._lists[i]
-            insort(blk, key)
-            self._len += 1
-            if len(blk) > 2 * self.LOAD:
-                half = len(blk) // 2
-                self._lists[i:i + 1] = [blk[:half], blk[half:]]
-                self._maxes[i:i + 1] = [blk[half - 1], blk[-1]]
-            else:
-                self._maxes[i] = blk[-1]
-
-        def discard(self, key) -> None:
-            i = bisect_left(self._maxes, key)
-            if i == len(self._lists):
-                return
-            blk = self._lists[i]
-            j = bisect_left(blk, key)
-            if j == len(blk) or blk[j] != key:
-                return
-            del blk[j]
-            self._len -= 1
-            if blk:
-                self._maxes[i] = blk[-1]
-            else:
-                del self._lists[i]
-                del self._maxes[i]
-
-        def irange(self, minimum=None, maximum=None,
-                   inclusive=(True, True)):
-            lists, maxes = self._lists, self._maxes
-
-            def gen():
-                if not lists:
-                    return
-                if minimum is None:
-                    bi, ki = 0, 0
-                else:
-                    bi = bisect_left(maxes, minimum)
-                    if bi == len(lists):
-                        return
-                    cut = bisect_left if inclusive[0] else bisect_right
-                    ki = cut(lists[bi], minimum)
-                while bi < len(lists):
-                    blk = lists[bi]
-                    while ki < len(blk):
-                        key = blk[ki]
-                        if maximum is not None and (
-                                key > maximum
-                                or (not inclusive[1] and key == maximum)):
-                            return
-                        yield key
-                        ki += 1
-                    bi += 1
-                    ki = 0
-
-            return gen()
-
-        def bisect_left(self, key) -> int:
-            i = bisect_left(self._maxes, key)
-            if i == len(self._lists):
-                return self._len
-            before = sum(len(blk) for blk in self._lists[:i])
-            return before + bisect_left(self._lists[i], key)
-
-        def __iter__(self):
-            for blk in self._lists:
-                yield from blk
-
-        def __len__(self) -> int:
-            return self._len
-
+from zeebe_tpu.state.db import BlockedKeyIndex, ZbDb, _DELETED, encode_key
 
 _index_base_segment = _codec_fn("index_base_segment")
 
@@ -247,13 +144,11 @@ class DurableZbDb(ZbDb):
         import threading
 
         # cold values need per-read resolution, which the native iterate
-        # cannot do — use the (identical-semantics) Python merge path; and
-        # the key index is a blocked SortedList (O(sqrt n) insert — a flat
-        # list's O(n) memmove per new key collapses at 10^5+ keys), which
-        # the native commit pass cannot mutate
+        # cannot do — use the (identical-semantics) Python merge path. The
+        # commit is ZbDb's, native pass included: the key index is the one
+        # every backend shares (state/db.py BlockedKeyIndex), and what a
+        # commit writes is hot
         self._native_iterate = None
-        self._native_commit = None
-        self._sorted_keys = SortedList()
         self.directory = Path(directory)
         self.directory.mkdir(parents=True, exist_ok=True)
         self.hot_budget_bytes = hot_budget_bytes
@@ -279,45 +174,6 @@ class DurableZbDb(ZbDb):
         # views into it — Linux keeps unlinked-but-mapped data readable)
         self._maps: list[mmap.mmap] = []
         self._recovery_lock = threading.Lock()
-
-    # -- committed-store internals (SortedList key index) ---------------------
-
-    def _put_committed(self, key: bytes, value: Any) -> None:
-        if key not in self._data:
-            self._sorted_keys.add(key)
-        self._data[key] = value
-
-    def _delete_committed(self, key: bytes) -> None:
-        if key in self._data:
-            del self._data[key]
-            self._sorted_keys.discard(key)
-
-    def _keys_with_prefix(self, prefix: bytes) -> list[bytes]:
-        from zeebe_tpu.state.db import _prefix_successor
-
-        return self._keys_in_range(prefix, _prefix_successor(prefix))
-
-    def _keys_in_range(self, lo: bytes, hi: bytes | None) -> list[bytes]:
-        if hi is None:
-            return list(self._sorted_keys.irange(lo))
-        return list(self._sorted_keys.irange(lo, hi, inclusive=(True, False)))
-
-    def _first_key_at_or_after(self, lo: bytes, hi: bytes | None) -> bytes | None:
-        if hi is None:
-            return next(iter(self._sorted_keys.irange(lo)), None)
-        return next(iter(self._sorted_keys.irange(lo, hi,
-                                                  inclusive=(True, False))), None)
-
-    def _rebuild_sorted_keys(self) -> None:
-        self._sorted_keys = SortedList(self._data)
-
-    def _install_sorted_keys(self, keys) -> None:
-        self._sorted_keys = SortedList(keys)
-
-    def _count_key_range(self, lo: bytes, hi: bytes | None) -> int:
-        j = (self._sorted_keys.bisect_left(hi) if hi is not None
-             else len(self._sorted_keys))
-        return j - self._sorted_keys.bisect_left(lo)
 
     # -- wal ------------------------------------------------------------------
 
@@ -400,8 +256,6 @@ class DurableZbDb(ZbDb):
     def committed_get(self, code, key_parts) -> Any:
         """Cross-thread committed read: resolves cold values WITHOUT
         promoting (no LRU/object mutation from the query thread)."""
-        from zeebe_tpu.state.db import encode_key
-
         self._ensure_recovered()
 
         if not isinstance(key_parts, tuple):
@@ -459,7 +313,7 @@ class DurableZbDb(ZbDb):
         data = self._data
         total = 0
         with open(tmp, "wb") as f:
-            for key in self._sorted_keys:
+            for key in self._index:
                 val = data[key]
                 kcrc = zlib.crc32(key) & 0xFFFFFFFF
                 if type(val) is memoryview:
@@ -577,10 +431,10 @@ class DurableZbDb(ZbDb):
                             data.pop(key, None)
                         else:
                             data[key] = _Packed(packed)
-            # key order: the base arrives sorted (SortedList construction
-            # from sorted input is a cheap O(n) pass); patch the (typically
-            # tiny) WAL key-set delta in with O(sqrt n) adds/discards
-            keys = SortedList(base_keys)
+            # key order: the base arrives sorted (the index builds from
+            # sorted input in one O(n) pass); patch the (typically tiny)
+            # WAL key-set delta in with one-block adds/discards
+            keys = BlockedKeyIndex(base_keys)
             base_set = set(base_keys) if touched else None
             for key in touched:
                 in_data = key in data
@@ -588,7 +442,7 @@ class DurableZbDb(ZbDb):
                     keys.add(key)
                 elif not in_data and key in base_set:
                     keys.discard(key)
-            self._sorted_keys = keys
+            self._index = keys
             # publish only after the view is complete (committed_get races)
             self._lazy_recovery = None
 
@@ -648,7 +502,7 @@ class DurableZbDb(ZbDb):
         # drop cold views so the maps can release; a map with a live
         # exported view elsewhere just stays for the GC
         self._data = {}
-        self._sorted_keys = []
+        self._index = BlockedKeyIndex()
         for mm in self._maps:
             try:
                 mm.close()
@@ -668,7 +522,7 @@ class DurableZbDb(ZbDb):
             raise RuntimeError("cannot snapshot with an open transaction")
         self._ensure_recovered()
         body = msgpack.packb([
-            [k, self._resolve(self._data[k])] for k in self._sorted_keys
+            [k, self._resolve(self._data[k])] for k in self._index
         ])
         crc = zlib.crc32(body) & 0xFFFFFFFF
         return self.SNAPSHOT_MAGIC + struct.pack("<I", crc) + body
@@ -693,7 +547,7 @@ class DurableZbDb(ZbDb):
         self._ensure_recovered()  # settle staged work before wholesale replace
         restored = ZbDb.from_snapshot_bytes(raw)
         self._data = restored._data
-        self._sorted_keys = SortedList(restored._sorted_keys)
+        self._index = restored._index
         self._hot.clear()
         self._hot_bytes = 0
         self._compact()  # publishes the manifest for the new state

@@ -495,7 +495,7 @@ class TieredZbDb(ZbDb):
         if self.in_transaction:
             raise RuntimeError("cannot snapshot with an open transaction")
         body = msgpack.packb(
-            [[k, self._resolve(self._data[k])] for k in self._sorted_keys]
+            [[k, self._resolve(self._data[k])] for k in self._index]
         )
         crc = zlib.crc32(body) & 0xFFFFFFFF
         return self.SNAPSHOT_MAGIC + struct.pack("<I", crc) + body

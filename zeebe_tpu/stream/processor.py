@@ -259,6 +259,16 @@ class StreamProcessor:
                           "device_fetch", "device_unpack", "materialize",
                           "append", "flush", "side_effects")
         }
+        # the state store's own work, which no stage above holds: one
+        # observation a transaction committed on the processing path (a
+        # kernel group's, a sequential command's; replay is not observed,
+        # as the stages are not)
+        self._m_commit = REGISTRY.histogram(
+            "stream_processor_pipeline_commit",
+            "seconds inside Transaction.commit() per transaction committed "
+            "on the processing path (a kernel group or a sequential command)"
+            ": the overlay applied to the committed store and its key index",
+            ("partition",)).labels(partition_label)
         # not a time: the host→device transfers a single-device group's
         # first jit call made (its numpy arguments); further chunks run off
         # the device-side carry and upload nothing
@@ -675,7 +685,7 @@ class StreamProcessor:
         self._run_deferred_effects()
         overlap = 0.0
         try:
-            with self.db.transaction():
+            with self.db.transaction() as txn:
                 if spec is not None:
                     pg, expected_pos, epoch, t_disp = spec
                     if (expected_pos == self._reader_position
@@ -750,6 +760,7 @@ class StreamProcessor:
                             self._note_live_dedupe(cmd, result.follow_ups)
                 append_dur = _time.perf_counter() - t_append
                 pipeline["append"].observe(append_dur)
+                self._commit_timed(txn)
         except _STORAGE_CORRUPTION:
             raise  # repairable disk fault: the pump's repair seam owns it
         except Exception:  # noqa: BLE001 — the fallback/rollback seam
@@ -1201,6 +1212,15 @@ class StreamProcessor:
         self._process_command(cmd)
         return True
 
+    def _commit_timed(self, txn) -> None:
+        """Commit ``txn`` as the last act inside its ``with`` block (whose
+        exit then finds it closed) and observe the seconds it took."""
+        import time as _time
+
+        t_commit = _time.perf_counter()
+        txn.commit()
+        self._m_commit.observe(_time.perf_counter() - t_commit)
+
     def _process_command(self, cmd: LoggedRecord) -> None:
         import time as _time
 
@@ -1216,9 +1236,10 @@ class StreamProcessor:
         start = _time.perf_counter()
         builder = ProcessingResultBuilder()
         try:
-            with self.db.transaction():
+            with self.db.transaction() as txn:
                 self._batch_process(cmd, builder)
                 self._write_and_mark(cmd, builder)
+                self._commit_timed(txn)
         except _STORAGE_CORRUPTION:
             raise  # repairable disk fault: the pump's repair seam owns it
         except Exception as error:  # noqa: BLE001 — the rollback/onError seam
