@@ -544,6 +544,39 @@ class TestWindowAccount:
         assert "ack_p50_ms" in text and "uncovered:" in text
         assert "outside the program's spans" in text
 
+    def test_streaming_workers_account_reads_the_push_span(self, recorded):
+        """The same traces with a ``jobstream.push`` span an instance, as a
+        run with streaming workers carries: the stretches between job created
+        and the worker's hold are that span's, and still add up."""
+        from zeebe_tpu.observability.account import (
+            STRETCHES_PUSHED,
+            window_account,
+        )
+        from zeebe_tpu.observability.critical_path import _attr
+
+        spans, head = recorded
+        polled = window_account(spans, head, *head["windowUs"])["account"]
+        assert polled["pushed"] == 0
+        pushes = [
+            {"traceId": s["traceId"], "name": "jobstream.push",
+             "startUs": s["startUs"] - 1_000, "durUs": s["durUs"] + 3_000,
+             "partitionId": s["partitionId"], "parent": "gateway.request",
+             "attrs": {"processInstanceKey": key}}
+            for s in spans if s.get("name") == "gateway.request"
+            and _attr(s, "intent") == "ACTIVATE"
+            for key in _attr(s, "processInstanceKeys") or ()]
+        account = window_account(spans + pushes, head,
+                                 *head["windowUs"])["account"]
+        assert account["pushed"] == account["instances"] == 3
+        for i, (name, covered) in STRETCHES_PUSHED.items():
+            assert (account["stretches"][i]["name"],
+                    account["stretches"][i]["covered_by"]) == (name, covered)
+        # the job is on its stream 2 ms after the activation answered
+        assert account["stretches"][3]["mean_ms"] == pytest.approx(
+            polled["stretches"][3]["mean_ms"] + 2.0)
+        assert sum(s["mean_ms"] for s in account["stretches"]) == pytest.approx(
+            account["whole"]["mean_ms"])
+
     def test_requests_outside_the_window_are_left_out(self, recorded):
         from zeebe_tpu.observability.account import window_account
 

@@ -42,7 +42,7 @@ from typing import Any, Callable
 
 from zeebe_tpu.protocol import msgpack
 from zeebe_tpu.state.db import ColumnFamilyCode, _DELETED as _DB_DELETED
-from zeebe_tpu.stream.api import activatable_job_types as _activatable_job_types
+from zeebe_tpu.stream.api import job_moves as _job_moves
 
 # record header layout (protocol/record.py _HEADER = "<BBBBqqqiqqH")
 _REC_KEY_OFF = 4
@@ -442,6 +442,8 @@ class PreparedBurst:
     responses: list  # [(extra, Record, request_stream_id, request_id)]
     has_pending_commands: bool = False
     job_types: frozenset = frozenset()  # job types made activatable by the burst
+    jobs_available: tuple = ()  # keys of the jobs it made activatable
+    jobs_ended: tuple = ()  # keys of the jobs it completed, canceled or failed
 
 
 _FMT_CODES = {"le_q": 0, "le_i": 1, "be_q": 2}
@@ -469,6 +471,10 @@ class BurstTemplate:
     responses: list[ResponseTemplate] = field(default_factory=list)
     has_pending_commands: bool = False
     job_types: frozenset = frozenset()
+    # the roles of the job keys the burst makes activatable and ends (the
+    # host-side wait stamps, stream/job_wait.py)
+    jobs_available: tuple = ()
+    jobs_ended: tuple = ()
     # compiled payload patch plan (native apply_patches): entry bytes +
     # distinct role list; False = not compilable (fallback loop)
     _plan: Any = field(default=None, repr=False, compare=False)
@@ -832,6 +838,11 @@ def build_template(
             f"unexplained large ints (not roles, not fingerprint-pinned): {stray[:4]}"
         )
 
+    moves = _job_moves(builder.follow_ups)
+    job_roles = {key: roles.of(key) for key in moves.available + moves.ended}
+    if None in job_roles.values():
+        raise NotTemplatable("a job key that is no role")
+
     return BurstTemplate(
         payload=bytes(payload),
         count=len(builder.follow_ups),
@@ -844,7 +855,9 @@ def build_template(
         has_pending_commands=any(
             f.record.is_command and not f.processed for f in builder.follow_ups
         ),
-        job_types=frozenset(_activatable_job_types(builder.follow_ups)),
+        job_types=frozenset(moves.types),
+        jobs_available=tuple(job_roles[key] for key in moves.available),
+        jobs_ended=tuple(job_roles[key] for key in moves.ended),
     )
 
 

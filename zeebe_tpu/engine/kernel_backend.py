@@ -3248,6 +3248,8 @@ class KernelBackend:
             responses=responses,
             has_pending_commands=template.has_pending_commands,
             job_types=template.job_types,
+            jobs_available=tuple(map(resolve, template.jobs_available)),
+            jobs_ended=tuple(map(resolve, template.jobs_ended)),
         )
 
     def _audit_template(self, template, adm: _Admitted, builder, cap_log,

@@ -71,6 +71,14 @@ class GatewayRuntimeBase:
         self.jobs_hub.notify(job_types)
         self.job_streams.on_jobs_available(partition_id, job_types)
 
+    def job_pushed(self, job_key: int) -> float | None:
+        """The push dispatcher put the job on a live client stream: the
+        seconds since the job was made activatable, where this process holds
+        its wait stamp (``stream/job_wait.py``). The stamps live with the
+        partition's leader, so a runtime whose brokers are other processes
+        has none and observes no ``job_push``."""
+        return None
+
     def _init_requests(self) -> None:
         self._round_robin = itertools.count()
         # request ids carry a startup nonce in the high bits: a restarted
@@ -326,6 +334,11 @@ class ClusterRuntime(GatewayRuntimeBase):
                 leader.db, job_type, tenant_ids)
         finally:
             lock.release()
+
+    def job_pushed(self, job_key: int) -> float | None:
+        leader = self._leader_partition(self.partition_for_key(job_key))
+        processor = getattr(leader, "processor", None)
+        return None if processor is None else processor.job_stamps.pushed(job_key)
 
     # -- request path ----------------------------------------------------------
 

@@ -43,6 +43,9 @@ from zeebe_tpu.protocol.intent import (  # noqa: E402
 )
 
 VERSION = "8.4.0-tpu"
+#: open ``StreamActivatedJobs`` calls a gateway serves at once, one thread
+#: each; a stream beyond them waits for one to close
+MAX_OPEN_JOB_STREAMS = 4096
 
 
 from zeebe_tpu.utils.metrics import REGISTRY as _REG  # noqa: E402
@@ -346,9 +349,10 @@ class GatewayService:
         """Job push: register a client stream with the dispatcher; the broker
         side's jobs-available side effect activates jobs and feeds them here
         with no polling (reference: StreamJobsHandler.java:36 →
-        ClientStreamManager → broker RemoteStreamRegistry push)."""
-        import queue as _queue
-
+        ClientStreamManager → broker RemoteStreamRegistry push). The call
+        lives as long as its worker, on a thread of the gateway's stream pool
+        (``Gateway``), and sleeps until a job is put on its queue or the call
+        ends."""
         tenant_filter = self._tenant_ids_field(context, request.tenantIds)
         streams = self.runtime.job_streams
         handle = streams.add_stream(
@@ -357,11 +361,11 @@ class GatewayService:
         )
         in_flight = None
         try:
-            while context.is_active():
-                try:
-                    in_flight = handle.jobs.get(timeout=0.25)
-                except _queue.Empty:
-                    continue
+            # the call's end (the client cancelled or went away, the server
+            # stops) puts the marker that ends the wait below
+            if not context.add_callback(lambda: handle.jobs.put(None)):
+                return      # it ended before it was registered
+            while (in_flight := handle.jobs.get()) is not None:
                 key, job = in_flight
                 yield self._activated_job(request, key, job)
                 in_flight = None
@@ -664,9 +668,20 @@ class Gateway:
                 request_deserializer=req_cls.FromString,
                 response_serializer=resp_cls.SerializeToString,
             )
+        # an open job stream lives as long as its worker: its handler runs on
+        # a pool of its own (threads made as streams open), so that streams,
+        # however many, take no handler from the unary RPCs' ``max_workers``
+        self._stream_pool = futures.ThreadPoolExecutor(
+            max_workers=MAX_OPEN_JOB_STREAMS,
+            thread_name_prefix="gateway-job-stream")
         for name, (req_cls, resp_cls) in _SERVER_STREAMING.items():
+            behavior = _wrap(getattr(self.service, name))
+            if name == "StreamActivatedJobs":
+                # grpc's own door for a handler that must not share the
+                # server's pool (grpc._server._select_thread_pool_for_behavior)
+                behavior.experimental_thread_pool = self._stream_pool
             handlers[name] = grpc.unary_stream_rpc_method_handler(
-                _wrap(getattr(self.service, name)),
+                behavior,
                 request_deserializer=req_cls.FromString,
                 response_serializer=resp_cls.SerializeToString,
             )
@@ -697,6 +712,8 @@ class Gateway:
 
     def stop(self, grace: float = 1.0) -> None:
         self.server.stop(grace)
+        # the stop ended every open stream's call; their threads go with them
+        self._stream_pool.shutdown(wait=False)
 
 
 def _wrap(method: Callable) -> Callable:

@@ -14,8 +14,20 @@ side effect (stream/processor.py on_jobs_available) that lands here. The
 notification, writes a JOB_BATCH ACTIVATE through the normal command path and
 delivers the activated jobs to a registered stream — so the record log is
 byte-identical to pull activation and replay/exporters see nothing special.
+(The design not taken is upstream's: activation inside the step that creates
+the job, no second command and no second Raft round; ROADMAP queues it.)
 Jobs pushed at a stream that died before delivery are handed back with
-JOB YIELD (reference: YieldingJobStreamErrorHandler)."""
+JOB YIELD (reference: YieldingJobStreamErrorHandler).
+
+The activation is a command that blocks for its whole round trip (Raft and
+the fsync), so every partition has a pusher thread of its own, started with
+the partition's first notification: a push for one partition never waits for
+another partition's commit, nor for its election or its ``submit`` timeout.
+A partition's pusher takes one ``(partition, job type)`` at a time, so at
+most one activation is in flight a partition. A delivery that lands on a live
+stream closes the job's wait stamp (``stream/job_wait.py``: histogram
+``stream_processor_pipeline_job_push``) and, with the tracer on, emits the
+span ``jobstream.push`` at its real interval."""
 
 from __future__ import annotations
 
@@ -73,13 +85,15 @@ class ClientJobStream:
     job_type: str
     worker: str
     timeout_ms: int
-    jobs: "queue.Queue[tuple[int, dict]]" = field(default_factory=queue.Queue)
+    #: (job key, job) as activated; None is the gateway's end-of-call marker
+    jobs: "queue.Queue[tuple[int, dict] | None]" = field(default_factory=queue.Queue)
     closed: bool = False
     tenant_ids: list | None = None  # authorized-tenant filter (None = default)
 
 
 from time import perf_counter as _perf_counter
 
+from zeebe_tpu.observability.tracer import get_tracer, instance_attrs
 from zeebe_tpu.utils.metrics import REGISTRY as _REG
 
 # job-stream registry metrics (reference: transport/stream metrics — clients,
@@ -108,38 +122,59 @@ _M_PUSH_LATENCY = _REG.histogram(
 
 class JobStreamDispatcher:
     """RemoteStreamRegistry + RemoteJobStreamer, runtime-side: registered
-    client streams per job type and a dispatcher thread turning notifications
-    into JOB_BATCH ACTIVATE commands whose jobs feed the streams."""
+    client streams per job type and, a partition, a pusher thread turning
+    notifications into JOB_BATCH ACTIVATE commands whose jobs feed the
+    streams."""
 
     def __init__(self, runtime) -> None:
         # runtime surface used: submit, partition_for_key, partition_count,
-        # has_activatable_jobs
+        # has_activatable_jobs, job_pushed
         self.runtime = runtime
         self._ids = itertools.count(1)
-        self._lock = threading.Condition()
+        self._lock = threading.RLock()
         self._streams: dict[str, list[ClientJobStream]] = {}
         self._rr: dict[str, int] = {}
         self._pending: set[tuple[int, str]] = set()
+        # partition -> its pusher's thread and the condition (over the
+        # registry's lock) that wakes it alone
+        self._pushers: dict[int, tuple[threading.Thread, threading.Condition]] = {}
         self._running = False
-        self._thread: threading.Thread | None = None
 
     # -- lifecycle -------------------------------------------------------------
 
     def start(self) -> None:
         self._running = True
         _M_SERVERS.inc()
-        self._thread = threading.Thread(
-            target=self._run, daemon=True, name="job-stream-dispatcher"
-        )
-        self._thread.start()
+        with self._lock:    # what was armed before the start
+            for partition_id in {k[0] for k in self._pending}:
+                self._arm(partition_id, ())
 
     def stop(self) -> None:
         self._running = False
         _M_SERVERS.dec()
         with self._lock:
-            self._lock.notify_all()
-        if self._thread is not None:
-            self._thread.join(timeout=5)
+            pushers = list(self._pushers.values())
+            self._pushers.clear()
+            for _thread, wake in pushers:
+                wake.notify_all()
+        deadline = time.monotonic() + 5
+        for thread, _wake in pushers:
+            thread.join(timeout=max(0.0, deadline - time.monotonic()))
+
+    def _arm(self, partition_id: int, job_types) -> None:
+        """Under the registry lock: the partition's pusher has these types to
+        push (a burst of notifications is one entry a type)."""
+        self._pending.update((partition_id, t) for t in job_types)
+        pusher = self._pushers.get(partition_id)
+        if pusher is not None:
+            pusher[1].notify()
+        elif self._running:
+            wake = threading.Condition(self._lock)
+            thread = threading.Thread(
+                target=self._run, args=(partition_id, wake), daemon=True,
+                name=f"job-stream-pusher-{partition_id}")
+            self._pushers[partition_id] = (thread, wake)
+            thread.start()
 
     # -- stream registry (AddStream / RemoveStream) ----------------------------
 
@@ -155,8 +190,7 @@ class JobStreamDispatcher:
             # existed must still be pushed (reference: broker re-notifies
             # streams on registration)
             for partition_id in range(1, self.runtime.partition_count + 1):
-                self._pending.add((partition_id, job_type))
-            self._lock.notify_all()
+                self._arm(partition_id, (job_type,))
         return stream
 
     def remove_stream(self, stream: ClientJobStream,
@@ -179,9 +213,11 @@ class JobStreamDispatcher:
                 self._streams.pop(stream.job_type, None)
             while True:
                 try:
-                    leftovers.append(stream.jobs.get_nowait())
+                    left = stream.jobs.get_nowait()
                 except queue.Empty:
                     break
+                if left is not None:    # None: the gateway's end-of-call marker
+                    leftovers.append(left)
         for key, job in leftovers:
             if not self._redeliver(stream.job_type, key, job):
                 self._yield_back(key)
@@ -195,21 +231,27 @@ class JobStreamDispatcher:
     def on_jobs_available(self, partition_id: int, job_types: set) -> None:
         with self._lock:
             armed = {t for t in job_types if self._streams.get(t)}
-            if not armed:
-                return
-            self._pending.update((partition_id, t) for t in armed)
-            self._lock.notify_all()
+            if armed:
+                self._arm(partition_id, armed)
 
     # -- dispatcher ------------------------------------------------------------
 
-    def _run(self) -> None:
+    def _run(self, partition_id: int, wake: threading.Condition) -> None:
+        """One partition's pusher: its pending job types one after another.
+        Whatever blocks here — the activation's commit, a partition without a
+        leader, a ``submit`` that times out — blocks this partition alone."""
         while self._running:
             with self._lock:
-                while self._running and not self._pending:
-                    self._lock.wait(0.5)
-                if not self._running:
-                    return
-                partition_id, job_type = self._pending.pop()
+                while True:
+                    if not self._running:
+                        return
+                    key = next((k for k in self._pending
+                                if k[0] == partition_id), None)
+                    if key is not None:
+                        break
+                    wake.wait(0.5)
+                self._pending.discard(key)
+            job_type = key[1]
             try:
                 self._push(partition_id, job_type)
             except Exception:  # noqa: BLE001 — a failed push must not kill the loop
@@ -221,7 +263,7 @@ class JobStreamDispatcher:
                 # retry-forever; backpressure/no-leader conditions clear)
                 with self._lock:
                     if self._streams.get(job_type):
-                        self._pending.add((partition_id, job_type))
+                        self._pending.add(key)
                 time.sleep(0.05)
 
     @staticmethod
@@ -278,30 +320,48 @@ class JobStreamDispatcher:
                     continue
                 keys = record.value.get("jobKeys", [])
                 jobs = record.value.get("jobs", [])
+                activation = record.source_record_position
                 for key, job in zip(keys, jobs):
                     _t0 = _perf_counter()
-                    if self._deliver(stream, key, job):
+                    if self._deliver(stream, key, job, activation):
                         _M_PUSHED.inc()
                         _M_PUSH_LATENCY.observe(_perf_counter() - _t0)
                     else:
                         _M_PUSH_FAIL.inc()
-                        if not self._redeliver(job_type, key, job):
+                        if not self._redeliver(job_type, key, job, activation):
                             self._yield_back(key)
                 if len(keys) >= PUSH_BATCH_SIZE:
                     progressed = True  # this group may have more to drain
             if not progressed:
                 return
 
-    def _deliver(self, stream: ClientJobStream, key: int, job: dict) -> bool:
+    def _deliver(self, stream: ClientJobStream, key: int, job: dict,
+                 activation: int = -1) -> bool:
         """Enqueue under the registry lock so the closed-check and the put are
-        atomic against remove_stream's drain."""
+        atomic against remove_stream's drain. ``activation``: the position of
+        the command that activated the job, where the caller has it."""
         with self._lock:
             if stream.closed:
                 return False
             stream.jobs.put((key, job))
-            return True
+        # the job is on a live stream: its wait for a worker is over. A job
+        # moved on from a dead stream's queue has no stamp left and reads None
+        waited = self.runtime.job_pushed(key)
+        if waited is not None:
+            tracer = get_tracer()
+            partition_id = self.runtime.partition_for_key(key)
+            trace_id = f"{partition_id}:{activation}"
+            if tracer.enabled and tracer.sampled(trace_id):
+                tracer.emit(trace_id, "jobstream.push", waited, partition_id,
+                            parent="gateway.request",
+                            attrs={"partition": partition_id,
+                                   "jobType": stream.job_type, "jobKey": key,
+                                   "streamId": stream.stream_id,
+                                   **instance_attrs(job)})
+        return True
 
-    def _redeliver(self, job_type: str, key: int, job: dict) -> bool:
+    def _redeliver(self, job_type: str, key: int, job: dict,
+                   activation: int = -1) -> bool:
         """Route an undeliverable job to another live stream of the type that
         is authorized for the job's tenant (never across tenants)."""
         tenant = job.get("tenantId", DEFAULT_TENANT)
@@ -319,7 +379,7 @@ class JobStreamDispatcher:
                 if not eligible:
                     return False
                 stream = eligible[0]
-            if self._deliver(stream, key, job):
+            if self._deliver(stream, key, job, activation):
                 return True
         return False
 

@@ -23,6 +23,10 @@ window:
   whole. A stretch that no span covers reads ``uncovered``;
 - beside the whole, the median the script itself printed (the harness's
   ``completion_p50_ms``): the difference lies outside the program's spans;
+  Where the workers stream (every instance has a ``jobstream.push`` span, from
+  the moment its job was made activatable to its being put on a client
+  stream) the two stretches between job created and the worker's hold are
+  that span's: the dispatcher's queue, then its activation and the delivery;
 - ``evicted``, which must be 0: a ring that dropped spans of the window makes
   the tool refuse (:class:`SpansEvicted`) instead of reporting a part.
 
@@ -67,6 +71,17 @@ STRETCHES = (
     ("complete acked -> process completed at exporter", "uncovered"),
     ("first export", "exporter.export"),
 )
+
+
+# where every instance of the window has a ``jobstream.push`` span (streaming
+# workers): no poll is waited for, the dispatcher submits the activation, and
+# the activation's stretch ends when the job is on its worker's stream
+STRETCHES_PUSHED = {
+    2: ("job created -> activate submitted (the dispatcher's queue)",
+        "jobstream.push"),
+    3: ("activate command, then the job onto its worker's stream",
+        "jobstream.push"),
+}
 
 
 class SpansEvicted(RuntimeError):
@@ -147,6 +162,8 @@ def _instance_account(spans: list[dict], requests: list[dict],
         elif what is not None and _attr(s, "rejection") is None:
             keep(what, _attr(s, "processInstanceKey"), s)
     for s in spans:
+        if s.get("name") == "jobstream.push":
+            keep("pushed", _attr(s, "processInstanceKey"), s)
         if s.get("name") != "exporter.export":
             continue
         record = (_attr(s, "valueType"), _attr(s, "intent"))
@@ -158,7 +175,7 @@ def _instance_account(spans: list[dict], requests: list[dict],
             keep("process completed", key, s)
 
     rows: list[list[int]] = []
-    creates = incomplete = 0
+    creates = incomplete = pushed = 0
     for (what, key), create in first.items():
         if what != "CreateProcessInstance" or key is None:
             continue
@@ -172,21 +189,29 @@ def _instance_account(spans: list[dict], requests: list[dict],
             incomplete += 1
             continue
         job_created, activate, complete, completed = parts
+        push = first.get(("pushed", key))
+        pushed += push is not None
+        handed = _end(activate) if push is None else max(_end(activate),
+                                                         _end(push))
         marks = [create["startUs"], _end(create), _end(job_created),
-                 activate["startUs"], _end(activate), complete["startUs"],
+                 activate["startUs"], handed, complete["startUs"],
                  _end(complete), completed["startUs"], _end(completed)]
         for i in range(1, len(marks)):
             marks[i] = max(marks[i], marks[i - 1])
         rows.append([b - a for a, b in zip(marks, marks[1:])])
     out = {"creates_in_window": creates, "incomplete": incomplete,
-           "instances": len(rows)}
+           "instances": len(rows), "pushed": pushed}
     if not rows:
         return out
+    stretches = list(STRETCHES)
+    if pushed == len(rows):
+        for i, stretch in STRETCHES_PUSHED.items():
+            stretches[i] = stretch
     wholes = [sum(row) for row in rows]
     out["whole"] = _stats(wholes)
     out["stretches"] = [
         {"name": name, "covered_by": covered, **_stats([row[i] for row in rows])}
-        for i, (name, covered) in enumerate(STRETCHES)]
+        for i, (name, covered) in enumerate(stretches)]
     uncovered = sum(s["mean_ms"] for s in out["stretches"]
                     if s["covered_by"] == "uncovered")
     out["uncovered_mean_ms"] = uncovered

@@ -18,6 +18,16 @@ from typing import Any, Callable
 
 from zeebe_tpu.logstreams import LoggedRecord
 from zeebe_tpu.protocol import Record, RejectionType, ValueType
+from zeebe_tpu.protocol.intent import JobBatchIntent, JobIntent
+
+_JOB_MADE_AVAILABLE = frozenset(map(int, (
+    JobIntent.CREATED, JobIntent.TIMED_OUT, JobIntent.RECURRED_AFTER_BACKOFF,
+    JobIntent.YIELDED)))
+_JOB_ENDED = frozenset(map(int, (
+    JobIntent.COMPLETED, JobIntent.CANCELED, JobIntent.ERROR_THROWN,
+    JobIntent.FAILED)))
+_JOB_BATCH_ACTIVATED = int(JobBatchIntent.ACTIVATED)
+_JOB_FAILED = int(JobIntent.FAILED)
 
 
 @dataclasses.dataclass(slots=True)
@@ -39,30 +49,50 @@ class ClientResponse:
     request_id: int
 
 
-def activatable_job_types(follow_ups) -> set[str]:
-    """Job types made activatable by a step's follow-up events — the
-    jobs-available notification source (reference: the engine's
-    JobsAvailableCallback wired through BpmnJobActivationBehavior /
-    JobBackoffChecker so gateways can wake parked long-polls and push
-    streams instead of polling)."""
-    from zeebe_tpu.protocol.intent import JobIntent
+@dataclasses.dataclass(slots=True)
+class JobMoves:
+    """What a step's follow-up events did to jobs, as the post-commit side
+    effects need it: the jobs-available notification's types, and the job
+    keys the host-side wait stamps turn on (``stream/job_wait.py``)."""
 
-    available = set()
+    types: set = dataclasses.field(default_factory=set)  # made activatable
+    available: list = dataclasses.field(default_factory=list)  # their keys
+    activated: list = dataclasses.field(default_factory=list)  # JOB_BATCH ACTIVATED
+    ended: list = dataclasses.field(default_factory=list)  # left for good
+
+
+def job_moves(follow_ups) -> JobMoves:
+    """One pass over a step's follow-ups. A job is made activatable by
+    CREATED, TIMED_OUT, RECURRED_AFTER_BACKOFF, YIELDED and a FAILED that
+    leaves retries and no backoff — the jobs-available notification source
+    (reference: the engine's JobsAvailableCallback wired through
+    BpmnJobActivationBehavior / JobBackoffChecker so gateways can wake parked
+    long-polls and push streams instead of polling). It ends with COMPLETED,
+    CANCELED, ERROR_THROWN and any other FAILED."""
+    moves = JobMoves()
     for f in follow_ups:
         rec = f.record
-        if rec.value_type != ValueType.JOB or not rec.is_event:
+        if not rec.is_event:
+            continue
+        if rec.value_type == ValueType.JOB_BATCH:
+            if int(rec.intent) == _JOB_BATCH_ACTIVATED:
+                moves.activated.extend(rec.value.get("jobKeys", ()))
+            continue
+        if rec.value_type != ValueType.JOB:
             continue
         intent = int(rec.intent)
-        if intent in (int(JobIntent.CREATED), int(JobIntent.TIMED_OUT),
-                      int(JobIntent.RECURRED_AFTER_BACKOFF), int(JobIntent.YIELDED)) or (
-            intent == int(JobIntent.FAILED)
+        if intent in _JOB_MADE_AVAILABLE or (
+            intent == _JOB_FAILED
             and rec.value.get("retries", 0) > 0
             and rec.value.get("retryBackoff", -1) <= 0
         ):
             job_type = rec.value.get("type", "")
             if job_type:
-                available.add(job_type)
-    return available
+                moves.types.add(job_type)
+                moves.available.append(rec.key)
+        elif intent in _JOB_ENDED:
+            moves.ended.append(rec.key)
+    return moves
 
 
 class ProcessingResultBuilder:

@@ -51,8 +51,9 @@ from zeebe_tpu.stream.api import (
     ProcessingResultBuilder,
     ProcessingScheduleService,
     RecordProcessor,
-    activatable_job_types,
+    job_moves,
 )
+from zeebe_tpu.stream.job_wait import JobWaitStamps
 
 from zeebe_tpu.protocol.intent import ProcessInstanceIntent as _PI
 
@@ -120,6 +121,9 @@ class StreamProcessor:
         # jobsAvailable callback → gateway long-poll wakeup / job push);
         # receives the set of job types a committed step made activatable
         self.on_jobs_available: Callable[[set], None] | None = None
+        # how long a job waits for its worker (stream/job_wait.py): stamped
+        # and observed with the post-commit effects below
+        self.job_stamps = JobWaitStamps(str(log_stream.partition_id))
         self.phase = Phase.INITIAL
         self._positions = db.column_family(ColumnFamilyCode.LAST_PROCESSED_POSITION)
         # replicated request dedupe (ISSUE 9): materialized here on BOTH the
@@ -1102,17 +1106,25 @@ class StreamProcessor:
             self._release_acks(notes)
 
     def _emit_group_effects(self, builders: list) -> None:
+        """Post-commit: each step's responses and tasks go out, its jobs'
+        wait stamps are taken (before its responses: the push dispatcher
+        reads a job's stamp once the activation has answered), and the types
+        it made activatable are notified."""
         from zeebe_tpu.engine.burst_templates import PreparedBurst
 
+        stamps = self.job_stamps
         job_types: set = set()
         for result in builders:
             if isinstance(result, PreparedBurst):
+                stamps.moved(result.jobs_available, (), result.jobs_ended)
                 for _extra, record, stream_id, request_id in result.responses:
                     self.response_sink(ClientResponse(record, stream_id, request_id))
                 job_types |= result.job_types
             else:
+                moves = job_moves(result.follow_ups)
+                stamps.moved(moves.available, moves.activated, moves.ended)
                 self._execute_side_effects(result)
-                job_types |= activatable_job_types(result.follow_ups)
+                job_types |= moves.types
         self._notify_jobs_available(job_types)
 
     def _group_commit_point(self) -> None:
@@ -1258,8 +1270,7 @@ class StreamProcessor:
             self._group_commit_point()
             self._run_deferred_effects()
         else:
-            self._execute_side_effects(builder)
-            self._notify_jobs_available(activatable_job_types(builder.follow_ups))
+            self._emit_group_effects((builder,))
         self._observe_follow_ups(builder.follow_ups)
         self._m_processed.inc()
         elapsed = _time.perf_counter() - start
