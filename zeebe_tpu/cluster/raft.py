@@ -22,16 +22,22 @@ from __future__ import annotations
 
 import enum
 import json
+import logging
 import os
+import queue
 import random
+import threading
 from pathlib import Path
 from time import perf_counter as _perf_counter
 from typing import Any, Callable
 
 from zeebe_tpu.cluster.messaging import MessagingService
 from zeebe_tpu.journal import SegmentedJournal
-from zeebe_tpu.journal.journal import CorruptedJournalError
+from zeebe_tpu.journal.journal import CorruptedJournalError, FlushWork
 from zeebe_tpu.protocol.msgpack import packb, unpackb
+from zeebe_tpu.utils import storage_io
+
+logger = logging.getLogger("zeebe_tpu.cluster.raft")
 
 HEARTBEAT_INTERVAL_MS = 250
 ELECTION_TIMEOUT_MS = 2_500
@@ -200,7 +206,9 @@ class RaftNode:
         ).labels(pid)
         self._m_flush_duration = REGISTRY.histogram(
             "flush_duration_seconds",
-            "seconds per raft journal fsync", ("partition",)).labels(pid)
+            "seconds per durability barrier of the raft log: one journal's "
+            "fsync, or one joint flush of a partition's co-located replicas",
+            ("partition",)).labels(pid)
         self._m_deferred_appends = REGISTRY.counter(
             "deferred_append_count_total",
             "appends acked before fsync (delayed flush policy)",
@@ -210,6 +218,30 @@ class RaftNode:
             "raft log reads by where the answer came from: the in-memory "
             "tail of the log, or the journal file", ("partition", "source"))
         self._m_reads_tail = log_reads.labels(pid, "tail")
+        # always on, named into the partition pipeline's family, which
+        # dashboards and the benchmark read side by side (ISSUE 36): what a
+        # commit costs, the fsync alone, and how many journals one
+        # durability barrier synced together
+        self._m_replicate = REGISTRY.histogram(
+            "stream_processor_pipeline_replicate",
+            "seconds per entry on the leader from RaftNode.append entered to "
+            "the commit index covering it (its own fsync, the followers' "
+            "appends and fsyncs, the deliveries between)", ("partition",),
+            buckets=(0.00025, 0.0005, 0.001, 0.0015, 0.002, 0.003, 0.005,
+                     0.01, 0.025, 0.1, 1.0)).labels(pid)
+        self._m_raft_fsync = REGISTRY.histogram(
+            "stream_processor_pipeline_raft_fsync",
+            "seconds per flush of a replica's raft journal (drain, write, "
+            "fsync, flush marker): the durability barrier before an "
+            "acknowledgement, alone or inside a joint flush", ("partition",),
+            buckets=(0.0001, 0.00025, 0.0005, 0.00075, 0.001, 0.0015, 0.002,
+                     0.003, 0.005, 0.01, 0.1, 1.0)).labels(pid)
+        self._m_flush_pass = REGISTRY.histogram(
+            "stream_processor_pipeline_flush_pass",
+            "raft journals synced together by one durability barrier (a "
+            "count, not a time): 1 for a replica's own inline barrier, the "
+            "number of co-located dirty replicas for a joint flush",
+            ("partition",), buckets=(1, 2, 3, 4, 5, 8)).labels(pid)
         self._m_reads_journal = log_reads.labels(pid, "journal")
         self._election_started_ms: int | None = None
         self._leader_since_ms: int | None = None
@@ -260,6 +292,23 @@ class RaftNode:
         self._flushed_index = min(self.journal.last_flushed_index,
                                   self.journal.last_index)
         self._flush_dirty = False
+        # joint flush (ISSUE 36): set by an owner that pumps several replicas
+        # of this partition on one thread (ClusterRuntime, through
+        # JointFlusher.flush). Such a node, while it has other members,
+        # leaves the barrier of an appended entry to the owner, which syncs
+        # the partition's dirty journals at the same time and then releases
+        # each replica's acknowledgement; _ack_index() holds at the flushed
+        # prefix meanwhile, so nothing is acked or counted before its fsync.
+        # Never set from configuration: whoever sets it takes on the passes.
+        self.joint_flush = False
+        # the leader and term a follower owes an append-resp once its
+        # deferred barrier is taken (None: nothing owed)
+        self._held_answer: tuple[str, int] | None = None
+        # set by every cut of the journal; _on_append_request reads it to
+        # keep a request that truncated on the inline barrier
+        self._log_cut = False
+        # leader only: perf_counter at append() by index, until committed
+        self._replicating: dict[int, float] = {}
         # boot-time rot suspicion (ISSUE 14): the open() scan truncates the
         # journal at the first corrupt frame — safe for a torn UNFSYNCED
         # tail (those bytes were never acked), but at-rest bit rot can land
@@ -381,18 +430,28 @@ class RaftNode:
             finally:
                 os.close(dir_fd)
 
-    def _after_local_append(self) -> None:
+    def _after_local_append(self, joint: bool = False) -> None:
         """Durability barrier after appending entries, before acknowledging
-        them (follower ack, or leader counting itself toward the quorum)."""
+        them (follower ack, or leader counting itself toward the quorum).
+        ``joint``: the caller can wait for its owner's joint flush (a
+        leader's append, a follower's plain append of entries); the barrier
+        is left to it only where an owner registered this node and the node
+        has other members — a quorum of one, and every node nobody
+        registered, syncs here and now."""
         if self.flush_policy == "immediate":
+            joint = joint and self.joint_flush and len(self.members) > 1
             if self.flush_interval_s <= 0:
+                if joint and self.journal.last_index != self._flushed_index:
+                    self._flush_dirty = True
+                    return
                 self._flush_journal()
                 return
             # group-commit posture: defer the fsync up to flush_interval_s
             # or the byte bound; _ack_index() holds at the flushed prefix,
-            # so deferral delays the ack — it never precedes the fsync
+            # so deferral delays the ack — it never precedes the fsync. A
+            # barrier that is due at once goes to the joint flush too.
             self._flush_dirty = True
-            if self._group_flush_due():
+            if self._group_flush_due() and not joint:
                 self._flush_journal()
         elif self.flush_policy == "delayed":
             self._flush_dirty = True
@@ -426,28 +485,96 @@ class RaftNode:
             try:
                 self.journal.flush()
             except OSError as exc:
-                # fsyncgate (ISSUE 14): the journal already failed the
-                # segment hard — fresh fd, file re-verified from the last
-                # known-flushed offset, suffix discarded. Our job is the
-                # consensus side of the contract: nothing the failed fsync
-                # covered may be acked, and a LEADER whose own log just
-                # rewound must stop leading (re-appending at reused indexes
-                # in the same term would hand followers conflicting entries
-                # the protocol cannot detect). The surviving cluster
-                # re-elects; this node re-converges as a follower.
-                self._flushed_index = min(self._flushed_index,
-                                          self.journal.last_index)
-                self._tail.cut_after(self.journal.last_index)
-                self._flush_dirty = False
-                self._last_flush_perf = _perf_counter()
-                self._note_storage_error(exc)
-                if self.role == RaftRole.LEADER:
-                    self._become(RaftRole.FOLLOWER)
+                self._flush_failed(exc)
                 return
-            self._m_flush_duration.observe(_perf_counter() - start)
-            self._flushed_index = self.journal.last_index
+            seconds = _perf_counter() - start
+            self._flushed(seconds)
+            self._m_flush_duration.observe(seconds)
+            self._m_flush_pass.observe(1)
         self._flush_dirty = False
         self._last_flush_perf = _perf_counter()
+
+    def _flushed(self, seconds: float) -> None:
+        self._m_raft_fsync.observe(seconds)
+        self._flushed_index = self.journal.last_index
+
+    def _flush_failed(self, exc: OSError) -> None:
+        # fsyncgate (ISSUE 14): the journal already failed the
+        # segment hard — fresh fd, file re-verified from the last
+        # known-flushed offset, suffix discarded. Our job is the
+        # consensus side of the contract: nothing the failed fsync
+        # covered may be acked, and a LEADER whose own log just
+        # rewound must stop leading (re-appending at reused indexes
+        # in the same term would hand followers conflicting entries
+        # the protocol cannot detect). The surviving cluster
+        # re-elects; this node re-converges as a follower.
+        self._flushed_index = min(self._flushed_index,
+                                  self.journal.last_index)
+        self._tail.cut_after(self.journal.last_index)
+        self._flush_dirty = False
+        self._held_answer = None
+        self._last_flush_perf = _perf_counter()
+        self._note_storage_error(exc)
+        if self.role == RaftRole.LEADER:
+            self._become(RaftRole.FOLLOWER)
+
+    def _flush_due(self) -> bool:
+        """A deferred barrier is pending and nothing says to wait longer:
+        the joint flush's (interval 0), or the group-commit posture's once
+        its interval or byte bound is reached."""
+        return (self._flush_dirty and self.flush_policy == "immediate"
+                and (self.flush_interval_s <= 0 or self._group_flush_due()))
+
+    def _release_held_acks(self) -> None:
+        """After a deferred barrier was taken, release what it was holding:
+        the leader re-counts its own durable vote; a follower answers the
+        leader whose entries it took, at the term it took them in (waiting
+        for the next heartbeat would add up to HEARTBEAT_INTERVAL_MS to
+        every deferred commit). A node that changed leader or term since —
+        a leader that stepped down with unsynced entries among them — owes
+        nobody an answer: its log may no longer be a prefix of the new
+        leader's, and the next append request settles that inline."""
+        if self.role == RaftRole.LEADER:
+            self._advance_commit()
+        elif (self.role == RaftRole.FOLLOWER
+              and self._held_answer == (self.leader_id, self.current_term)
+              and self.leader_id != self.member_id):
+            self._send(self.leader_id, "append-resp", {
+                "term": self.current_term, "success": True,
+                "lastIndex": self._ack_index(),
+                "follower": self.member_id,
+            })
+        self._held_answer = None
+
+    # -- joint flush (ISSUE 36): the owner's three steps ------------------------
+
+    def begin_joint_flush(self) -> FlushWork | None:
+        """Owner's thread. The file work of this node's pending barrier, to
+        be run beside the other replicas'; None where nothing is due."""
+        if not self._flush_due():
+            return None
+        if self.journal.last_index == self._flushed_index:
+            self._flush_journal()  # nothing left to sync: only the release
+            self._release_held_acks()
+            return None
+        return self.journal.begin_flush()
+
+    def finish_joint_flush(self, work: FlushWork) -> None:
+        """Owner's thread, after ``work.run()`` returned on whichever
+        thread: the bookkeeping _flush_journal does after journal.flush()
+        (flushed index, the journal's listeners and metrics; on
+        ``OSError`` the fsyncgate rewind and a leader's step-down), then
+        the acknowledgement the barrier was holding."""
+        start = _perf_counter()
+        try:
+            self.journal.finish_flush(work)
+        except OSError as exc:
+            self._flush_failed(exc)
+            return
+        self._flushed(work.seconds + (_perf_counter() - start))
+        self._flush_dirty = False
+        self._last_flush_perf = _perf_counter()
+        self._release_held_acks()
 
     def _truncate_after(self, index: int) -> None:
         had_config_after = any(
@@ -455,6 +582,7 @@ class RaftNode:
         )
         self.journal.truncate_after(index)
         self._tail.cut_after(index)
+        self._log_cut = True
         # conflicting entries re-appended on top of a truncation must be
         # fsynced again even when the log lands back on the old flushed index
         self._flushed_index = min(self._flushed_index, index)
@@ -475,6 +603,7 @@ class RaftNode:
     def _reset_journal(self, next_index: int) -> None:
         self.journal.reset(next_index)
         self._tail.clear()
+        self._log_cut = True
         self._flushed_index = min(self._flushed_index, next_index - 1)
         # the log prefix (and any config entries in it) is gone: the current
         # membership becomes the configuration base for rollbacks
@@ -670,19 +799,11 @@ class RaftNode:
                 # leader re-counts its own durable vote, a follower
                 # proactively acks the leader (waiting for the next
                 # heartbeat would add up to HEARTBEAT_INTERVAL_MS to every
-                # deferred commit)
-                if self.flush_interval_s <= 0 or self._group_flush_due():
+                # deferred commit). A joint flush its owner has not taken
+                # by now (it takes one before every tick) is taken here too.
+                if self._flush_due():
                     self._flush_journal()
-                    if self.role == RaftRole.LEADER:
-                        self._advance_commit()
-                    elif (self.role == RaftRole.FOLLOWER
-                          and self.leader_id is not None
-                          and self.leader_id != self.member_id):
-                        self._send(self.leader_id, "append-resp", {
-                            "term": self.current_term, "success": True,
-                            "lastIndex": self._ack_index(),
-                            "follower": self.member_id,
-                        })
+                    self._release_held_acks()
             else:
                 self._flush_journal()  # delayed flush policy drains here
         if self.role == RaftRole.LEADER:
@@ -946,14 +1067,20 @@ class RaftNode:
         quorum (reference: AppendListener.onCommit)."""
         if self.role != RaftRole.LEADER:
             return None
+        entered = _perf_counter()
         index = self._append_local({
             "term": self.current_term, "init": False, "asqn": asqn, "data": data,
         })
-        self._after_local_append()
+        # send, sync together, acknowledge (ISSUE 36): where an owner's
+        # joint flush is coming the entry goes out to the followers before
+        # this journal is synced, and the leader's own vote waits at its
+        # flushed prefix (_ack_index) until the joint flush releases it
+        self._after_local_append(joint=True)
         if self.role != RaftRole.LEADER:
             # a failed fsync inside the append stepped this leader down and
             # rewound the suffix — the caller must treat this as not-leader
             return None
+        self._replicating[index] = entered
         if on_commit is not None:
             self._pending_appends[index] = on_commit
         self._broadcast_appends()
@@ -1040,6 +1167,7 @@ class RaftNode:
                 "follower": self.member_id,
             })
             return
+        self._log_cut = False
         for entry in req["entries"]:
             index = entry["index"]
             local_term = self._entry_term(index)
@@ -1048,12 +1176,26 @@ class RaftNode:
             elif local_term != entry["term"]:
                 self._truncate_after(index - 1)
                 self._append_at(index, entry)
-        self._after_local_append()  # flush BEFORE acking (Raft durability)
+        # flush BEFORE acking (Raft durability): here and now, or — a plain
+        # append of entries on a node whose owner syncs its partition's
+        # replicas together — in the owner's joint flush, which then sends
+        # the answer held below. A heartbeat and a request that cut the log
+        # keep the inline barrier.
+        self._after_local_append(
+            joint=bool(req["entries"]) and not self._log_cut)
         # the leader's commit index as last advertised — lets a joining
         # replica detect when it has fully caught up (topology PARTITION_JOIN)
         self.leader_commit_hint = max(self.leader_commit_hint, req["commit"])
         if req["commit"] > self.commit_index:
             self._set_commit(min(req["commit"], self._last_log_index()))
+        if self._flush_dirty and self.flush_policy == "immediate":
+            # the barrier is deferred: the release after it answers this
+            # leader at this term. A joint flush is at most a delivery pass
+            # away and there is nothing to say before it; the group-commit
+            # posture answers now with its flushed prefix, as before
+            self._held_answer = (sender, self.current_term)
+            if self.flush_interval_s <= 0:
+                return
         self._send(sender, "append-resp", {
             "term": self.current_term, "success": True,
             # group-commit posture acks only the flushed prefix; the leader
@@ -1120,6 +1262,13 @@ class RaftNode:
             self._m_leader_transition.observe(
                 (self.clock_millis() - self._leader_since_ms) / 1000.0)
             self._leader_since_ms = None
+        if self._replicating:
+            now = _perf_counter()
+            for pending_index in list(self._replicating):  # in append order
+                if pending_index > index:
+                    break
+                self._m_replicate.observe(
+                    now - self._replicating.pop(pending_index))
         for pending_index in sorted(self._pending_appends):
             if pending_index <= index:
                 self._pending_appends.pop(pending_index)(pending_index)
@@ -1238,6 +1387,8 @@ class RaftNode:
             self._leader_since_ms = None
         if role != RaftRole.LEADER:
             self._pending_appends.clear()
+            self._replicating.clear()
+        self._held_answer = None
         for listener in self.role_listeners:
             listener(role, self.current_term)
 
@@ -1250,3 +1401,108 @@ class RaftNode:
     def committed_entries(self, from_index: int) -> list[dict]:
         """Entries up to the commit index (application entries only carry data)."""
         return self._read_entries(from_index, upto=self.commit_index)
+
+
+# -- joint flush (ISSUE 36) -----------------------------------------------------
+
+
+class _SyncHelper(threading.Thread):
+    """A persistent helper of one JointFlusher: runs the file work it is
+    handed (``FlushWork.run``: drain, write, fsync, flush marker; it never
+    raises) and says when it is done. It touches no RaftNode."""
+
+    def __init__(self, name: str) -> None:
+        super().__init__(daemon=True, name=name)
+        self.inbox: queue.SimpleQueue = queue.SimpleQueue()
+        self.done: queue.SimpleQueue = queue.SimpleQueue()
+        self.start()
+
+    def run(self) -> None:
+        while True:
+            work = self.inbox.get()
+            if work is None:
+                return
+            try:
+                work.run()
+            finally:
+                self.done.put(work)  # the owner waits on this, always
+
+
+class JointFlusher:
+    """The durability barrier of one partition's co-located replicas, taken
+    by the thread that owns them all (``ClusterRuntime._run_partition``,
+    under the partition's lock).
+
+    On machines of their own a partition's replicas fsync at the same time
+    by construction, and a leader writes to its own disk while it
+    replicates. Replicas that share a process share one ownership thread,
+    and with each barrier inside its append handler an entry's fsyncs were
+    taken one after another before anything could commit. Here they are
+    taken together: ``flush(nodes)`` collects the replicas whose barrier is
+    due (``RaftNode.begin_joint_flush``), runs the file work of all of them
+    at once — the caller takes one, persistent helper threads the others;
+    ``os.fsync`` releases the GIL — joins, and then, on the caller's thread
+    and replica by replica, does each flush's bookkeeping and releases the
+    acknowledgement it held (``RaftNode.finish_joint_flush``). The order of
+    every replica is unchanged: append, sync, acknowledge; only the file
+    work leaves the thread.
+
+    With a storage-fault plane installed (``utils/storage_io.py``: its
+    seeded faults are drawn in call order) the journals are synced in the
+    order given, on the caller's thread, so a seed replays the same faults;
+    the clean path is the concurrent one."""
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+        self._helpers: list[_SyncHelper] = []
+        self._logged: set[str] = set()
+
+    def flush(self, nodes: list[RaftNode]) -> int:
+        """One pass; returns how many journals it synced."""
+        pending = []
+        for node in nodes:
+            node.joint_flush = True  # from its next append on
+            work = node.begin_joint_flush()
+            if work is not None:
+                pending.append((node, work))
+        if not pending:
+            return 0
+        start = _perf_counter()
+        if len(pending) == 1 or storage_io.controller() is not None:
+            for _node, work in pending:
+                work.run()
+        else:
+            while len(self._helpers) < len(pending) - 1:
+                self._helpers.append(_SyncHelper(
+                    f"{self.name}-sync-{len(self._helpers) + 1}"))
+            busy = list(zip(self._helpers, pending[1:]))
+            for helper, (_node, work) in busy:
+                helper.inbox.put(work)
+            pending[0][1].run()
+            for helper, _ in busy:
+                helper.done.get()
+        for node, work in pending:
+            try:
+                node.finish_joint_flush(work)
+                self._logged.discard(node.member_id)
+            except Exception:  # noqa: BLE001 — one replica's fault (its
+                # journal closed under it) must not keep the others' acks;
+                # its barrier stays pending and its acks stay held
+                if node.member_id not in self._logged:
+                    self._logged.add(node.member_id)
+                    logger.exception(
+                        "joint flush of %s partition %s failed; retrying "
+                        "(logged once per streak)", node.member_id,
+                        node.partition_id)
+        # one barrier, one observation: the journal-flush controller reads
+        # flush_duration_seconds' rate x p50 as the fsync duty cycle, and
+        # journals synced at the same time are busy once, not three times
+        first = pending[0][0]
+        first._m_flush_pass.observe(len(pending))
+        first._m_flush_duration.observe(_perf_counter() - start)
+        return len(pending)
+
+    def close(self) -> None:
+        for helper in self._helpers:
+            helper.inbox.put(None)
+        self._helpers.clear()

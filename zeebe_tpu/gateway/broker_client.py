@@ -21,6 +21,7 @@ from typing import Any
 from zeebe_tpu.broker import Broker, BrokerCfg
 from zeebe_tpu.broker.broker import resolve_leader_partition
 from zeebe_tpu.cluster.messaging import LoopbackNetwork
+from zeebe_tpu.cluster.raft import JointFlusher
 from zeebe_tpu.parallel.partitioning import subscription_partition_id
 from zeebe_tpu.protocol import Record
 from zeebe_tpu.protocol.keys import decode_partition_id
@@ -230,17 +231,38 @@ class ClusterRuntime(GatewayRuntimeBase):
 
     def _run_partition(self, pid: int) -> None:
         logged: set[str] = set()
-        while self._running:
-            with self._plocks[pid]:
-                self._pump_brokers(lambda b: b.pump_partition(pid), logged)
-                try:
-                    moved = self.net.deliver_lane(pid)
-                except Exception:  # noqa: BLE001 — deliver_one already guards
-                    # handler errors; this guards queue-level corruption
-                    logger.exception("partition %s delivery failed", pid)
-                    moved = 0
-            if moved == 0:
-                time.sleep(0.001)
+        # this partition's replicas share this thread, so their durability
+        # barriers are taken here, together (cluster/raft.py: JointFlusher):
+        # a turn settles what gateway threads appended since the last one,
+        # pumps, and settles what the pump appended
+        flusher = JointFlusher(f"partition-{pid}")
+        try:
+            while self._running:
+                with self._plocks[pid]:
+                    moved = self._settle(pid, flusher)
+                    self._pump_brokers(lambda b: b.pump_partition(pid), logged)
+                    moved += self._settle(pid, flusher)
+                if moved == 0:
+                    time.sleep(0.001)
+        finally:
+            flusher.close()
+
+    def _settle(self, pid: int, flusher: JointFlusher) -> int:
+        """Deliver the partition's messages (a follower that took entries
+        appends them and holds its answer), sync every dirty Raft journal of
+        the partition at once, release the acknowledgements, and deliver
+        those to the leader, which commits. Returns the messages moved."""
+        try:
+            moved = self.net.deliver_lane(pid)
+            nodes = [p.raft for b in self.brokers.values()
+                     if (p := b.partitions.get(pid)) is not None]
+            if flusher.flush(nodes):
+                moved += self.net.deliver_lane(pid)
+        except Exception:  # noqa: BLE001 — deliver_one already guards
+            # handler errors; this guards queue-level corruption
+            logger.exception("partition %s delivery failed", pid)
+            moved = 0
+        return moved
 
     def _run_control(self) -> None:
         logged: set[str] = set()

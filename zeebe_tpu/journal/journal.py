@@ -27,6 +27,7 @@ can later be swapped for the C++ implementation without contract changes.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import logging
 import os
 import struct
@@ -407,24 +408,9 @@ class _Segment:
         self._read_hint = None
 
     def flush(self) -> None:
-        start = _perf()
-        self._drain()
-        self.file.flush()
-        try:
-            storage_io.fsync(self.file.fileno(), self.path)
-        except OSError as exc:
-            # fsyncgate (ISSUE 14): after a failed fsync the page cache
-            # state is UNDEFINED — retrying on the same fd can "succeed"
-            # without the earlier dirty pages ever reaching the platter
-            # (the PostgreSQL fsyncgate lesson). Fail the segment hard:
-            # drop the fd, reopen, re-verify from the last known-flushed
-            # offset; everything the failed fsync covered is discarded and
-            # must never count toward an acked prefix.
-            self._reopen_after_failed_fsync()
-            raise FlushFailedError(
-                exc.errno, f"fsync failed on {self.path}: {exc}") from exc
-        self.durable_size = self.size
-        _M_SEGMENT_FLUSH.observe(_perf() - start)
+        work = FlushWork(self)
+        work.run()
+        work.settle()
 
     def _reopen_after_failed_fsync(self) -> None:
         self._pending.clear()
@@ -498,6 +484,71 @@ class _Segment:
         self._pending_bytes = 0
         self.close()
         self.path.unlink(missing_ok=True)
+
+
+class FlushWork:
+    """One flush of a segment in two parts, so that several journals can be
+    synced at the same time (``os.fsync`` releases the GIL). ``run`` is the
+    file work and nothing else — drain the write buffer, write, fsync and,
+    once the fsync returned, the journal's advisory flush marker; it touches
+    the segment's file and write buffer and the marker's file only (no
+    listener, no raft state), never raises, and may run on another thread
+    while the caller holds the journal still (no append, read or cut
+    meanwhile). ``settle``, on the owning thread again, is what the file
+    work's outcome does to the segment."""
+
+    __slots__ = ("segment", "covered_bytes", "marker", "seconds", "error",
+                 "fsync_failed")
+
+    def __init__(self, segment: _Segment, covered_bytes: int = 0,
+                 marker: "Callable[[], None] | None" = None) -> None:
+        self.segment = segment
+        self.covered_bytes = covered_bytes
+        self.marker = marker
+        self.seconds = 0.0
+        self.error: Exception | None = None
+        self.fsync_failed = False
+
+    def run(self) -> None:
+        segment = self.segment
+        start = _perf()
+        try:  # whatever is raised is kept for settle, on the owning thread
+            segment._drain()
+            segment.file.flush()
+        except Exception as exc:  # noqa: BLE001
+            self.error = exc
+        else:
+            try:
+                storage_io.fsync(segment.file.fileno(), segment.path)
+            except Exception as exc:  # noqa: BLE001
+                self.error = exc
+                self.fsync_failed = isinstance(exc, OSError)
+            else:
+                if self.marker is not None:
+                    self.marker()  # written only after the fsync returned
+        self.seconds = _perf() - start
+
+    def settle(self) -> None:
+        segment = self.segment
+        exc = self.error
+        if exc is None:
+            segment.durable_size = segment.size
+            _M_SEGMENT_FLUSH.observe(self.seconds)
+            return
+        if not self.fsync_failed:
+            # a write fault of the drain: the buffered frames are kept and
+            # the next drain re-seeks over a torn prefix
+            raise exc
+        # fsyncgate (ISSUE 14): after a failed fsync the page cache
+        # state is UNDEFINED — retrying on the same fd can "succeed"
+        # without the earlier dirty pages ever reaching the platter
+        # (the PostgreSQL fsyncgate lesson). Fail the segment hard:
+        # drop the fd, reopen, re-verify from the last known-flushed
+        # offset; everything the failed fsync covered is discarded and
+        # must never count toward an acked prefix.
+        segment._reopen_after_failed_fsync()
+        raise FlushFailedError(
+            exc.errno, f"fsync failed on {segment.path}: {exc}") from exc
 
 
 class SegmentedJournal:
@@ -706,22 +757,37 @@ class SegmentedJournal:
         JournalMetaStore last-flushed index). The meta write is advisory —
         recovery re-derives state from segment scans — so it is a plain
         8-byte overwrite, not an fsync'd rename, keeping the hot append path
-        at one fsync per flush."""
+        at one fsync per flush. Three steps (ISSUE 36), which a caller that
+        syncs several journals at once takes apart: ``begin_flush`` on the
+        owning thread, the returned work's ``run`` on any thread, and
+        ``finish_flush`` on the owning thread again."""
+        work = self.begin_flush()
+        work.run()
+        return self.finish_flush(work)
+
+    def begin_flush(self) -> "FlushWork":
         self._flush_append_metrics()
-        covered_bytes = self._unflushed_bytes
+        return FlushWork(
+            self.segments[-1], self._unflushed_bytes,
+            functools.partial(self._write_flush_marker,
+                              max(self.last_index, 0)))
+
+    def finish_flush(self, work: "FlushWork") -> int:
+        """The bookkeeping of a flush whose file work has run: on a failed
+        fsync fail the segment hard and raise; else the durable size, the
+        metrics and the flush listeners."""
         start = _perf()
         try:
-            self.segments[-1].flush()
+            work.settle()
         except OSError:
             _M_FAILED_FLUSH.inc()
             raise
         idx = self.last_index
-        self._write_flush_marker(max(idx, 0))
         self._unflushed_bytes = 0
         self._last_flush_t = _perf()
         _M_LAST_FLUSHED.set(max(idx, 0))
         _M_FLUSHES.inc()
-        elapsed = _perf() - start
+        elapsed = work.seconds + (_perf() - start)
         _M_FLUSH_SECONDS.observe(elapsed)
         _M_FLUSH_TIME.observe(elapsed)
         if slow_flush_listeners and elapsed >= SLOW_FLUSH_THRESHOLD_S:
@@ -743,7 +809,7 @@ class SegmentedJournal:
             # group-flush span: the durability edge every gated ack waits on
             # (flushes are group-commit cadence, not per-append — cheap)
             _TRACER.emit("infra:journal", "journal.flush", elapsed,
-                         attrs={"coveredBytes": covered_bytes,
+                         attrs={"coveredBytes": work.covered_bytes,
                                 "lastIndex": idx})
         return idx
 
