@@ -10,7 +10,9 @@ A definition is a dict::
      "defaults": {"gw": "flow_3"}}
 
 ``type`` is the BPMN tag (``startEvent``, ``endEvent``, ``serviceTask`` with a
-``job_type``, ``exclusiveGateway``, ``parallelGateway``, ``subProcess``);
+``job_type``, ``exclusiveGateway``, ``parallelGateway``, ``subProcess``,
+``intermediateCatchEvent`` with a ``timer_ms`` or a ``message`` and its
+``correlation_variable``);
 ``parent`` names the enclosing sub-process (None: the process itself); a
 ``condition`` is ``["x", ">", 10]``, the only comparison the mixes use. The
 same dict is written out as BPMN XML for the deployment and walked by the
@@ -34,10 +36,9 @@ class _Builder:
     def __init__(self, pid: str) -> None:
         self.d = {"id": pid, "nodes": [], "flows": [], "defaults": {}}
 
-    def node(self, nid: str, tag: str, parent=None, job_type=None) -> str:
+    def node(self, nid: str, tag: str, parent=None, **attrs) -> str:
         node = {"id": nid, "type": tag, "parent": parent}
-        if job_type is not None:
-            node["job_type"] = job_type
+        node.update((k, v) for k, v in attrs.items() if v is not None)
         self.d["nodes"].append(node)
         return nid
 
@@ -129,14 +130,45 @@ def embedded_subprocess(pid: str) -> dict:
     return b.d
 
 
+def _catch_then_task(pid: str, catch: str, job_type, **attrs) -> dict:
+    """start -> intermediate catch event ``catch`` -> service task (of
+    ``job_type``, by default one of its own) -> end."""
+    b = _Builder(pid)
+    b.node("s", "startEvent")
+    b.node(catch, "intermediateCatchEvent", **attrs)
+    b.node("task", "serviceTask", job_type=job_type or f"work_{pid}")
+    b.node("e", "endEvent")
+    for source, target in (("s", catch), (catch, "task"), ("task", "e")):
+        b.flow(source, target)
+    return b.d
+
+
+def message_catch(pid: str, message: str, correlation_variable: str,
+                  job_type: str | None = None) -> dict:
+    """A catch of ``message``, correlated by the value of the instance's
+    ``correlation_variable``, then a task: upstream's ``msg_one_task.bpmn``
+    as restated."""
+    return _catch_then_task(pid, "catch", job_type, message=message,
+                            correlation_variable=correlation_variable)
+
+
+def timer_catch(pid: str, duration_ms: int, job_type: str | None = None) -> dict:
+    """A timer catch of ``duration_ms``, then a task: upstream's
+    ``timerProcess.bpmn`` as restated."""
+    return _catch_then_task(pid, "wait", job_type, timer_ms=int(duration_ms))
+
+
 KINDS = {"task_chain": task_chain, "exclusive_chain": exclusive_chain,
          "fork_join": fork_join, "route": route,
-         "embedded_subprocess": embedded_subprocess}
+         "embedded_subprocess": embedded_subprocess,
+         "message_catch": message_catch, "timer_catch": timer_catch}
 
 
 def build_definitions(specs: list) -> list:
     """``specs``: the traffic file's ``definitions`` — each ``{"kind": ...,
-    "id": ..., <the kind's own keys>}``."""
+    "id": ..., <the kind's own keys>}``, and optionally ``"weight"``: how
+    many times the definition's requests come in a round of the plan
+    (default 1; kept on the definition only where the entry names it)."""
     out = []
     for spec in specs:
         spec = dict(spec)
@@ -144,7 +176,14 @@ def build_definitions(specs: list) -> list:
         if kind not in KINDS:
             raise ValueError(f"unknown definition kind {kind!r}; known: "
                              f"{sorted(KINDS)}")
-        out.append(KINDS[kind](spec.pop("id"), **spec))
+        weight = spec.pop("weight", None)
+        d = KINDS[kind](spec.pop("id"), **spec)
+        if weight is not None:
+            if int(weight) != weight or weight < 1:
+                raise ValueError(f"definition {d['id']!r}: weight {weight!r} "
+                                 "is not a whole number of 1 or more")
+            d["weight"] = int(weight)
+        out.append(d)
     return out
 
 
@@ -164,6 +203,18 @@ def jobs_per_instance(d: dict):
     splits = any(n["type"] == "exclusiveGateway" and leaving.get(n["id"], 0) > 1
                  for n in d["nodes"])
     return None if tasks and splits else tasks
+
+
+def catch_of(d: dict):
+    """The definition's intermediate catch event (a node dict), or None."""
+    return next((n for n in d["nodes"] if n["type"] == "intermediateCatchEvent"),
+                None)
+
+
+def longest_timer_ms(definitions: list) -> int:
+    """The longest timer any instance of the mix waits on (0: none)."""
+    return max((n.get("timer_ms", 0) for d in definitions for n in d["nodes"]),
+               default=0)
 
 
 def max_fanout(definitions: list) -> int:
@@ -203,6 +254,24 @@ def _xml_scope(d: dict, parent, indent: str) -> list:
                 f'retries="3" />',
                 f"{indent}  </bpmn:extensionElements>",
                 f"{indent}</bpmn:serviceTask>"]
+        elif tag == "intermediateCatchEvent" and "message" in n:
+            lines += [
+                f"{indent}<bpmn:intermediateCatchEvent{attrs}>",
+                f"{indent}  <bpmn:extensionElements>",
+                f'{indent}    <zeebe:subscription correlationKey='
+                f'"= {n["correlation_variable"]}" />',
+                f"{indent}  </bpmn:extensionElements>",
+                f'{indent}  <bpmn:messageEventDefinition '
+                f'messageRef="{_message_ref(n["message"])}" />',
+                f"{indent}</bpmn:intermediateCatchEvent>"]
+        elif tag == "intermediateCatchEvent":
+            lines += [
+                f"{indent}<bpmn:intermediateCatchEvent{attrs}>",
+                f"{indent}  <bpmn:timerEventDefinition>",
+                f"{indent}    <bpmn:timeDuration>"
+                f"{iso_duration(n['timer_ms'])}</bpmn:timeDuration>",
+                f"{indent}  </bpmn:timerEventDefinition>",
+                f"{indent}</bpmn:intermediateCatchEvent>"]
         else:
             lines.append(f"{indent}<bpmn:{tag}{attrs} />")
     for f in d["flows"]:
@@ -222,12 +291,27 @@ def _xml_scope(d: dict, parent, indent: str) -> list:
     return lines
 
 
+def iso_duration(millis: int) -> str:
+    """An ISO 8601 duration of whole or fractional seconds: 10000 -> PT10S."""
+    seconds, rest = divmod(int(millis), 1000)
+    return f"PT{seconds}S" if not rest else f"PT{seconds}.{rest:03d}S"
+
+
+def _message_ref(name: str) -> str:
+    return f"message_{name}"
+
+
 def to_bpmn_xml(d: dict) -> str:
     lines = [
         "<?xml version='1.0' encoding='utf-8'?>",
         f'<bpmn:definitions xmlns:bpmn="{BPMN_NS}" xmlns:zeebe="{ZEEBE_NS}" '
-        f'targetNamespace="http://zeebe-tpu/bpmn">',
-        f'  <bpmn:process id="{d["id"]}" name="{d["id"]}" isExecutable="true">']
+        f'targetNamespace="http://zeebe-tpu/bpmn">']
+    # a message is declared beside the process, its catch refers to it
+    lines += [f'  <bpmn:message id="{_message_ref(name)}" name="{escape(name)}" />'
+              for name in sorted({n["message"] for n in d["nodes"]
+                                  if "message" in n})]
+    lines.append(
+        f'  <bpmn:process id="{d["id"]}" name="{d["id"]}" isExecutable="true">')
     lines += _xml_scope(d, None, "    ")
     lines += ["  </bpmn:process>", "</bpmn:definitions>"]
     return "\n".join(lines) + "\n"
@@ -267,23 +351,58 @@ def payload_bytes(payload: dict) -> int:
     return len(json.dumps(payload, separators=(",", ":")))
 
 
+def correlation_variable(d: dict):
+    """The variable whose value correlates a message to an instance of
+    ``d``, or None where its instances wait for no message."""
+    catch = catch_of(d)
+    return None if catch is None else catch.get("correlation_variable")
+
+
+def message_variables(correlation_key: str) -> dict:
+    """The document a mix's publisher sends with the message for one
+    correlation key: the catch merges it into the instance, so an instance
+    that holds another key's document was reached by another's message."""
+    return {"published_for": correlation_key}
+
+
 def first_touch_plan(definitions: list, partitions: int, payload: dict) -> list:
     """Definition order, twice round the partitions: every partition's
     registry then grows its table set in the same order run to run, so the
-    device programs and their compile-cache keys repeat."""
-    return [(d["id"], {"x": X_VALUES[i % len(X_VALUES)], **payload})
-            for d in definitions for i in range(2 * partitions)]
+    device programs and their compile-cache keys repeat. A request that
+    waits for a message carries a key of its own, the same in every run."""
+    plan = []
+    for d in definitions:
+        var = correlation_variable(d)
+        for i in range(2 * partitions):
+            variables = {"x": X_VALUES[i % len(X_VALUES)]}
+            if var is not None:
+                variables[var] = f"first-touch-{len(plan)}"
+            plan.append((d["id"], {**variables, **payload}))
+    return plan
 
 
 def request_plan(definitions: list, n: int, payload: dict, seed: int) -> list:
     """``n`` requests ``(process id, variables)``. Every seed draws from the
-    same set — each definition with each ``x`` equally often — in another
-    order: whole rounds of (definition x value) pairs, each round shuffled."""
+    same set — each definition with each ``x`` equally often, or ``weight``
+    times as often — in another order: whole rounds of (definition x value)
+    pairs, each round shuffled. A request of a definition that waits for a
+    message carries a correlation key of its own, ``<tag>-<i>`` with ``i``
+    its place in the plan and the tag drawn from the seed by a generator of
+    its own, so that the shuffle draws what it drew without one."""
     rng = random.Random(seed)
-    combos = [(d["id"], x) for d in definitions for x in X_VALUES]
+    combos = [(d["id"], x) for d in definitions
+              for _ in range(d.get("weight", 1)) for x in X_VALUES]
     plan: list = []
     while len(plan) < n:
         block = list(combos)
         rng.shuffle(block)
         plan += block
-    return [(pid, {"x": x, **payload}) for pid, x in plan[:n]]
+    keyed = {d["id"]: correlation_variable(d) for d in definitions}
+    tag = f"{random.Random(seed ^ 0xC0441E).getrandbits(64):016x}"
+    out = []
+    for i, (pid, x) in enumerate(plan[:n]):
+        variables = {"x": x}
+        if keyed[pid] is not None:
+            variables[keyed[pid]] = f"{tag}-{i}"
+        out.append((pid, {**variables, **payload}))
+    return out

@@ -17,11 +17,15 @@ which parent and child share on one machine): in the open loop the schedule's
 time, in the closed loop the moment its creator was free to send it: when its
 last create was acknowledged or, with ``"on": "completion"``, when the
 completion of its last instance's last job was acknowledged to a worker here.
+A mix with ``messages`` has a publisher besides: each create of an instance
+that waits for the message is followed by one publish with its correlation
+key, stamped as a create is; the window answers once those are answered too.
 """
 
 from __future__ import annotations
 
 import argparse
+import heapq
 import inspect
 import json
 import os
@@ -123,6 +127,11 @@ def jobs_to_wait_for(traffic: dict):
     jobs = {}
     for spec, d in zip(traffic["definitions"],
                        defs.build_definitions(traffic["definitions"])):
+        if spec["kind"] in ("message_catch", "timer_catch"):
+            raise ValueError(
+                f"definition {d['id']!r} of kind {spec['kind']!r} under a loop "
+                "closed on completions: its instances wait for a message or a "
+                "timer, which the workers cannot see")
         jobs[d["id"]] = defs.jobs_per_instance(d)
         if not jobs[d["id"]]:
             raise ValueError(
@@ -130,6 +139,118 @@ def jobs_to_wait_for(traffic: dict):
                 "closed on completions: its instances run no job, or a number "
                 "that may depend on x, so the workers cannot tell when one is done")
     return jobs
+
+
+#: the keys of a mix's ``messages``
+MESSAGE_KEYS = {"name", "publish_after_ms", "ttl_ms", "senders"}
+
+
+def messages_of(traffic: dict):
+    """The mix's ``messages`` and, for it, process id -> the variable that
+    correlates its instances; None where the mix has no ``messages``. A
+    definition that waits for a message no publisher sends, a publisher of a
+    message no definition waits for, or a key missing is refused by name."""
+    waiting = {d["id"]: (catch["message"], catch["correlation_variable"])
+               for d in defs.build_definitions(traffic["definitions"])
+               if (catch := defs.catch_of(d)) is not None and "message" in catch}
+    spec = traffic.get("messages")
+    name = None if spec is None else spec.get("name")
+    if unsent := sorted(pid for pid, (m, _) in waiting.items() if m != name):
+        raise ValueError(f"definitions {unsent} wait for a message that no "
+                         "publisher sends (the mix's \"messages\")")
+    if spec is None:
+        return None
+    if set(spec) != MESSAGE_KEYS:
+        raise ValueError(f"messages: keys {sorted(spec)}, wanted "
+                         f"{sorted(MESSAGE_KEYS)}")
+    after = spec["publish_after_ms"]
+    if not (isinstance(after, list) and after
+            and all(isinstance(a, (int, float)) and a >= 0 for a in after)):
+        raise ValueError(f"messages.publish_after_ms {after!r}: a list of "
+                         "delays in ms, none negative")
+    if not waiting:
+        raise ValueError(f"messages: no definition waits for {name!r}")
+    return spec, {pid: var for pid, (_m, var) in waiting.items()}
+
+
+class Publisher:
+    """The mix's ``messages``: for every create of a definition that waits
+    for the message, one ``PublishMessage`` with that create's correlation
+    key, ``publish_after_ms`` after the create was due (the list's delays
+    dealt round the creates in the order they were made), sent from a pool of
+    ``senders`` of its own and retried as a create is."""
+
+    def __init__(self, gen: "LoadGen", spec: dict, waiting: dict) -> None:
+        self.after_s = [float(a) / 1e3 for a in spec["publish_after_ms"]]
+        self.name, self.ttl_ms = spec["name"], int(spec["ttl_ms"])
+        self.waiting = waiting
+        self.gen = gen
+        self.pool = ThreadPoolExecutor(max_workers=int(spec["senders"]))
+        self.cond = threading.Condition()
+        self.heap: list = []    # (due, order, publish record)
+        self.dealt = 0
+        self.open = 0           # scheduled, not yet answered
+        self.closed = False
+        gen._spawn(self._run)
+
+    def schedule(self, pid: str, variables: dict, due: float,
+                 phase: str) -> None:
+        var = self.waiting.get(pid)
+        if var is None:
+            return
+        with self.cond:
+            after = self.after_s[self.dealt % len(self.after_s)]
+            rec = {"phase": phase, "pid": pid,
+                   "correlation_key": variables[var], "create_due": due,
+                   "due": due + after}
+            heapq.heappush(self.heap, (rec["due"], self.dealt, rec))
+            self.dealt += 1
+            self.open += 1
+            self.cond.notify()
+
+    def _run(self) -> None:
+        with self.cond:
+            while not self.closed:
+                if not self.heap:
+                    self.cond.wait()
+                    continue
+                left = self.heap[0][0] - time.monotonic()
+                if left > 0:
+                    self.cond.wait(left)
+                    continue
+                self.pool.submit(self.gen._guard, self._publish,
+                                 heapq.heappop(self.heap)[2])
+
+    def _publish(self, rec: dict) -> None:
+        gen = self.gen
+        client = gen.client()
+        rec["sent"] = time.monotonic()
+        key, attempts, sheds = gen.retry.call(
+            "publish", client.publish_message, self.name,
+            rec["correlation_key"],
+            variables=defs.message_variables(rec["correlation_key"]),
+            ttl_ms=self.ttl_ms, give_up_at=rec["sent"] + gen.give_up_s)
+        rec.update(ack=time.monotonic(), ok=key is not None, key=key,
+                   attempts=attempts, sheds=sheds)
+        with gen.lock:
+            gen.publishes.append(rec)
+            gen.rpcs += attempts
+            gen.sheds += sheds
+        with self.cond:
+            self.open -= 1
+            self.cond.notify_all()
+
+    def drain(self) -> None:
+        """Until every publish scheduled so far was answered or given up."""
+        with self.cond:
+            while self.open and self.gen.error is None:
+                self.cond.wait(0.1)
+
+    def close(self) -> None:
+        with self.cond:
+            self.closed = True
+            self.cond.notify_all()
+        self.pool.shutdown(wait=True, cancel_futures=True)
 
 
 class LoadGen:
@@ -152,6 +273,7 @@ class LoadGen:
         self.payload = defs.make_payload(traffic.get("payload"), seed)
         self.give_up_s = float(traffic.get("give_up_s", 10.0))
         self.records: list = []
+        self.publishes: list = []
         self.completed_jobs: list = []   # keys of acknowledged completions
         self.rpcs = 0
         self.sheds = 0
@@ -164,6 +286,8 @@ class LoadGen:
         self.error: BaseException | None = None
         self.t0: float | None = None
         self.t_end: float | None = None
+        messages = messages_of(traffic)
+        self.publisher = None if messages is None else Publisher(self, *messages)
 
     def client(self):
         if not hasattr(self.local, "client"):
@@ -175,6 +299,8 @@ class LoadGen:
     # -- one create ---------------------------------------------------------
 
     def create(self, pid: str, variables: dict, due: float, phase: str) -> dict:
+        if self.publisher is not None:
+            self.publisher.schedule(pid, variables, due, phase)
         sent = time.monotonic()
         inst, attempts, sheds = self.retry.call(
             "create", self.client().create_instance, pid, variables=variables,
@@ -184,6 +310,8 @@ class LoadGen:
                "sent": sent, "ack": ack, "ok": inst is not None,
                "key": None if inst is None else inst.process_instance_key,
                "attempts": attempts, "sheds": sheds}
+        if self.publisher is not None and pid in self.publisher.waiting:
+            rec["correlation_key"] = variables[self.publisher.waiting[pid]]
         with self.lock:
             self.records.append(rec)
             self.rpcs += attempts
@@ -365,6 +493,8 @@ class LoadGen:
             for t in self.warm_threads:
                 t.join()
             self.pool.shutdown(wait=True)
+        if self.publisher is not None:
+            self.publisher.drain()
         # this process's cores from the window's opening until its last
         # request was answered: whether the generator is what bounds a cell
         cpu_cores = (time.process_time() - cpu0) / (time.monotonic() - t0)
@@ -377,11 +507,25 @@ class LoadGen:
         with open(path, "w") as out:
             for r in records:
                 out.write(json.dumps(r) + "\n")
-        return {"records_file": path, "window_requests": len(window),
-                "rpcs": self.rpcs - rpcs0, "sheds": self.sheds - sheds0,
-                "cpu_cores": round(cpu_cores, 3)}
+        answer = {"records_file": path, "window_requests": len(window),
+                  "rpcs": self.rpcs - rpcs0, "sheds": self.sheds - sheds0,
+                  "cpu_cores": round(cpu_cores, 3)}
+        if self.publisher is not None:
+            with self.lock:
+                publishes = [r for r in self.publishes
+                             if r["phase"] != "first_touch"]
+            answer["publishes_file"] = os.path.join(self.out_dir,
+                                                    "publishes.jsonl")
+            with open(answer["publishes_file"], "w") as out:
+                for r in publishes:
+                    out.write(json.dumps(r) + "\n")
+            answer["window_publishes"] = sum(r["phase"] == "window"
+                                             for r in publishes)
+        return answer
 
     def stop(self) -> dict:
+        if self.publisher is not None:
+            self.publisher.close()
         for w in self.workers:
             w.stop()
         for c in self.clients:

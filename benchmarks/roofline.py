@@ -12,8 +12,9 @@ only, no matmul: the kernel is memory-bound and its least time is bytes over
 the chip's memory bandwidth.
 
 Token steps are counted from the records of the window, not from the device:
-every element instance that activated took one step to pass its element, and
-a service task a second one when its job completed.
+every element instance that activated took one step to pass its element, a
+service task a second one when its job completed, and a catch event a second
+one when its timer triggered or its message was correlated.
 """
 
 from __future__ import annotations
@@ -33,6 +34,8 @@ def token_steps(events: list) -> int:
             steps += 1      # an element inside the process, not the process
         elif event[0] == "JOB" and event[1] == "COMPLETED":
             steps += 1      # the task's second pass
+        elif event[:2] in (("TIMER", "TRIGGERED"), ("PMS", "CORRELATED")):
+            steps += 1      # the catch's second pass
     return steps
 
 

@@ -19,7 +19,8 @@ for a rehearsal: it ends with exit code 3 and prints **no** result line.
 program's own Raft path (``lying_follower``), on the replicas' disks once the
 cluster has stopped (``torn_snapshot``), in the state a deployment is seeded
 with (``lose_parked``), in the running partition's state store as the window
-opens (``forget_parked``) or where the harness takes its answers (the others) —
+opens (``forget_parked``), in what the replicas' logs are read to hold
+(``lose_publish``) or where the harness takes its answers (the others) —
 for the controls and the fault tests (benchmarks/tests/); never used by a
 measurement.
 """
@@ -39,6 +40,7 @@ import sys
 import tempfile
 import threading
 import time
+from collections import Counter
 from pathlib import Path
 
 T_PROCESS_START = time.monotonic()
@@ -58,7 +60,7 @@ REHEARSAL_EXIT = 3
 REFUSED_EXIT = 2
 FAULTS = ("lying_follower", "lose_acked", "at_least_once", "alter_record",
           "replica_export_differs", "torn_snapshot", "lose_parked",
-          "forget_parked")
+          "forget_parked", "double_correlate", "early_timer", "lose_publish")
 
 
 class Refused(Exception):
@@ -199,6 +201,18 @@ def window_metrics(window: list, completed_at: dict, all_completions: list,
     }
 
 
+def by_definition(window: list, completed_at: dict) -> dict:
+    """The median completion, ms, of each definition's creates due in the
+    window, a create that never completed counted as missing."""
+    out = {}
+    for pid in sorted({r["pid"] for r in window}):
+        mine = [r for r in window if r["pid"] == pid]
+        done = [completed_at[r["key"]] - r["due"] for r in mine
+                if r["ok"] and r["key"] in completed_at]
+        out[pid] = round(1e3 * schedule.percentile(done, 0.50, len(mine)), 3)
+    return out
+
+
 def decide_correct(numbers: dict) -> bool:
     """``numbers``: name -> ``{"value", "limit"}``; every value within its
     limit (a limit is an upper bound; ``min`` marks a lower one)."""
@@ -208,12 +222,16 @@ def decide_correct(numbers: dict) -> bool:
 
 def compare(definitions: list, requests: list, observed_events: dict,
             completed_at: dict, returned: dict, completed_jobs: list,
-            logs: dict, marks: dict, position_of: dict | None = None) -> dict:
+            logs: dict, marks: dict, position_of: dict | None = None,
+            publishes: list = ()) -> dict:
     """The comparison that decides ``correct``, over every acknowledged
     create of the run: its exported records against the plain reference, its
-    completion, and — for it and for every acknowledged job completion — its
-    presence in every replica's log on disk; and the replicas' logs against
-    each other, byte for byte, as far as all had committed. Exact: limits 0.
+    completion, and — for it, for every acknowledged job completion and for
+    every acknowledged publish of a message — its presence in every
+    replica's log on disk; and the replicas' logs against each other, byte
+    for byte, as far as all had committed. Exact: limits 0. A message catch
+    is held to the acknowledged publish of its correlation key
+    (``publishes``: the child's records of them).
 
     ``logs``: ``served.replica_logs``; ``marks``: (partition, broker) -> the
     replica's commit index while the cluster ran; ``position_of``: key -> the
@@ -224,17 +242,25 @@ def compare(definitions: list, requests: list, observed_events: dict,
     by_id = {d["id"]: d for d in definitions}
     acked = [r for r in requests if r["ok"]]
     never = [r["key"] for r in acked if r["key"] not in completed_at]
+    messages = {p["correlation_key"]: {
+        "key": p["key"], "variables": defs.message_variables(p["correlation_key"])}
+        for p in publishes if p["ok"]}
     bad = reference.mismatches(
         by_id, [(r["key"], r["pid"], r["variables"]) for r in acked
-                if r["key"] in completed_at], observed_events, returned)
+                if r["key"] in completed_at], observed_events, returned,
+        messages)
     # a key's upper bits name its partition (the protocol's layout)
     keys = {r["key"] for r in acked}
     jobs = set(completed_jobs)
+    published = {m["key"] for m in messages.values()}
     missing = differing = under_a_snapshot = 0
     position_of = position_of or {}
     for (pid, _broker), log in logs.items():
         gone = ({k for k in keys if k >> 51 == pid} - log["created"]) | (
             {k for k in jobs if k >> 51 == pid} - log["jobs_completed"])
+        if published:
+            gone |= ({k for k in published if k >> 51 == pid}
+                     - log["messages_published"])
         covered = log.get("snapshot_position", 0)
         kept = sum(1 for k in gone if 0 < position_of.get(k, math.inf) <= covered)
         under_a_snapshot += kept
@@ -256,6 +282,16 @@ def compare(definitions: list, requests: list, observed_events: dict,
     }
     return {"numbers": numbers, "examples": bad[:3], "never": never[:3],
             "under_a_snapshot": under_a_snapshot}
+
+
+def lose_a_publish(logs: dict, publishes: list) -> int:
+    """The fault ``lose_publish``: the first acknowledged publish of the
+    window, by its message key, is taken out of what one replica's log on
+    disk is read to hold. Returns the message's key."""
+    key = min(p["key"] for p in publishes if p["ok"] and p["phase"] == "window")
+    replica = min(r for r in logs if r[0] == key >> 51)
+    logs[replica]["messages_published"].discard(key)
+    return key
 
 
 def parked_checks(seeded: dict, replicas: int, running: dict, logs: dict,
@@ -348,8 +384,16 @@ def run(args) -> int:
     try:    # what the child would refuse, before there is a cluster to start
         loadgen.worker_arguments(traffic["workers"])
         loadgen.jobs_to_wait_for(traffic)
+        messages = loadgen.messages_of(traffic)
+        timer_s = defs.longest_timer_ms(
+            defs.build_definitions(traffic["definitions"])) / 1e3
+        if timer_s and timer_s >= float(
+                traffic.get("setup", {}).get("drain_max_s", 60.0)):
+            raise ValueError(f"setup.drain_max_s does not exceed the longest "
+                             f"timer, {timer_s:g} s")
     except ValueError as err:
         raise Refused(f"traffic mix {cell['traffic']!r}: {err}") from None
+    keyed = {} if messages is None else messages[1]
     state = config.get("state")
     try:
         if state is not None:
@@ -388,6 +432,7 @@ def run(args) -> int:
     setup = traffic.get("setup", {})
     definitions = defs.build_definitions(traffic["definitions"])
     payload = defs.make_payload(traffic.get("payload"), args.seed)
+    catches = any(defs.catch_of(d) is not None for d in definitions)
 
     from zeebe_tpu.engine.device_health import shared_device_health
 
@@ -481,6 +526,7 @@ def run(args) -> int:
         sleep_until(t0)
         observed.fault = args.fault
         counters0 = system.counters()
+        routing0 = system.routing() if catches else None
         elections0 = system.elections()
         compiles0 = ledger.compiles
         cpu0, t0_wall = cpu_clocks(), time.time()
@@ -493,6 +539,7 @@ def run(args) -> int:
         sleep_until(t0 + seconds)
         cpu = cpu_shares(cpu0, cpu_clocks(), seconds)
         counters1 = system.counters()
+        routing1 = system.routing() if catches else None
         elections_in_window = system.elections() - elections0
         compiles_in_window = ledger.compiles - compiles0
         reply = child.answer(seconds + 90.0)
@@ -500,6 +547,11 @@ def run(args) -> int:
                     Path(reply["records_file"]).read_text().splitlines()]
         for r in requests:
             r["variables"] = {"x": r["x"], **payload}
+            if "correlation_key" in r:
+                r["variables"][keyed[r["pid"]]] = r["correlation_key"]
+        publishes = ([json.loads(line) for line in Path(
+            reply["publishes_file"]).read_text().splitlines()]
+            if "publishes_file" in reply else [])
         acked = [r["key"] for r in requests if r["ok"]]
         drain_start = time.monotonic()
         wait_completed(observed, acked, float(setup.get("drain_max_s", 60.0)),
@@ -533,6 +585,9 @@ def run(args) -> int:
                 "snapshots damaged on the stopped replicas' disks")
         t_check = time.monotonic()
         logs = srv.replica_logs(data_dir / "data", layout, seeded)
+        if args.fault == "lose_publish":
+            say(f"lose_publish: the replicas' logs are read without message "
+                f"{lose_a_publish(logs, publishes)}")
     finally:
         if child is not None:
             child.close()
@@ -560,8 +615,32 @@ def run(args) -> int:
         "peaks": peaks, "max_fanout": defs.max_fanout(definitions),
         "token_steps_per_s": steps / seconds,
     }
-    say(f"window: {json.dumps({k: round(v, 3) for k, v in numbers.items()})}")
+    shown = {k: round(v, 3) for k, v in numbers.items()}
+    if catches:
+        shown["completion_p50_ms_by_definition"] = by_definition(
+            window, completed_at)
+    if messages is not None:
+        # how the window's messages met their subscriptions
+        sides = reference.message_sides(events)
+        shown["messages_met"] = dict(Counter(
+            str(reference.path_of(sides.get(r["correlation_key"], [])))
+            for r in window if r["ok"] and "correlation_key" in r))
+    say(f"window: {json.dumps(shown)}")
     say(f"counts in window: {json.dumps(delta)}")
+    if catches:
+        routed = routing1 - routing0
+        caught = Counter(e[:2] for key in window_keys for e in events.get(key, ())
+                         if e[:2] in (("TIMER", "TRIGGERED"),
+                                      ("PMS", "CORRELATED")))
+        duration = {d["id"]: defs.longest_timer_ms([d]) for d in definitions}
+        lags = [e[6] - (e[5] - duration[r["pid"]]) for r in window if r["ok"]
+                for e in events.get(r["key"], ()) if e[:2] == ("TIMER", "CREATED")]
+        say(f"catch path: the window's instances were triggered "
+            f"{caught[('TIMER', 'TRIGGERED')]} times and correlated "
+            f"{caught[('PMS', 'CORRELATED')]}, a timer's record stamped "
+            f"{min(lags, default=0)}-{max(lags, default=0)} ms after the "
+            f"clock its due date was read from; commands routed in the window "
+            f"{json.dumps(dict(routed))}")
     say(f"child: window={ {k: v for k, v in reply.items() if k != 'records_file'} } "
         f"stop={stopped} drain_s={drain_s:.2f}")
     snapshots = sorted(round(log["snapshot_written_at"] - t0_wall, 1)
@@ -587,18 +666,25 @@ def run(args) -> int:
 
     if args.keep_events:
         kept = {}
+        sides = reference.message_sides(events)
+        published = {p["correlation_key"]: p["key"] for p in publishes if p["ok"]}
         for r in requests:
-            if r["ok"] and r["key"] in completed_at:
-                kept.setdefault(f"{r['pid']}:{r['x']}", {
-                    "pid": r["pid"], "variables": r["variables"],
-                    "events": events[r["key"]]})
+            name = f"{r['pid']}:{r['x']}"
+            if r["ok"] and r["key"] in completed_at and name not in kept:
+                kept[name] = {"pid": r["pid"], "variables": r["variables"],
+                              "events": events[r["key"]]}
+                if "correlation_key" in r:   # and its message's own sequence
+                    kept[name].update(
+                        key=r["key"],
+                        message_key=published.get(r["correlation_key"]),
+                        message_side=sides.get(r["correlation_key"], []))
         Path(args.keep_events).write_text(json.dumps(
             {"definitions": traffic["definitions"], "instances": kept}))
 
     # ---- correct
     returned = payload if traffic["workers"]["complete_with_payload"] else {}
     verdict = compare(definitions, requests, events, completed_at, returned,
-                      completed_jobs, logs, marks, position_of)
+                      completed_jobs, logs, marks, position_of, publishes)
     checks = verdict["numbers"]
     checks["exports_differing_at_a_position"] = {"value": observed.differing,
                                                  "limit": 0}

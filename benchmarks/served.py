@@ -63,6 +63,17 @@ class Observed:
             return self.ranks.setdefault(key, len(self.ranks)) % 10 == 0
 
 
+def _breakable(event: tuple, fault: str) -> bool:
+    """The records a fault may break: ``double_correlate`` a correlation,
+    ``early_timer`` a trigger, the others an instance's own records (not the
+    message partition's, which are kept by correlation key)."""
+    if fault == "double_correlate":
+        return event[:2] == ("PMS", "CORRELATED")
+    if fault == "early_timer":
+        return event[:2] == ("TIMER", "TRIGGERED")
+    return event[0] in ("PI", "JOB", "VAR", "TIMER", "PMS")
+
+
 def _broken(event: tuple, fault: str):
     """The control and the fault tests: what the timed path produced, broken
     where the harness takes it (one instance in ten: ``Observed.broken``).
@@ -71,9 +82,15 @@ def _broken(event: tuple, fault: str):
     ``at_least_once``: a job's completion is applied twice (exactly-once
     broken); ``alter_record``: a token is sent down another flow;
     ``replica_export_differs``: as ``alter_record``, but in a later replica's
-    export of a position, not in the first."""
+    export of a position, not in the first; ``double_correlate``: a message
+    is correlated to its instance twice; ``early_timer``: a timer's trigger
+    is stamped a millisecond before its due date."""
     if fault == "lose_acked":
         return []
+    if fault == "double_correlate":
+        return [event, event]
+    if fault == "early_timer":
+        return [event[:6] + (event[5] - 1,)]
     if fault == "at_least_once" and event[:2] == ("JOB", "COMPLETED"):
         return [event, event]
     if (fault in ("alter_record", "replica_export_differs")
@@ -92,6 +109,46 @@ def names_process(record, process_id: str) -> bool:
                    for job in value.get("jobs") or ()))
 
 
+def catch_event(record, value, kind: str) -> tuple:
+    """``(event, key, acked)`` of a catch event's record, as the reference's
+    plain tuples. On the instance's partition, in its events, by the
+    instance's key:
+        ("TIMER", intent, element_id, timer_key, element_instance_key,
+         due_date, record_timestamp)
+        ("PMS", intent, element_id, element_instance_key, message_name,
+         correlation_key, message_key)
+    On the message's partition, by ``("MESSAGE", correlation key)``: the
+    message partition's own sequence for that key, in its log order:
+        ("MS", intent, element_id, message_name, process_instance_key,
+         message_key)
+        ("MESSAGE", intent, message_key)
+    and a batch of expiries by ``("MESSAGE_BATCH",)``:
+        ("MESSAGE_BATCH", intent, (message_key, ...))
+    ``acked``: the message key of a ``PUBLISHED``, which a publish's
+    acknowledgement rests on; else None. ``kind``: the record's value type,
+    by the first word of the tuple it makes."""
+    intent = record.intent.name
+    if kind == "TIMER":
+        return (("TIMER", intent, value["targetElementId"], record.key,
+                 value["elementInstanceKey"], value["dueDate"],
+                 record.timestamp), value["processInstanceKey"], None)
+    if kind == "PMS":
+        return (("PMS", intent, value["targetElementId"],
+                 value["elementInstanceKey"], value["messageName"],
+                 value["correlationKey"], value.get("messageKey", -1)),
+                value["processInstanceKey"], None)
+    if kind == "MS":
+        return (("MS", intent, value["targetElementId"], value["messageName"],
+                 value["processInstanceKey"], value.get("messageKey", -1)),
+                ("MESSAGE", value["correlationKey"]), None)
+    if kind == "MESSAGE":
+        return (("MESSAGE", intent, record.key),
+                ("MESSAGE", value["correlationKey"]),
+                record.key if intent == "PUBLISHED" else None)
+    return (("MESSAGE_BATCH", intent, tuple(value["messageKeys"])),
+            ("MESSAGE_BATCH",), None)
+
+
 def capture_exporter(observed: Observed):
     """The standard exporter SPI, as ``ZEEBE_BROKER_EXPORTERS_*`` would load
     it. Completion is observed here, where a deployment observes it."""
@@ -104,6 +161,11 @@ def capture_exporter(observed: Observed):
     pi_type, job_type, var_type = (ValueType.PROCESS_INSTANCE, ValueType.JOB,
                                    ValueType.VARIABLE)
     creation_type = ValueType.PROCESS_INSTANCE_CREATION
+    catch_types = {ValueType.TIMER: "TIMER",
+                   ValueType.PROCESS_MESSAGE_SUBSCRIPTION: "PMS",
+                   ValueType.MESSAGE_SUBSCRIPTION: "MS",
+                   ValueType.MESSAGE: "MESSAGE",
+                   ValueType.MESSAGE_BATCH: "MESSAGE_BATCH"}
 
     class CaptureExporter(Exporter):
         def export(self, logged) -> None:
@@ -130,10 +192,16 @@ def capture_exporter(observed: Observed):
                              value["value"])
                 if event is not None:
                     key = value["processInstanceKey"]
+                elif value_type in catch_types:
+                    # after the three above, so that their records cost
+                    # what they cost without it
+                    event, key, acked = catch_event(record, value,
+                                                    catch_types[value_type])
             kept = [] if event is None else [event]
             fault = observed.fault
             where = (record.partition_id, logged.position)
-            if event is not None and fault is not None and observed.broken(key):
+            if (event is not None and fault is not None
+                    and _breakable(event, fault) and observed.broken(key)):
                 if fault != "replica_export_differs" or where in observed.seen:
                     kept = _broken(event, fault)
             said = (int(record.record_type), int(value_type),
@@ -334,6 +402,21 @@ class Served:
                                         for d in runner.shard_devices)
                 if runner is not None else []}
 
+    def routing(self) -> Counter:
+        """How the kernel backends routed commands so far, cumulative: each
+        host-path reason (a head command's names its kind, as
+        ``head-not-admittable:TIMER.TRIGGER``) and, by definition,
+        ``kernel:<id>`` / ``host:<id>``, the commands that rode a kernel
+        group and those that took the host path."""
+        out: Counter = Counter()
+        for b in self.backends():
+            out.update(b.fallback_reasons)
+            for definition, (kernel, host, *_rest) in (
+                    b.accounting.per_definition.items()):
+                out[f"kernel:{definition}"] += kernel
+                out[f"host:{definition}"] += host
+        return out
+
     def state_keys(self) -> int:
         """Committed keys in the largest partition's state, over all
         replicas: what a seeded deployment starts its window with."""
@@ -472,7 +555,8 @@ def replica_logs(data_dir: Path, layout: dict, parked: dict | None = None) -> di
     """(partition, broker) -> what that replica's Raft log holds **on disk**,
     read once the cluster is stopped and with none of its memory: ``entries``
     (raft index -> the entry's bytes), ``created`` (the instance keys whose
-    creation it holds) and ``jobs_completed`` (the job keys whose completion
+    creation it holds), ``jobs_completed`` (the job keys whose completion
+    it holds) and ``messages_published`` (the message keys whose publication
     it holds). The durability side of an acknowledgement. A saturated
     partition snapshots within a run and compacts its log behind the
     snapshot: ``snapshot_position`` is the processed position of the newest
@@ -494,7 +578,7 @@ def replica_logs(data_dir: Path, layout: dict, parked: dict | None = None) -> di
     from zeebe_tpu.journal.journal import read_only_records
     from zeebe_tpu.logstreams.log_stream import _deserialize_batch
     from zeebe_tpu.protocol import ValueType
-    from zeebe_tpu.protocol.intent import (JobIntent,
+    from zeebe_tpu.protocol.intent import (JobIntent, MessageIntent,
                                            ProcessInstanceCreationIntent)
     from zeebe_tpu.protocol.msgpack import unpackb
     from zeebe_tpu.state.snapshot import FileBasedSnapshotStore, load_chain_db
@@ -506,7 +590,7 @@ def replica_logs(data_dir: Path, layout: dict, parked: dict | None = None) -> di
             log_dir = data_dir / name / f"partition-{pid}" / "raft" / "raft-log"
             if not log_dir.is_dir():
                 continue
-            entries, created, jobs, in_log = {}, set(), set(), 0
+            entries, created, jobs, published, in_log = {}, set(), set(), set(), 0
             for journal_record in read_only_records(log_dir):
                 entry = unpackb(journal_record.data)
                 data = entry.get("data")
@@ -527,6 +611,9 @@ def replica_logs(data_dir: Path, layout: dict, parked: dict | None = None) -> di
                     elif (record.value_type == ValueType.JOB
                           and record.intent == JobIntent.COMPLETED):
                         jobs.add(record.key)
+                    elif (record.value_type == ValueType.MESSAGE
+                          and record.intent == MessageIntent.PUBLISHED):
+                        published.add(record.key)
             covered, written_at, db = 0, None, None
             store = data_dir / name / f"partition-{pid}" / "snapshots"
             if store.is_dir():
@@ -545,6 +632,7 @@ def replica_logs(data_dir: Path, layout: dict, parked: dict | None = None) -> di
             del db      # a seeded state is a million rows: one at a time
             out[(pid, name)] = {"entries": entries, "created": created,
                                 "jobs_completed": jobs,
+                                "messages_published": published,
                                 "snapshot_position": covered,
                                 "snapshot_written_at": written_at}
             if parked is not None:

@@ -13,9 +13,10 @@ import pytest
 import run
 
 STEADY = "default3x3.one_task_steady"
-#: not a cell of the benchmark yet (PERF.md section 7): run from a manifest
-#: of the test's own, the steady twin's entry with the capacity mix
+#: not cells of the benchmark yet (PERF.md section 7): run from a manifest
+#: of the test's own, the steady twin's entry with the mix of the name
 CAPACITY = "default3x3.one_task_capacity"
+MIX = "default3x3.default_mix_steady"
 CASES = [(STEADY, None, True),
          (STEADY, "lying_follower", False),  # control, in the program's Raft
                                              # path: acks without a quorum
@@ -28,14 +29,19 @@ CASES = [(STEADY, None, True),
          (CAPACITY, "lose_acked", False),
          # no snapshot falls inside a window this short, so tearing those on
          # the disks changes nothing (served.damage_snapshots has its own test)
-         (CAPACITY, "torn_snapshot", True)]
+         (CAPACITY, "torn_snapshot", True),
+         # upstream's whole default mix: a message and a timer catch besides
+         (MIX, None, True),
+         (MIX, "double_correlate", False),   # a message correlated twice
+         (MIX, "early_timer", False),        # a trigger before its due date
+         (MIX, "lose_publish", False)]       # an acknowledged publish lost
 
 
 def manifest_with(cell: str, tmp_path) -> str:
     """BENCHMARK.json with ``cell`` beside its steady twin: the same
     deployment and metrics, the mix of the cell's own name."""
     manifest = json.loads((run.ROOT / "BENCHMARK.json").read_text())
-    twin = cell.replace("_capacity", "_steady")
+    twin = STEADY if cell == MIX else cell.replace("_capacity", "_steady")
     entry = next(w for w in manifest["workloads"] if w["name"] == twin)
     manifest["workloads"].append({**entry, "name": cell,
                                   "traffic": cell.split(".")[1]})
@@ -54,7 +60,7 @@ def test_correct_under_fault(cell, fault, expected, tmp_path):
     cmd = [sys.executable, str(run.HERE / "run.py"), "--workload", cell,
            "--seed", str(2**31 + 17), "--seconds", "4", "--trace", "0",
            "--rehearse-cpu"] + (["--fault", fault] if fault else [])
-    if cell == CAPACITY:
+    if cell != STEADY:
         cmd += ["--manifest", manifest_with(cell, tmp_path)]
     proc = subprocess.run(cmd, capture_output=True, text=True, timeout=600,
                           env={**os.environ, "JAX_PLATFORMS": "cpu"})

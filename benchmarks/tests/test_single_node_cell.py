@@ -72,7 +72,9 @@ def test_every_metric_the_cell_reports_lists_it():
     assert {m["name"] for m in what["end_to_end"]} == {
         "completed_per_s", "completion_p50_ms", "setup_s"}
     assert {m["name"] for m in what["per_layer"]} >= PER_LAYER
-    for m in MANIFEST["per_layer"]:
+    # a metric that reads what only other cells have (job_push_ms_per_job:
+    # jobs pushed to streams) does not list this one
+    for m in what["per_layer"]:
         assert CELL in m["workloads"], m["name"]
         assert m["moves"] in {"completed_per_s", "completion_p50_ms"}
     # the two that read a histogram of this PR are read in both cells
