@@ -447,7 +447,8 @@ class ZeebePartition:
             self.processor.on_ack = self.latency_observatory.observe
         self.processor.start()
         self.checkers = DueDateCheckers(
-            self.engine.state, self.processor.schedule_service, self.clock_millis
+            self.engine.state, self.processor.schedule_service, self.clock_millis,
+            self.processor.catch_stamps,
         )
         if self.tiering_cfg is not None:
             # fresh manager per transition over the fresh db (the seams —

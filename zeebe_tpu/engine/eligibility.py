@@ -359,6 +359,16 @@ class PathAccounting:
         self._children: dict = {}
         self._kernel_child = self._records_total.labels(
             self.partition, "kernel", "-")
+        #: (path, ``<ValueType>.<Intent>``) → commands processed: ``kernel``
+        #: in a committed kernel group, ``host`` on the sequential path
+        self.kinds: Counter = Counter()
+        self._by_kind = REGISTRY.counter(
+            "kernel_records_by_kind_total",
+            "commands processed by the stream processor, by path (kernel: "
+            "in a committed kernel group; host: on the sequential path) and "
+            "command kind",
+            ("partition", "path", "kind"))
+        self._kind_children: dict = {}
 
     def _def_slot(self, definition: str) -> list:
         slot = self.per_definition.get(definition)
@@ -388,6 +398,17 @@ class PathAccounting:
         slot = self._def_slot(definition)
         slot[0] += n
         self._set_coverage(slot)
+
+    def note_kind(self, path: str, record) -> None:
+        """One command of ``record``'s kind was processed on ``path``."""
+        key = (path, record.value_type, record.intent)
+        slot = self._kind_children.get(key)
+        if slot is None:
+            kind = f"{record.value_type.name}.{record.intent.name}"
+            slot = self._kind_children[key] = (
+                (path, kind), self._by_kind.labels(self.partition, path, kind))
+        self.kinds[slot[0]] += 1
+        slot[1].inc()
 
     def note_host(self, reason: str, definition: str = "-") -> None:
         """One head command took the host path for ``reason`` (a catalog

@@ -2877,14 +2877,17 @@ class KernelBackend:
     def note_group_success(self, pg: _PendingGroup) -> None:
         """Per-definition kernel-path accounting for one materialized group
         (coverage gauge + parity gate), batched per definition to bound
-        gauge writes. Called by the processor AFTER the group's transaction
-        commits — noting inside ``finish_group`` would double-count the
-        group when a post-materialization commit failure rolls it back and
-        the same commands re-admit on the next pump."""
+        gauge writes, and each command by kind. Called by the processor
+        AFTER the group's transaction commits — noting inside
+        ``finish_group`` would double-count the group when a
+        post-materialization commit failure rolls it back and the same
+        commands re-admit on the next pump."""
         defs: dict[str, int] = {}
+        note_kind = self.accounting.note_kind
         for adm in pg.admitted:
             pid = adm.inst.info.exe.process_id
             defs[pid] = defs.get(pid, 0) + 1
+            note_kind("kernel", adm.cmd.record)
         for pid, n in defs.items():
             self.accounting.note_kernel(pid, n)
         # clean-group evidence for the health ladder: a committed group

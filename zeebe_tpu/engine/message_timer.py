@@ -15,11 +15,14 @@ CORRELATE back; completion acks with MESSAGE_SUBSCRIPTION CORRELATE.
 
 from __future__ import annotations
 
+from time import perf_counter
 from typing import Callable
 
 from zeebe_tpu.engine.engine_state import EI_ACTIVATED, EngineState
 from zeebe_tpu.engine.writers import Writers
 from zeebe_tpu.logstreams import LoggedRecord
+from zeebe_tpu.observability.profiler import phase_annotation
+from zeebe_tpu.observability.tracer import get_tracer
 from zeebe_tpu.parallel.partitioning import (
     InterPartitionCommandSender,
     subscription_partition_id,
@@ -37,6 +40,7 @@ from zeebe_tpu.protocol.intent import (
     ProcessMessageSubscriptionIntent,
     TimerIntent,
 )
+from zeebe_tpu.stream.catch_wait import CORRELATIONS
 
 #: max message keys per MESSAGE_BATCH EXPIRE command — bounds the record size
 #: like the reference's batch-size cap (MessageBatchExpireProcessor)
@@ -192,7 +196,7 @@ class MessageProcessors:
             pi_key = sub.get("processInstanceKey", -1)
             if self.state.messages.was_correlated_to(key, pi_key):
                 continue
-            self._correlate(key, published_value, sub_key, sub, writers)
+            self._correlate(key, published_value, sub_key, sub, writers, cmd)
 
         # message start events (tenant-matched)
         for start_sub in self.state.message_start_subscriptions.find(name):
@@ -216,9 +220,10 @@ class MessageProcessors:
             )
 
     def _correlate(self, message_key: int, message: dict, sub_key: int, sub: dict,
-                   writers: Writers) -> None:
+                   writers: Writers, cause: LoggedRecord) -> None:
         _correlate_to_subscription(
-            self.state, self.sender, message_key, message, sub_key, sub, writers
+            self.state, self.sender, message_key, message, sub_key, sub, writers,
+            cause,
         )
 
     def expire(self, cmd: LoggedRecord, writers: Writers) -> None:
@@ -227,6 +232,8 @@ class MessageProcessors:
         if msg is None:
             return
         writers.append_event(key, ValueType.MESSAGE, MessageIntent.EXPIRED, msg)
+        if CORRELATIONS:
+            writers.after_commit(lambda: CORRELATIONS.drop_messages((key,)))
 
     def expire_batch(self, cmd: LoggedRecord, writers: Writers) -> None:
         """MESSAGE_BATCH EXPIRE: one EXPIRED event removes every named
@@ -240,14 +247,17 @@ class MessageProcessors:
             self.state.next_key(), ValueType.MESSAGE_BATCH,
             MessageBatchIntent.EXPIRED, {"messageKeys": still},
         )
+        if CORRELATIONS:
+            writers.after_commit(lambda: CORRELATIONS.drop_messages(still))
 
 
 def _correlate_to_subscription(
     state: EngineState, sender, message_key: int, message: dict,
-    sub_key: int, sub: dict, writers: Writers,
+    sub_key: int, sub: dict, writers: Writers, cause: LoggedRecord,
 ) -> None:
     """Message-partition correlation: CORRELATING event + ship the CORRELATE
-    command to the subscription's process partition."""
+    command to the subscription's process partition (``cause``: the command
+    being processed, a publish or a subscription's create)."""
     writers.append_event(
         sub_key, ValueType.MESSAGE_SUBSCRIPTION, MessageSubscriptionIntent.CORRELATING,
         {**sub, "messageKey": message_key, "variables": message.get("variables", {})},
@@ -268,7 +278,31 @@ def _correlate_to_subscription(
         },
         key=sub["elementInstanceKey"],
     )
-    writers.after_commit(lambda: sender.send_command(receiver, correlate_cmd))
+    writers.after_commit(lambda: _send_correlation(
+        state.partition_id, sender, receiver, correlate_cmd, cause))
+
+
+def _send_correlation(partition_id: int, sender, receiver: int,
+                      correlate_cmd: Record, cause: LoggedRecord) -> None:
+    """Post-commit: the correlation leaves for the instance's partition,
+    stamped first (``stream/catch_wait.py``) and, with the tracer on, spanned
+    in the trace of the command that correlated it."""
+    value = correlate_cmd.value
+    CORRELATIONS.sent(value["elementInstanceKey"], value["messageKey"])
+    t0 = perf_counter()
+    with phase_annotation("correlate_send"):
+        sender.send_command(receiver, correlate_cmd)
+    tracer = get_tracer()
+    if tracer.enabled:
+        trace_id = (f"{partition_id}:"
+                    f"{tracer.resolve_root(partition_id, cause.position, cause.position)}")
+        if tracer.sampled(trace_id):
+            tracer.emit(trace_id, "message.correlate_send", perf_counter() - t0,
+                        partition_id,
+                        attrs={"partition": partition_id,
+                               "receiverPartition": receiver,
+                               "processInstanceKey": value["processInstanceKey"],
+                               "messageName": value["messageName"]})
 
 
 class MessageSubscriptionProcessors:
@@ -298,7 +332,8 @@ class MessageSubscriptionProcessors:
             if message.get("tenantId", DEFAULT_TENANT) != tenant:
                 continue
             _correlate_to_subscription(
-                self.state, self.sender, message_key, message, sub_key, value, writers
+                self.state, self.sender, message_key, message, sub_key, value, writers,
+                cmd,
             )
             break
 
@@ -318,6 +353,9 @@ class MessageSubscriptionProcessors:
         if sub is None:
             return
         writers.append_event(key, ValueType.MESSAGE_SUBSCRIPTION, MessageSubscriptionIntent.DELETED, sub)
+        if CORRELATIONS:
+            element_key = sub.get("elementInstanceKey", -1)
+            writers.after_commit(lambda: CORRELATIONS.drop_element(element_key))
 
 
 class ProcessMessageSubscriptionProcessors:
@@ -404,12 +442,15 @@ class DueDateCheckers:
     O(due) scans — state stays the single source of truth."""
 
     def __init__(self, engine_state: EngineState, schedule_service,
-                 clock_millis) -> None:
+                 clock_millis, stamps) -> None:
         from zeebe_tpu.engine.timer_wheel import DueDateWheel
 
         self.state = engine_state
         self.schedule = schedule_service
         self.clock_millis = clock_millis
+        # the processor's ``catch_stamps`` (stream/catch_wait.py): each timer
+        # the sweep triggers, with its due date, for ``timer_lag``
+        self.stamps = stamps
         self._handle = None
         self._scheduled_due: int | None = None
         self.wheel = DueDateWheel(clock_millis,
@@ -453,6 +494,20 @@ class DueDateCheckers:
             self._handle = self.schedule.run_at(due, self._sweep)
 
     def _sweep(self) -> list[Record]:
+        t0 = perf_counter()
+        with phase_annotation("due_sweep"):
+            now, timers, expired, commands = self._sweep_due()
+        tracer = get_tracer()
+        if tracer.enabled:
+            pid = self.state.partition_id
+            trace_id = f"{pid}:sweep:{now}"
+            if tracer.sampled(trace_id):
+                tracer.emit(trace_id, "duedate.sweep", perf_counter() - t0, pid,
+                            attrs={"partition": pid, "timersTriggered": timers,
+                                   "messagesExpired": expired})
+        return commands
+
+    def _sweep_due(self) -> tuple[int, int, int, list[Record]]:
         now = self.clock_millis()
         # the wheel entries this sweep covers are spent: drop them and
         # cascade entered coarse buckets (stale/canceled entries die here
@@ -461,10 +516,12 @@ class DueDateCheckers:
         self._scheduled_due = None
         commands: list[Record] = []
         with self.state.db.transaction():
-            for timer_key, _timer in self.state.timers.due_timers(now):
+            for timer_key, timer in self.state.timers.due_timers(now):
                 commands.append(
                     command(ValueType.TIMER, TimerIntent.TRIGGER, {}, key=timer_key)
                 )
+                self.stamps.swept(timer_key, timer["dueDate"])
+            timers = len(commands)
             # batched expiry: ONE MESSAGE_BATCH command expires the whole due
             # backlog (chunked to bound record size) — per-message EXPIRE is
             # exactly the per-record overhead this framework exists to kill
@@ -486,4 +543,4 @@ class DueDateCheckers:
                     command(ValueType.JOB, JobIntent.RECUR_AFTER_BACKOFF,
                             {"recurAt": until}, key=job_key)
                 )
-        return commands
+        return now, timers, len(expired_keys), commands

@@ -53,6 +53,7 @@ from zeebe_tpu.stream.api import (
     RecordProcessor,
     job_moves,
 )
+from zeebe_tpu.stream.catch_wait import CatchStamps
 from zeebe_tpu.stream.job_wait import JobWaitStamps
 
 from zeebe_tpu.protocol.intent import ProcessInstanceIntent as _PI
@@ -124,6 +125,9 @@ class StreamProcessor:
         # how long a job waits for its worker (stream/job_wait.py): stamped
         # and observed with the post-commit effects below
         self.job_stamps = JobWaitStamps(str(log_stream.partition_id))
+        # how long a catch waited (stream/catch_wait.py): the due dates this
+        # partition's sweep triggered, observed at the end of processing
+        self.catch_stamps = CatchStamps(str(log_stream.partition_id))
         self.phase = Phase.INITIAL
         self._positions = db.column_family(ColumnFamilyCode.LAST_PROCESSED_POSITION)
         # replicated request dedupe (ISSUE 9): materialized here on BOTH the
@@ -360,6 +364,7 @@ class StreamProcessor:
         # a command's reply is released, only while tracing is enabled
         self.on_ack: Callable[[str, float], None] | None = None
         clock = clock_millis or log_stream.clock_millis
+        self._clock_millis = clock
         self.schedule_service = ProcessingScheduleService(clock, self._write_scheduled_commands)
         self._reader_position = 1
         self._scan_hint = -1  # batch-slot cursor for the sequential scans
@@ -815,6 +820,8 @@ class StreamProcessor:
             self._m_device_uploads.observe(pending.uploads)
         pipeline["materialize"].observe(pending.t_materialize)
         self._observe_admission(pending, cmds)
+        self.catch_stamps.processed(cmds, self.log_stream.readable_at,
+                                    self._clock_millis, kernel=True)
         self._m_batched.inc(len(cmds))
         elapsed = _time.perf_counter() - group_start
         self._m_latency.observe(elapsed)
@@ -1272,6 +1279,10 @@ class StreamProcessor:
         else:
             self._emit_group_effects((builder,))
         self._observe_follow_ups(builder.follow_ups)
+        self.catch_stamps.processed((cmd,), self.log_stream.readable_at,
+                                    self._clock_millis, kernel=False)
+        if self.kernel_backend is not None:
+            self.kernel_backend.accounting.note_kind("host", cmd.record)
         self._m_processed.inc()
         elapsed = _time.perf_counter() - start
         if traced:

@@ -113,7 +113,8 @@ class EngineHarness:
                 lambda rec: self.stream.writer.try_write([LogAppendEntry(rec)])
             )
         self.engine.wire_sender(sender)
-        self.checkers = DueDateCheckers(self.engine.state, self.processor.schedule_service, self.clock)
+        self.checkers = DueDateCheckers(self.engine.state, self.processor.schedule_service,
+                                        self.clock, self.processor.catch_stamps)
         self.redistributor = CommandRedistributor(
             self.engine.state, self.engine.sender, self.processor.schedule_service, self.clock
         )
