@@ -274,6 +274,105 @@ class TestJobWorker:
             del client.activate_jobs
 
 
+def parked(runtime, job_type: str) -> int:
+    return sum(map(len, runtime.jobs_hub._parked.get(job_type, {}).values()))
+
+
+def wait_until(predicate, timeout_s: float = 10.0) -> bool:
+    deadline = time.monotonic() + timeout_s
+    while time.monotonic() < deadline:
+        if predicate():
+            return True
+        time.sleep(0.01)
+    return predicate()
+
+
+class TestLongPollingWorker:
+    def test_worker_sends_its_request_timeout(self, stack):
+        client, _ = stack
+        sent, activate = [], client.activate_jobs
+
+        def recording(job_type, **kw):
+            sent.append(kw["request_timeout_ms"])
+            return activate(job_type, **kw)
+
+        client.activate_jobs = recording
+        worker = JobWorker(client, "wt_nothing", lambda job: {})
+        try:
+            worker.start()
+            assert wait_until(lambda: sent)
+        finally:
+            worker.stop()
+            del client.activate_jobs
+        assert set(sent) == {JobWorker.REQUEST_TIMEOUT_MS} == {10_000}
+
+    def test_a_job_made_after_the_poll_parked_needs_no_second_poll(self, stack):
+        client, runtime = stack
+        client.deploy_resource(("wl.bpmn", one_task("wl", "wl_work")))
+        answers, activate = [], client.activate_jobs
+
+        def counting(job_type, **kw):
+            jobs = activate(job_type, **kw)
+            answers.append(len(jobs))
+            return jobs
+
+        client.activate_jobs = counting
+        worker = JobWorker(client, "wl_work", lambda job: {})
+        try:
+            worker.start()
+            assert wait_until(lambda: parked(runtime, "wl_work") == 1)
+            client.create_instance("wl")
+            assert wait_until(lambda: worker.handled_count == 1)
+        finally:
+            worker.stop()
+            del client.activate_jobs
+        # the job came back on the worker's first poll, the one that parked
+        assert answers[0] == 1
+
+    def test_stop_cancels_a_parked_poll(self, stack):
+        client, runtime = stack
+        client.deploy_resource(("ws.bpmn", one_task("ws", "ws_work")))
+        worker = JobWorker(client, "ws_work", lambda job: {}).start()
+        assert wait_until(lambda: parked(runtime, "ws_work") == 1)
+        started = time.monotonic()
+        worker.stop()
+        assert time.monotonic() - started < 1.0
+        # the gateway took the cancelled poll out of the queue: a job made
+        # now is activated by nobody
+        assert wait_until(lambda: parked(runtime, "ws_work") == 0, 1.0)
+        client.create_instance("ws")
+        [job] = client.activate_jobs("ws_work", request_timeout_ms=5_000)
+        assert worker.handled_count == 0
+        client.complete_job(job.key, {})
+
+    def test_only_a_refused_or_failed_poll_backs_off(self, stack):
+        client, _ = stack
+        calls = []
+
+        def answering(job_type, **kw):
+            calls.append(time.monotonic())
+            if len(calls) <= 3:
+                raise RuntimeError("refused")
+            if len(calls) >= 9:
+                time.sleep(0.05)
+            return []       # empty at its request timeout
+
+        client.activate_jobs = answering
+        worker = JobWorker(client, "wb_nothing", lambda job: {},
+                           poll_interval_s=0.1, max_backoff_s=0.4)
+        try:
+            worker.start()
+            assert wait_until(lambda: len(calls) >= 9)
+        finally:
+            worker.stop()
+            del client.activate_jobs
+        gaps = [b - a for a, b in zip(calls, calls[1:])]
+        # after each refusal: 0.1, 0.2, 0.4 s; after an empty answer: at once,
+        # five polls in less time than one backoff
+        assert all(g >= 0.95 * b for g, b in zip(gaps[:3], (0.1, 0.2, 0.4))), gaps
+        assert sum(gaps[3:8]) < 0.1, gaps
+
+
 class TestEvaluateDecision:
     def test_evaluate_decision_rpc(self, stack):
         import json as _json

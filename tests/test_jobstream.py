@@ -16,7 +16,7 @@ from zeebe_tpu.gateway import ClusterRuntime, Gateway
 from zeebe_tpu.gateway.jobstream import JobNotificationHub
 from zeebe_tpu.client import JobWorker, ZeebeTpuClient
 from zeebe_tpu.models.bpmn import Bpmn, to_bpmn_xml
-from zeebe_tpu.protocol import ValueType, command
+from zeebe_tpu.protocol import DEFAULT_TENANT, ValueType, command
 from zeebe_tpu.protocol.intent import JobIntent
 from zeebe_tpu.testing import EngineHarness
 
@@ -108,28 +108,31 @@ class TestNotificationHub:
         woke = []
 
         def waiter():
-            woke.append(hub.wait("t", seen, timeout_s=5.0))
+            woke.append(hub.wait(hub.waiter("t", (DEFAULT_TENANT,)), seen,
+                                 timeout_s=5.0))
 
         t = threading.Thread(target=waiter)
         t.start()
         time.sleep(0.05)
-        hub.notify({"t"})
+        hub.notify({"t"}, 2)
         t.join(timeout=2)
-        assert woke == [True]
+        assert woke == [2]      # the partition that notified
 
     def test_wait_times_out_for_other_type(self):
         hub = JobNotificationHub()
         seen = hub.version("t")
-        hub.notify({"other"})
-        assert hub.wait("t", seen, timeout_s=0.05) is False
+        hub.notify({"other"}, 1)
+        assert hub.wait(hub.waiter("t", (DEFAULT_TENANT,)), seen,
+                        timeout_s=0.05) is None
 
     def test_no_missed_wakeup_between_check_and_wait(self):
         # version read before the state check: a notify that lands between
         # check and wait must not be lost
         hub = JobNotificationHub()
         seen = hub.version("t")
-        hub.notify({"t"})  # lands "during the state check"
-        assert hub.wait("t", seen, timeout_s=5.0) is True
+        hub.notify({"t"}, 1)  # lands "during the state check"
+        assert hub.wait(hub.waiter("t", (DEFAULT_TENANT,)), seen,
+                        timeout_s=5.0) == 1
 
 
 # ---------------------------------------------------------------------------

@@ -185,7 +185,7 @@ class TestPeek:
         try:
             rec = rt._m_peek[1] = Recorder()
             for _ in range(3):  # not started: no leader, but the lock is got
-                assert rt.has_activatable_jobs(1, "work") is False
+                assert rt.has_activatable_jobs(1, "work") is None
             assert len(rec.values) == 3
             assert all(0 <= v < 1 for v in rec.values)
         finally:
@@ -200,8 +200,8 @@ class TestPeek:
         try:
             rec = rt._m_peek[1] = Recorder()
             rt._plocks[1] = Stalled()
-            assert rt.has_activatable_jobs(1, "work") is False
-            assert rt.has_activatable_jobs(7, "work") is False  # no partition
+            assert rt.has_activatable_jobs(1, "work") is None
+            assert rt.has_activatable_jobs(7, "work") is None  # no partition
             assert rec.values == []
         finally:
             rt.stop()

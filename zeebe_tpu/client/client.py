@@ -11,13 +11,16 @@ from __future__ import annotations
 
 import json
 from dataclasses import dataclass, field
-from typing import Any, Iterator
+from typing import Any, Callable, Iterator
 
 import grpc
 
 from zeebe_tpu.gateway.proto import gateway_pb2 as pb
 
 _SERVICE = "gateway_protocol.Gateway"
+#: a long-poll's gRPC deadline lies this long past its request timeout, by
+#: which the gateway answers it
+LONG_POLL_DEADLINE_MARGIN_S = 10.0
 
 
 def _method(channel, name, req_cls, resp_cls, streaming=False):
@@ -255,16 +258,31 @@ class ZeebeTpuClient:
     def activate_jobs(self, job_type: str, max_jobs: int = 32,
                       worker: str = "python-client", timeout_ms: int = 300_000,
                       request_timeout_ms: int = 0,
-                      tenant_ids: list[str] | None = None) -> list[ActivatedJob]:
+                      tenant_ids: list[str] | None = None,
+                      on_call: Callable[[grpc.Call], None] | None = None,
+                      ) -> list[ActivatedJob]:
+        """``request_timeout_ms`` > 0 long-polls: the gateway parks the call
+        until a job of the type is there or the timeout passes, and the
+        call's deadline is that much later than the timeout. ``on_call`` is
+        handed the call before it is waited on, so that another thread can
+        cancel it; a cancelled call returns no jobs."""
         if tenant_ids is None and self.default_tenant:
             tenant_ids = [self.default_tenant]
-        jobs: list[ActivatedJob] = []
-        for resp in self._activate(pb.ActivateJobsRequest(
+        call = self._activate(pb.ActivateJobsRequest(
             type=job_type, worker=worker, timeout=timeout_ms,
             maxJobsToActivate=max_jobs, requestTimeout=request_timeout_ms,
             tenantIds=tenant_ids or [],
-        )):
-            jobs.extend(_job_of(j) for j in resp.jobs)
+        ), timeout=(request_timeout_ms / 1000 + LONG_POLL_DEADLINE_MARGIN_S
+                    if request_timeout_ms > 0 else None))
+        if on_call is not None:
+            on_call(call)
+        jobs: list[ActivatedJob] = []
+        try:
+            for resp in call:
+                jobs.extend(_job_of(j) for j in resp.jobs)
+        except grpc.RpcError:
+            if not call.cancelled():    # cancelled here, not by the gateway
+                raise
         return jobs
 
     def stream_jobs(self, job_type: str, worker: str = "python-client",

@@ -40,7 +40,12 @@ class JobClient:
 
 
 class JobWorker:
-    """Background polling worker with exponential empty-poll backoff.
+    """Background long-polling worker.
+
+    Every poll asks the gateway to park it for up to ``REQUEST_TIMEOUT_MS``
+    until a job of the type is there; an answer that comes back empty at that
+    timeout polls again at once. A refused or failed poll backs off,
+    ``poll_interval_s`` doubling up to ``max_backoff_s``.
 
     Jobs are handled on ``HANDLER_THREADS`` threads of the worker's own
     (reference: the Go worker's default ``Concurrency``; the Java client's
@@ -58,6 +63,9 @@ class JobWorker:
     jobs arrive as the broker creates them, no polling)."""
 
     HANDLER_THREADS = 4
+    #: how long a poll may park at the gateway (reference: the Java client's
+    #: default request timeout and the Go worker's, both long-polling)
+    REQUEST_TIMEOUT_MS = 10_000
 
     def __init__(
         self,
@@ -84,6 +92,7 @@ class JobWorker:
         self.stream_enabled = stream_enabled
         self._running = False
         self._thread: threading.Thread | None = None
+        self._call = None       # the poll or stream in flight, to cancel
         self._handlers: list[threading.Thread] = []
         self._jobs: queue.SimpleQueue = queue.SimpleQueue()
         # guards the three counts; notified whenever a job was finished
@@ -106,14 +115,15 @@ class JobWorker:
         return self
 
     def stop(self) -> None:
-        """Jobs a handler has in hand are finished (waited for up to 5 s);
-        jobs still queued are left to their activation timeout."""
-        self._running = False
-        call = getattr(self, "_call", None)
+        """A parked poll or an open stream is cancelled; jobs a handler has
+        in hand are finished (waited for up to 5 s); jobs still queued are
+        left to their activation timeout."""
+        with self._finished:
+            self._running = False
+            call = self._call
+            self._finished.notify_all()
         if call is not None:
             call.cancel()
-        with self._finished:
-            self._finished.notify_all()
         for _ in self._handlers:
             self._jobs.put(None)
         deadline = time.monotonic() + 5
@@ -129,26 +139,27 @@ class JobWorker:
                 if room <= 0:
                     self._finished.wait(self.max_backoff_s)
                     continue
-                finished = self.handled_count + self.failed_count
             try:
                 jobs = self.client.activate_jobs(
                     self.job_type, max_jobs=room,
                     worker=self.worker_name, timeout_ms=self.timeout_ms,
+                    request_timeout_ms=self.REQUEST_TIMEOUT_MS,
+                    on_call=self._track,
                 )
             except Exception:
-                jobs = []
-            if jobs:
-                backoff = self.poll_interval_s
-                self._accept(jobs)
+                with self._finished:
+                    self._finished.wait_for(lambda: not self._running, backoff)
+                backoff = min(backoff * 2, self.max_backoff_s)
                 continue
-            # an empty (or refused) poll backs off, unless a job is finished
-            # meanwhile: the instance's next job is usually there by then
-            with self._finished:
-                woken = self._finished.wait_for(
-                    lambda: not self._running or finished
-                    != self.handled_count + self.failed_count, backoff)
-            backoff = (self.poll_interval_s if woken
-                       else min(backoff * 2, self.max_backoff_s))
+            backoff = self.poll_interval_s
+            self._accept(jobs)
+
+    def _track(self, call) -> None:
+        """The poll's call, before it parks: ``stop`` cancels it."""
+        with self._finished:
+            self._call = call
+            if not self._running:
+                call.cancel()
 
     def _accept(self, jobs) -> None:
         with self._finished:

@@ -121,7 +121,7 @@ class GatewayRuntimeBase:
         self.job_streams = JobStreamDispatcher(self)
 
     def _on_jobs_available(self, partition_id: int, job_types: set) -> None:
-        self.jobs_hub.notify(job_types)
+        self.jobs_hub.notify(job_types, partition_id)
         self.job_streams.on_jobs_available(partition_id, job_types)
 
     def job_pushed(self, job_key: int) -> float | None:
@@ -397,22 +397,24 @@ class ClusterRuntime(GatewayRuntimeBase):
     # -- partition selection ---------------------------------------------------
 
     def has_activatable_jobs(self, partition_id: int, job_type: str,
-                             tenant_ids: list[str] | None = None) -> bool:
+                             tenant_ids: list[str] | None = None
+                             ) -> bool | None:
         """Long-poll peek: checks the leader's state without writing a
         JOB_BATCH ACTIVATE into the replicated log (reference:
         LongPollingActivateJobsHandler parks requests until jobsAvailable).
         ``tenant_ids`` keeps a tenant-filtered long-poll from flooding the log
-        with empty activations when only other tenants' jobs exist."""
+        with empty activations when only other tenants' jobs exist. None
+        where it cannot tell: an unknown partition, one without a leader, or
+        one whose ownership thread is stalled."""
         entered = time.perf_counter()
         lock = self._plocks.get(partition_id)
         if lock is None or not lock.acquire(timeout=1.0):
-            # unknown partition, or its ownership thread is stalled: report
-            # "no jobs" — long-polls and the push dispatcher both retry
-            return False
+            # long-polls and the push dispatcher both retry
+            return None
         try:
             leader = self._leader_partition(partition_id)
             if leader is None or leader.db is None:
-                return False
+                return None
             # committed-read discipline: long-poll peeks run off the pump
             # thread — read the committed activatable index, never the
             # processing-owned transaction slot (zlint caught the old

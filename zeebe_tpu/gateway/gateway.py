@@ -15,6 +15,7 @@ from __future__ import annotations
 import json
 import time
 from concurrent import futures
+from time import perf_counter
 from typing import Any, Callable
 
 import grpc
@@ -46,6 +47,12 @@ VERSION = "8.4.0-tpu"
 #: open ``StreamActivatedJobs`` calls a gateway serves at once, one thread
 #: each; a stream beyond them waits for one to close
 MAX_OPEN_JOB_STREAMS = 4096
+#: ``ActivateJobs`` calls a gateway serves at once, one thread each, parked or
+#: not; a call beyond them waits for one to end
+MAX_PARKED_POLLS = 4096
+#: a long-poll handed a notification of a partition it could not peek (no
+#: leader, or its lock stalled) looks again this often until its timeout
+HELD_WAKE_RETRY_S = 0.1
 
 
 from zeebe_tpu.utils.metrics import REGISTRY as _REG  # noqa: E402
@@ -53,6 +60,20 @@ from zeebe_tpu.utils.metrics import REGISTRY as _REG  # noqa: E402
 _M_LONG_POLL_QUEUED = _REG.gauge(
     "long_polling_queued_current",
     "ActivateJobs requests parked waiting for jobs").labels()
+#: a long-poll's time in the hub's queue and its empty wake-ups: always on,
+#: named into the partition pipeline's family beside the peek they save
+_M_POLL_PARK = _REG.histogram(
+    "stream_processor_pipeline_poll_park",
+    "seconds per parked ActivateJobs from its parking to the notification "
+    "that woke it (partition: the one that notified) or to its request "
+    "timeout (partition: none); a call that ended while parked, and the "
+    "short wait of a poll that looks again at a partition it could not peek, "
+    "are not observed", ("partition",))
+_M_POLL_WAKE_EMPTY = _REG.histogram(
+    "stream_processor_pipeline_poll_wake_empty",
+    "seconds per woken ActivateJobs that activated nothing, from its wake-up "
+    "to the end of its fan-out (partition: the one that notified)",
+    ("partition",))
 _M_TOPOLOGY_ROLES = _REG.gauge(
     "gateway_topology_partition_roles",
     "known partition roles (3=leader 1=follower)", ("node", "partition"))
@@ -74,6 +95,9 @@ class GatewayService:
                  auth: TenantAuthorizer | None = None) -> None:
         self.runtime = runtime
         self.auth = auth or TenantAuthorizer()
+        # a gateway whose polls park reads zero empty wake-ups, not none
+        for partition_id in range(1, runtime.partition_count + 1):
+            _M_POLL_WAKE_EMPTY.labels(str(partition_id))
 
     # -- tenant authorization (IdentityInterceptor equivalent) -----------------
 
@@ -281,69 +305,118 @@ class GatewayService:
 
     def ActivateJobs(self, request, context):
         """Fan out across partitions round-robin until maxJobs or all empty;
-        park until requestTimeout if nothing was activated, woken by the
-        jobs-available notification (reference:
-        LongPollingActivateJobsHandler.java:36 — no poll loop)."""
-        deadline = time.time() + max((request.requestTimeout or 0), 0) / 1000
+        with a ``requestTimeout``, park until it passes if nothing was
+        activated, in the queue of the job type and tenant filter at the hub
+        (reference: LongPollingActivateJobsHandler.java:36 — no poll loop).
+        A jobs-available notification wakes the first parked poll of each
+        filter alone, which starts its fan-out at the partition that notified
+        and ends it at the first partition that activated jobs: the other
+        partitions' jobs come with notifications of their own. A woken poll
+        whose room that partition filled hands the notification on, since the
+        partition may hold more; one that activated nothing parks again at
+        the head of the queue. A notification whose partition could not be
+        peeked (no leader, or its lock stalled) stays with the poll, which
+        looks again after ``HELD_WAKE_RETRY_S``; the call's end hands on
+        every notification it still holds."""
+        deadline = time.monotonic() + max(request.requestTimeout or 0, 0) / 1000
         remaining = request.maxJobsToActivate or 32
         tenant_filter = self._tenant_ids_field(context, request.tenantIds)
-        hub = getattr(self.runtime, "jobs_hub", None)
-        while context.is_active():
-            seen_version = hub.version(request.type) if hub is not None else 0
-            jobs = []
-            for partition_id in range(1, self.runtime.partition_count + 1):
-                if remaining <= 0 or not context.is_active():
-                    break
-                # peek before writing: an idle long-poller must not flood the
-                # replicated log with empty JOB_BATCH ACTIVATE commands —
-                # including when only OTHER tenants' jobs woke the hub. The
-                # peek must mirror the engine's filter default ([default
-                # tenant] when the field is omitted), or residual tenant jobs
-                # would make every wakeup write an empty activation.
-                if not self.runtime.has_activatable_jobs(
-                        partition_id, request.type,
-                        tenant_filter.get("tenantIds", [DEFAULT_TENANT])):
-                    continue
-                activate = command(ValueType.JOB_BATCH, JobBatchIntent.ACTIVATE, {
-                    "type": request.type,
-                    "worker": request.worker or "default",
-                    "timeout": request.timeout or 300_000,
-                    "maxJobsToActivate": remaining,
-                    **tenant_filter,
-                })
+        # the peek must mirror the engine's filter default ([default tenant]
+        # when the field is omitted), or residual tenant jobs would make every
+        # wakeup write an empty activation
+        tenants = tuple(sorted(tenant_filter.get("tenantIds", [DEFAULT_TENANT])))
+        hub = self.runtime.jobs_hub
+        partitions = range(1, self.runtime.partition_count + 1)
+        waiter = None
+        woken = None    # the partition whose notification woke this round
+        held: list[int] = []    # partitions notified to this poll, not acted on
+        try:
+            while context.is_active():
+                seen_version = hub.version(request.type)
+                woke_at = perf_counter()
+                handed = bool(held)
+                jobs = []
+                for partition_id in (*held, *(p for p in partitions
+                                               if p not in held)):
+                    if remaining <= 0 or not context.is_active():
+                        break
+                    # peek before writing: an idle long-poller must not flood
+                    # the replicated log with empty JOB_BATCH ACTIVATE
+                    # commands, including when only OTHER tenants' jobs woke
+                    # the hub; None: the partition could not be peeked
+                    peek = self.runtime.has_activatable_jobs(
+                        partition_id, request.type, list(tenants))
+                    if not peek:
+                        if peek is False and partition_id in held:
+                            held.remove(partition_id)
+                        continue
+                    activate = command(ValueType.JOB_BATCH, JobBatchIntent.ACTIVATE, {
+                        "type": request.type,
+                        "worker": request.worker or "default",
+                        "timeout": request.timeout or 300_000,
+                        "maxJobsToActivate": remaining,
+                        **tenant_filter,
+                    })
+                    if jobs:
+                        # jobs an earlier partition already activated must
+                        # reach the worker: a later partition that sheds,
+                        # times out or has no leader ends the fan-out —
+                        # aborting the call would strand them, activated,
+                        # until their job timeout
+                        try:
+                            record = self.runtime.submit(partition_id, activate)
+                        except (NoLeaderError, ResourceExhaustedError,
+                                RequestTimeoutError):
+                            break
+                        if record.is_rejection:
+                            break
+                    else:
+                        record = self._submit(context, partition_id, activate)
+                    if partition_id in held:
+                        held.remove(partition_id)
+                    for key, job in zip(record.value.get("jobKeys", []),
+                                        record.value.get("jobs", [])):
+                        jobs.append(self._activated_job(request, key, job))
+                        remaining -= 1
+                    if jobs and handed:
+                        if remaining <= 0:
+                            hub.hand_on(request.type, tenants, partition_id)
+                        break
                 if jobs:
-                    # jobs an earlier partition already activated must reach
-                    # the worker: a later partition that sheds, times out or
-                    # has no leader ends the fan-out — aborting the call would
-                    # strand them, activated, until their job timeout
-                    try:
-                        record = self.runtime.submit(partition_id, activate)
-                    except (NoLeaderError, ResourceExhaustedError,
-                            RequestTimeoutError):
-                        break
-                    if record.is_rejection:
-                        break
-                else:
-                    record = self._submit(context, partition_id, activate)
-                for key, job in zip(record.value.get("jobKeys", []),
-                                    record.value.get("jobs", [])):
-                    jobs.append(self._activated_job(request, key, job))
-                    remaining -= 1
-            if jobs:
-                yield pb.ActivateJobsResponse(jobs=jobs)
-                return
-            now = time.time()
-            if now >= deadline:
-                return
-            _M_LONG_POLL_QUEUED.inc()
-            try:
-                if hub is not None:
-                    # bounded wait so client cancellation is noticed promptly
-                    hub.wait(request.type, seen_version, min(deadline - now, 1.0))
-                else:
-                    time.sleep(0.02)
-            finally:
-                _M_LONG_POLL_QUEUED.dec()
+                    for partition_id in held:
+                        hub.hand_on(request.type, tenants, partition_id)
+                    held.clear()
+                    yield pb.ActivateJobsResponse(jobs=jobs)
+                    return
+                if woken is not None:
+                    _M_POLL_WAKE_EMPTY.labels(str(woken)).observe(
+                        perf_counter() - woke_at)
+                now = time.monotonic()
+                if now >= deadline or not context.is_active():
+                    return
+                if waiter is None:
+                    # the call's end takes the poll out of the queue at once
+                    waiter = hub.waiter(request.type, tenants)
+                    if not context.add_callback(waiter.cancel):
+                        return
+                retry = bool(held)
+                parked_at = perf_counter()
+                _M_LONG_POLL_QUEUED.inc()
+                try:
+                    woken = hub.wait(
+                        waiter, seen_version,
+                        min(deadline - now, HELD_WAKE_RETRY_S) if retry
+                        else deadline - now, front=handed)
+                finally:
+                    _M_LONG_POLL_QUEUED.dec()
+                if woken is not None and woken not in held:
+                    held.append(woken)
+                if not waiter.cancelled and (woken is not None or not retry):
+                    _M_POLL_PARK.labels("none" if woken is None else str(woken)
+                                        ).observe(perf_counter() - parked_at)
+        finally:
+            for partition_id in held:
+                hub.hand_on(request.type, tenants, partition_id)
 
     def StreamActivatedJobs(self, request, context):
         """Job push: register a client stream with the dispatcher; the broker
@@ -668,18 +741,24 @@ class Gateway:
                 request_deserializer=req_cls.FromString,
                 response_serializer=resp_cls.SerializeToString,
             )
-        # an open job stream lives as long as its worker: its handler runs on
-        # a pool of its own (threads made as streams open), so that streams,
-        # however many, take no handler from the unary RPCs' ``max_workers``
-        self._stream_pool = futures.ThreadPoolExecutor(
-            max_workers=MAX_OPEN_JOB_STREAMS,
-            thread_name_prefix="gateway-job-stream")
+        # an open job stream lives as long as its worker, and a long-poll as
+        # long as its request timeout: each kind's handlers run on a pool of
+        # its own (threads made as calls open), so that streams and parked
+        # polls, however many, take no handler from the unary RPCs'
+        # ``max_workers``
+        self._pools = {
+            "StreamActivatedJobs": futures.ThreadPoolExecutor(
+                max_workers=MAX_OPEN_JOB_STREAMS,
+                thread_name_prefix="gateway-job-stream"),
+            "ActivateJobs": futures.ThreadPoolExecutor(
+                max_workers=MAX_PARKED_POLLS,
+                thread_name_prefix="gateway-long-poll"),
+        }
         for name, (req_cls, resp_cls) in _SERVER_STREAMING.items():
             behavior = _wrap(getattr(self.service, name))
-            if name == "StreamActivatedJobs":
-                # grpc's own door for a handler that must not share the
-                # server's pool (grpc._server._select_thread_pool_for_behavior)
-                behavior.experimental_thread_pool = self._stream_pool
+            # grpc's own door for a handler that must not share the server's
+            # pool (grpc._server._select_thread_pool_for_behavior)
+            behavior.experimental_thread_pool = self._pools[name]
             handlers[name] = grpc.unary_stream_rpc_method_handler(
                 behavior,
                 request_deserializer=req_cls.FromString,
@@ -712,8 +791,10 @@ class Gateway:
 
     def stop(self, grace: float = 1.0) -> None:
         self.server.stop(grace)
-        # the stop ended every open stream's call; their threads go with them
-        self._stream_pool.shutdown(wait=False)
+        # the stop ended every open stream's and parked poll's call; their
+        # threads go with them
+        for pool in self._pools.values():
+            pool.shutdown(wait=False)
 
 
 def _wrap(method: Callable) -> Callable:

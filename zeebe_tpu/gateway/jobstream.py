@@ -9,7 +9,8 @@ woken by a "jobsAvailable" notification instead of polling).
 
 Design (tpu-native runtime): processing emits a post-commit jobs-available
 side effect (stream/processor.py on_jobs_available) that lands here. The
-``JobNotificationHub`` wakes parked ActivateJobs long-polls; the
+``JobNotificationHub`` wakes one parked ActivateJobs long-poll a
+notification and tenant filter, first parked first woken; the
 ``JobStreamDispatcher`` owns the registered client streams and, on
 notification, writes a JOB_BATCH ACTIVATE through the normal command path and
 delivers the activated jobs to a registered stream — so the record log is
@@ -31,6 +32,7 @@ span ``jobstream.push`` at its real interval."""
 
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import queue
@@ -46,35 +48,143 @@ logger = logging.getLogger("zeebe_tpu.gateway.jobstream")
 PUSH_BATCH_SIZE = 32
 
 
+class PollWaiter:
+    """One long-poll's place in the queue of its job type and tenant filter,
+    for the whole call: ``cancel`` (the call's end) takes it out of the queue
+    at once, so that no notification is handed to a client that went away."""
+
+    __slots__ = ("_hub", "job_type", "tenants", "_event", "_wake", "_queued",
+                 "cancelled")
+
+    def __init__(self, hub: "JobNotificationHub", job_type: str,
+                 tenants: tuple) -> None:
+        self._hub = hub
+        self.job_type = job_type
+        self.tenants = tenants
+        self._event = threading.Event()
+        self._wake: int | None = None   # the partition handed, not yet taken
+        self._queued = False
+        self.cancelled = False
+
+    def cancel(self) -> None:
+        self._hub._cancel(self)
+
+
 class JobNotificationHub:
-    """Versioned per-job-type wakeup: long-polls snapshot a version, check
-    state, then wait for the version to move (no sleep-poll)."""
+    """Per job type and tenant filter, the parked long-polls in arrival
+    order. A jobs-available notification names a job type and a partition,
+    not a tenant: it wakes the first poll of each tenant filter parked on the
+    type (one poll where every poll of the type asks for the same tenants)
+    and tells it which partition notified (reference:
+    LongPollingActivateJobsHandler hands the notification to the type's next
+    pending request). A notification is also kept, one a partition, for a
+    filter that had no poll parked: a poll reads the type's version before it
+    peeks and, when it parks, takes a kept notification that is newer than
+    its peek and was handed to no poll of its filter, instead of sleeping."""
 
     def __init__(self) -> None:
-        self._cond = threading.Condition()
+        self._lock = threading.Lock()
         self._versions: dict[str, int] = {}
+        # job type -> tenant filter -> parked polls, first parked first
+        self._parked: dict[str, dict[tuple, collections.deque[PollWaiter]]] = {}
+        # job type -> (partition, the filter it is for; None: every filter)
+        # -> (the version it made, the filters it was handed to)
+        self._kept: dict[str, dict[tuple, tuple[int, set]]] = {}
 
-    def notify(self, job_types: set) -> None:
-        with self._cond:
+    def notify(self, job_types: set, partition_id: int) -> None:
+        """``partition_id``: the partition whose step made the jobs
+        activatable."""
+        with self._lock:
             for job_type in job_types:
-                self._versions[job_type] = self._versions.get(job_type, 0) + 1
-            self._cond.notify_all()
+                self._hand(job_type, partition_id, None)
+
+    def hand_on(self, job_type: str, tenants: tuple, partition_id: int) -> None:
+        """A poll handed a notification that leaves the partition's jobs to
+        the next poll of its filter (its room is full, or its call ended)
+        passes the notification on."""
+        with self._lock:
+            self._hand(job_type, partition_id, tenants)
+
+    def _hand(self, job_type: str, partition_id: int,
+              tenants: tuple | None) -> None:
+        version = self._versions[job_type] = self._versions.get(job_type, 0) + 1
+        queues = self._parked.get(job_type, {})
+        handed = set()
+        for filt in (tuple(queues) if tenants is None else (tenants,)):
+            if filt in queues:
+                waiter = self._unqueue(queues, filt)
+                waiter._wake = partition_id
+                waiter._event.set()
+                handed.add(filt)
+        self._kept.setdefault(job_type, {})[partition_id, tenants] = (
+            version, handed)
 
     def version(self, job_type: str) -> int:
-        with self._cond:
+        with self._lock:
             return self._versions.get(job_type, 0)
 
-    def wait(self, job_type: str, seen_version: int, timeout_s: float) -> bool:
-        """Block until jobs of the type were made available after
-        ``seen_version`` was read, or the timeout passes."""
-        deadline = time.monotonic() + timeout_s
-        with self._cond:
-            while self._versions.get(job_type, 0) == seen_version:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    return False
-                self._cond.wait(remaining)
-            return True
+    def waiter(self, job_type: str, tenants: tuple) -> PollWaiter:
+        return PollWaiter(self, job_type, tenants)
+
+    def wait(self, waiter: PollWaiter, seen_version: int, timeout_s: float,
+             front: bool = False) -> int | None:
+        """Park until a notification of the waiter's type is handed to it,
+        and return the partition that notified; None when the
+        timeout passes or the waiter is cancelled first. ``seen_version``:
+        the type's version read before the poll's peek, which saw every
+        notification up to it. ``front``: a poll that was handed a
+        notification and found nothing goes back to the head of the queue."""
+        job_type, tenants = waiter.job_type, waiter.tenants
+        with self._lock:
+            if waiter.cancelled:
+                return None
+            kept = sorted(self._kept.get(job_type, {}).items(),
+                          key=lambda item: item[1][0])
+            for (partition_id, filt), (version, handed) in kept:
+                if (version > seen_version and filt in (None, tenants)
+                        and tenants not in handed):
+                    handed.add(tenants)
+                    return partition_id
+            queues = self._parked.setdefault(job_type, {})
+            parked = queues.setdefault(tenants, collections.deque())
+            if front:
+                parked.appendleft(waiter)
+            else:
+                parked.append(waiter)
+            waiter._queued = True
+        waiter._event.wait(timeout_s)
+        with self._lock:
+            waiter._event.clear()
+            partition_id, waiter._wake = waiter._wake, None
+            if waiter._queued:      # timed out (or cancelled) while parked
+                self._unqueue(self._parked[job_type], tenants, waiter)
+            return partition_id
+
+    def _cancel(self, waiter: PollWaiter) -> None:
+        with self._lock:
+            waiter.cancelled = True
+            if waiter._queued:
+                self._unqueue(self._parked[waiter.job_type], waiter.tenants,
+                              waiter)
+            if waiter._wake is not None:
+                # handed a notification it will not act on: the next one has it
+                self._hand(waiter.job_type, waiter._wake, waiter.tenants)
+                waiter._wake = None
+            waiter._event.set()
+
+    @staticmethod
+    def _unqueue(queues: dict, tenants: tuple,
+                 waiter: PollWaiter | None = None) -> PollWaiter:
+        """Take ``waiter`` (None: the first) out of its filter's queue."""
+        parked = queues[tenants]
+        if waiter is None:
+            waiter = parked.popleft()
+        else:
+            parked.remove(waiter)
+        if not parked:
+            del queues[tenants]
+        waiter._queued = False
+        return waiter
 
 
 @dataclass
