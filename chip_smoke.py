@@ -21,8 +21,9 @@ public gRPC client (``ZeebeTpuClient``, ``JobWorker``):
 ``--chips 4`` replaces phases 2-3 by the path a four-chip host takes by default
 and what it is compared with: (A) four partitions' logs through a
 ``MeshKernelRunner`` over the four chips, byte-compared with the direct
-one-device path under a fixed clock; (B) 1 broker, 4 partitions, the broker's
-own mesh runner, 10,000 one_task instances through the gRPC client.
+one-device path under a fixed clock; (B) 1 broker, 4 partitions, the mesh
+runner the runtime builds by default, 10,000 one_task instances through the
+gRPC client; in both every mesh group shadow-verified by the host oracle.
 
 The last line of stdout is the result, and exists only after a run on the chip:
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``.
@@ -686,8 +687,8 @@ def mesh_parity(args, n_shards: int) -> None:
 
 
 def served_mesh(args, out_dir: Path, n_shards: int) -> None:
-    """(B): 1 broker, 4 partitions — the broker builds the mesh runner itself
-    from the attached devices."""
+    """(B): 1 broker, 4 partitions — the runtime builds the mesh runner
+    itself from the attached devices."""
     from zeebe_tpu.testing.workloads import one_task
 
     tally = Tally()
@@ -709,8 +710,15 @@ def served_mesh(args, out_dir: Path, n_shards: int) -> None:
         say(f"  mesh: dispatches={runner.dispatches} groups="
             f"{runner.groups_dispatched} coalesced={runner.coalesced_dispatches}"
             f" shard devices={sorted(_name(d) for d in runner.shard_devices)}")
-        say("  note: the mesh path skips shadow verification (ROADMAP S7); "
-            "its correctness evidence is (A) and the journal replay")
+        from zeebe_tpu.engine.device_health import shared_device_health
+
+        health = shared_device_health()
+        say(f"  shadow, (A) and (B): {health.shadow_checks} kernel groups "
+            f"(the direct path's and the mesh's) re-run by the host oracle, "
+            f"{health.shadow_mismatches} mismatches")
+        require(health.shadow_checks >= runner.groups_dispatched
+                and health.shadow_mismatches == 0,
+                "mesh groups unverified or mismatched")
         require(runner.dispatches > 0 and runner.coalesced_dispatches > 0,
                 "no (coalesced) mesh dispatch")
         require(len(runner.shard_devices) == n_shards,

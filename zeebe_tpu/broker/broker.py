@@ -499,18 +499,15 @@ class Broker:
             return None  # the kernel backend is the runner's only consumer
         if self._injected_mesh_runner is not None:
             return self._injected_mesh_runner
-        shards = self.cfg.kernel_mesh_shards
-        if shards < 0:
-            # auto: shard over the attached devices, capped at the broker's
-            # partition count (extra shards would be permanent dummy-block
-            # padding and larger per-chunk transfers); below 2 the direct
-            # single-device dispatch path wins (no runner indirection)
-            shards = min(len(self._devices), self.cfg.partition_count)
-            if shards < 2:
-                shards = 0
-        if shards > 0 and self._owned_mesh_runner is None:
-            from zeebe_tpu.parallel.mesh_runner import MeshKernelRunner
+        from zeebe_tpu.parallel.mesh_runner import MeshKernelRunner, mesh_shards
 
+        # auto (-1) shards over the attached devices, capped at the broker's
+        # partition count (extra shards would be permanent dummy-block
+        # padding and larger per-chunk transfers); below 2 the direct
+        # single-device dispatch path wins (no runner indirection)
+        shards = mesh_shards(self.cfg.kernel_mesh_shards,
+                             self.cfg.partition_count, len(self._devices))
+        if shards > 0 and self._owned_mesh_runner is None:
             self._owned_mesh_runner = MeshKernelRunner(n_shards=shards)
         return self._owned_mesh_runner
 

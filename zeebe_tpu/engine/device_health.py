@@ -35,9 +35,11 @@ offline gate joins against the injected-fault ledger.
 
 Scope caveats (also in docs/device-faults.md): the ladder is per-BROKER
 (one state for every partition in the process, matching the shared
-router), per-process not per-chip, and it watches the *direct* dispatch
-path only — mesh dispatch is neither watched nor shadow-verified
-(ROADMAP S7).
+router), per-process not per-chip, and it gates the *direct* dispatch
+path only: mesh groups are shadow-verified at the same sample rate and a
+mismatch latches SUSPECT (which boosts the sample), but the watchdog, the
+quarantine's host routing and the canaries do not reach the mesh
+(ROADMAP S7, D1).
 """
 
 from __future__ import annotations

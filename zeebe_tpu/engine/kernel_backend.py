@@ -1091,6 +1091,22 @@ def _device_of(array):
     return device
 
 
+def _repadded(arrays: dict, geometry: tuple) -> np.ndarray:
+    """A group's packed state at a larger ``geometry`` (instances, tokens,
+    slots, elements), padded as the mesh runner pads a shard block: tokens
+    past the group's own are empty, instances past its own are done."""
+    from zeebe_tpu.ops.automaton import packed_state_layout, packed_state_views
+
+    packed = np.zeros(packed_state_layout(geometry)[1], np.int32)
+    views = packed_state_views(packed, geometry)
+    views["elem"][:] = -1
+    views["done"][:] = 1
+    for name in ("elem", "phase", "inst", "def_of", "var_slots",
+                 "join_counts", "mi_left", "done"):
+        views[name][:len(arrays[name])] = arrays[name]
+    return packed
+
+
 class DeviceWedgedError(RuntimeError):
     """A device dispatch/fetch exceeded the per-dispatch watchdog deadline
     (``ZEEBE_BROKER_DEVICE_DISPATCHTIMEOUTMS``) — the gray-failure shape a
@@ -1246,6 +1262,9 @@ class _PendingGroup:
     shadow: bool = False
     canary: bool = False
     raw_rows: list = field(default_factory=list)
+    # a mesh group's rows come in the geometry of the dispatch that carried
+    # it, (instances, tokens, chunk steps); the oracle runs at the same one
+    shadow_geometry: tuple | None = None
     corrupt_tokens: list = field(default_factory=list)
     # stage wall times (seconds), observed by the stream processor.
     # Single-device groups split device_elapsed into its three parts:
@@ -1300,6 +1319,10 @@ class KernelBackend:
         # partition's groups run as shards of ONE mesh dispatch, coalescing
         # with other partitions' concurrently submitted groups
         self.mesh_runner = mesh_runner
+        if mesh_runner is not None:
+            from zeebe_tpu.parallel.mesh_runner import M_WAIT
+
+            self._m_mesh_wait = M_WAIT.labels(str(engine.state.partition_id))
         # must match the stream processor's batch budget: the host-escape
         # drain accounts commands exactly like the sequential batch loop
         self.max_commands_in_batch = max_commands_in_batch
@@ -2238,6 +2261,9 @@ class KernelBackend:
         pg.tables = self.registry.tables
         if self.mesh_runner is not None:
             pg.mesh = True
+            # the same sample stream as a single-device group's; canaries
+            # never ride the mesh (the ladder does not gate it)
+            pg.shadow = self._shadow_sampled()
             return
 
         # link-aware backend choice: the identical program, on the device
@@ -2295,6 +2321,11 @@ class KernelBackend:
         if pg.mesh:
             from zeebe_tpu.parallel.mesh_runner import GroupRequest
 
+            tracer = self._tracer
+            group_trace = None
+            if tracer.enabled and pg.admitted:
+                group_trace = (f"{self._partition_id}:"
+                               f"g{pg.admitted[0].cmd.position}")
             t0 = _time.perf_counter()
             result = self.mesh_runner.submit(GroupRequest(
                 device_tables=self.registry.device_tables,
@@ -2305,9 +2336,18 @@ class KernelBackend:
                 num_tokens=pg.T,
                 max_steps=self.max_steps,
                 chunk_steps=self.chunk_steps,
+                partition=self._partition_id,
+                trace=group_trace,
+                keep_rows=pg.shadow,
             ))
             submit_dur = _time.perf_counter() - t0
             pg.device_elapsed += submit_dur
+            if result.rows is not None:
+                pg.raw_rows = result.rows
+                pg.shadow_geometry = result.geometry
+            if result.steps is not None:
+                # a dispatch that raised carries nothing to wait for
+                self._m_mesh_wait.observe(result.wait_s)
             if result.steps is None:
                 pg.fail_reason = "mesh-dispatch-error"
                 logger.warning("mesh kernel dispatch errored; falling back")
@@ -2321,10 +2361,7 @@ class KernelBackend:
             # fused-dispatch refactor changes exactly this window, so it
             # must arrive measurable — one span per submit on the wave's
             # group trace, outcome included so declined submits are visible
-            tracer = self._tracer
-            if tracer.enabled and pg.admitted:
-                group_trace = (f"{self._partition_id}:"
-                               f"g{pg.admitted[0].cmd.position}")
+            if group_trace is not None:
                 # group spans bypass head sampling — they carry the
                 # substitution intervals for every sampled command
                 tracer.emit(
@@ -2595,8 +2632,7 @@ class KernelBackend:
         corruption model), which is what the gate proves."""
         import jax
 
-        from zeebe_tpu.ops.automaton import unpack_events
-
+        from zeebe_tpu.ops.automaton import run_collect_packed, unpack_events
         from zeebe_tpu.utils import backend
 
         # the oracle's device never depends on the router's verdict: on an
@@ -2607,14 +2643,26 @@ class KernelBackend:
         if host_dev == backend.devices()[0]:
             host_dev = None
         dt = self.registry.device_tables_for(host_dev)
-        chunk = self.chunk_steps
-        T, I = pg.T, pg.I
-        FO = pg.tables.out_target.shape[2]
+        tables = pg.tables
+        # a mesh dispatch may have re-padded the group to a larger bucket and
+        # chunk: the oracle runs at that geometry, so that padding alone can
+        # never read as a mismatch
+        I, T, chunk = pg.shadow_geometry or (pg.I, pg.T, self.chunk_steps)
+        geometry = (I, T, tables.num_slots, tables.max_elements)
+        packed = (pg.packed if (I, T) == (pg.I, pg.T)
+                  else _repadded(pg.arrays, geometry))
+        FO = tables.out_target.shape[2]
         steps: list[dict] = []
         rows: list = []
         max_chunks = max(1, self.max_steps // chunk)
-        with _device_ctx(host_dev):
-            run = self._run_chunk(pg, dt, pg.packed)
+
+        def run_chunk(state):
+            with _device_ctx(host_dev):
+                return run_collect_packed(dt, state, geometry=geometry,
+                                          n_steps=chunk,
+                                          config=tables.kernel_config)
+
+        run = run_chunk(packed)
         self.shadow_by_device[_device_of(run[1])] += 1
         for k in range(max_chunks):
             carry, packed = run
@@ -2629,8 +2677,7 @@ class KernelBackend:
             if quiesced.size:
                 return steps, rows
             if k + 1 < max_chunks:
-                with _device_ctx(host_dev):
-                    run = self._run_chunk(pg, dt, carry)
+                run = run_chunk(carry)
         # the oracle did not quiesce: the group is genuinely pathological —
         # raise so the caller abandons it (sequential host re-execution)
         raise RuntimeError(
@@ -2735,8 +2782,9 @@ class KernelBackend:
         # host-routed (typed accounting) except the periodic canary — ONE
         # group per interval dispatched under FORCED shadow verification (a
         # known-answer probe: the host oracle is the answer, so a wrong
-        # canary cannot commit wrong bytes). Mesh dispatch is not watched
-        # by the ladder and is not gated here (ROADMAP S7).
+        # canary cannot commit wrong bytes). Mesh groups are shadow-sampled
+        # and a mismatch latches the ladder, but the ladder does not gate
+        # the mesh here (ROADMAP S7, D1).
         canary = False
         if self.mesh_runner is None and self.health.is_quarantined():
             if self.health.canary_due():
@@ -2823,7 +2871,7 @@ class KernelBackend:
         if pg is None:
             return [], []
         steps = self._await_kernel(pg)
-        if steps is not None and not pg.mesh and pg.shadow:
+        if steps is not None and pg.shadow:
             # the validation/shadow seam (ISSUE 15): the ONLY way a device
             # result may proceed toward the group transaction when sampled
             # — on mismatch the host oracle's steps come back instead

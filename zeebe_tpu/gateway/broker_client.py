@@ -192,7 +192,7 @@ class ClusterRuntime(GatewayRuntimeBase):
                  backup_store_directory=None,
                  backup_store=None,
                  kernel_backend: bool = True,
-                 kernel_mesh_shards: int = 0) -> None:
+                 kernel_mesh_shards: int = -1) -> None:
         self.partition_count = partition_count
         self.net = LoopbackNetwork(lanes=partition_count)
         self._lock = threading.RLock()
@@ -217,19 +217,30 @@ class ClusterRuntime(GatewayRuntimeBase):
         self.brokers: dict[str, Broker] = {}
         # one mesh per process: every in-process broker's partitions submit
         # kernel groups to the SAME runner, so the whole cluster's batch
-        # coalesces onto one device mesh (partition = shard, SURVEY §2.13)
+        # coalesces onto one device mesh (partition = shard, SURVEY §2.13).
+        # -1 (auto) is resolved here, once, by the broker's own rule; 0 is
+        # off for every broker too, which would otherwise resolve auto alone
         self.mesh_runner = None
-        if kernel_mesh_shards > 0 and kernel_backend:
-            from zeebe_tpu.parallel.mesh_runner import MeshKernelRunner
+        shards = 0
+        if kernel_backend:
+            from zeebe_tpu.parallel.mesh_runner import (
+                MeshKernelRunner,
+                mesh_shards,
+            )
+            from zeebe_tpu.utils import backend
 
-            self.mesh_runner = MeshKernelRunner(n_shards=kernel_mesh_shards)
+            shards = mesh_shards(kernel_mesh_shards, partition_count,
+                                 len(backend.devices()))
+            if shards > 0:
+                self.mesh_runner = MeshKernelRunner(n_shards=shards)
         from pathlib import Path
 
         for m in members:
             cfg = BrokerCfg(node_id=m, partition_count=partition_count,
                             replication_factor=replication_factor,
                             cluster_members=members,
-                            kernel_backend=kernel_backend)
+                            kernel_backend=kernel_backend,
+                            kernel_mesh_shards=shards)
             self.brokers[m] = Broker(
                 cfg, self.net.join(m),
                 directory=(Path(directory) / m if directory else None),

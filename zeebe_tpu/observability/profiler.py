@@ -388,16 +388,21 @@ REQUEST_PHASES = ("rpc_lock_wait", "rpc_write", "rpc_await")
 # (``DueDateCheckers``) and a correlation's post-commit send to the
 # instance's partition
 CATCH_PHASES = ("due_sweep", "correlate_send")
+# one sharded dispatch of the mesh runner (``parallel/mesh_runner.py``) on
+# its leading thread: the host-to-device puts of the shards' state, a
+# chunk's sharded program with its fetch, and the shards' rows unpacked
+MESH_PHASES = ("mesh_put", "mesh_run", "mesh_unpack")
 _PHASE_NAMES = {phase: PHASE_PREFIX + phase
-                for phase in PHASES + REQUEST_PHASES + CATCH_PHASES}
+                for phase in PHASES + REQUEST_PHASES + CATCH_PHASES
+                + MESH_PHASES}
 
 
 def phase_annotation(phase: str):
     """``jax.profiler.TraceAnnotation`` over one phase of a kernel group, of
-    a request or of the catch path (``PHASES``, ``REQUEST_PHASES``,
-    ``CATCH_PHASES``), so that any ``jax.profiler`` capture — the tracer on
-    or off — shows what the pump thread or the submitting thread was in
-    beside the device's own events.
+    a request, of the catch path or of a mesh dispatch (``PHASES``,
+    ``REQUEST_PHASES``, ``CATCH_PHASES``, ``MESH_PHASES``), so that any
+    ``jax.profiler`` capture — the tracer on or off — shows what the pump
+    thread or the submitting thread was in beside the device's own events.
     Inert (one atomic read in the profiler) while no capture is recording.
     Only a group's own work is annotated, from ``admit`` (an admission probe
     that found a command) on: the pump's empty probes run every millisecond

@@ -24,21 +24,67 @@ other partitions enqueued — XLA execution releases the GIL, so groups pile
 up naturally while the device is busy), and wakes the waiters. A
 ``run_groups()`` synchronous API underneath is the deterministic seam the
 tests drive directly.
+
+Instruments (always on): a dispatch observes its wall, its host→device
+transfers and the groups it carried into ``stream_processor_pipeline_mesh_*``
+histograms; each result carries its group's wait behind other dispatches,
+which the submitting backend observes under its partition's label. The
+dispatch's three phases are ``zeebe.kernel_chunk.mesh_*`` annotations
+(``profiler.MESH_PHASES``), and with the tracer on the dispatch is a
+``mesh.dispatch`` span in the trace of its leading group.
 """
 
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Any
 
 import numpy as np
 
+from zeebe_tpu.observability.profiler import phase_annotation
 from zeebe_tpu.parallel.mesh import (
     BATCH_AXIS,
     make_mesh,
     state_specs,
 )
+from zeebe_tpu.utils.metrics import REGISTRY
+
+# one observation a group, by its submitting backend (label partition): the
+# wait from submit() entered to the start of the dispatch that carried it
+M_WAIT = REGISTRY.histogram(
+    "stream_processor_pipeline_mesh_wait",
+    "seconds per kernel group from MeshKernelRunner.submit() entered to the "
+    "start of the sharded dispatch that carried it (the wait behind other "
+    "partitions' dispatches on the one leader thread)",
+    ("partition",))
+# one observation a sharded dispatch: its wall, from the first device_put to
+# the last shard's rows unpacked over all chunks; and two counts, not times
+_M_DISPATCH = REGISTRY.histogram(
+    "stream_processor_pipeline_mesh_dispatch",
+    "seconds per sharded mesh dispatch, from the first host-to-device put "
+    "to the last shard's event rows unpacked, over all its chunks")
+_M_UPLOADS = REGISTRY.histogram(
+    "stream_processor_pipeline_mesh_uploads",
+    "host (numpy) arrays a sharded mesh dispatch transferred to the mesh, "
+    "one host-to-device transfer each (a count, not a time)",
+    buckets=(0, 1, 2, 4, 8, 16, 32))
+_M_GROUPS = REGISTRY.histogram(
+    "stream_processor_pipeline_mesh_groups",
+    "kernel groups one sharded mesh dispatch carried (a count, not a time)",
+    buckets=(1, 2, 3, 4, 8, 16))
+
+
+def mesh_shards(requested: int, partitions: int, devices: int) -> int:
+    """The shards a mesh runner gets: ``requested`` when positive, 0 (none)
+    when 0, and for -1 (auto) ``min(devices, partitions)`` — more shards than
+    partitions would be permanent padding — or none below 2, where the
+    direct single-device dispatch wins."""
+    if requested >= 0:
+        return requested
+    shards = min(devices, partitions)
+    return shards if shards >= 2 else 0
 
 
 @dataclass
@@ -58,6 +104,15 @@ class GroupRequest:
     num_tokens: int  # T
     max_steps: int
     chunk_steps: int
+    #: the submitting partition and its group's trace (tracer on): the
+    #: dispatch's span joins the trace of its leading group
+    partition: int = 0
+    trace: str | None = None
+    #: sampled for shadow verification: the result carries this request's
+    #: own shard's event rows, chunk by chunk (nobody else pays for them)
+    keep_rows: bool = False
+    #: perf_counter when ``submit()`` was entered (0: run_groups direct)
+    submitted_at: float = 0.0
 
 
 @dataclass
@@ -65,6 +120,13 @@ class GroupResult:
     steps: list | None  # per-step unpacked event dicts; None → fall back
     overflow: bool = False
     quiesced: bool = True
+    #: the shard's fetched event rows, chunk by chunk (``keep_rows`` only),
+    #: in the dispatch's geometry: (instances, tokens, chunk steps)
+    rows: list | None = None
+    geometry: tuple | None = None
+    #: seconds from ``submit()`` entered to the start of the dispatch that
+    #: carried the group: the wait behind other partitions' dispatches
+    wait_s: float = 0.0
 
 
 @dataclass
@@ -142,6 +204,7 @@ class MeshKernelRunner:
 
         from zeebe_tpu.ops.automaton import unpack_events
 
+        t_start = time.perf_counter()
         self.dispatches += 1
         self.groups_dispatched += len(requests)
         if len(requests) > 1:
@@ -163,76 +226,116 @@ class MeshKernelRunner:
                 blocks.append(np.full_like(blocks[0], fill))
             return np.concatenate(blocks, axis=0)
 
-        elem = shard_arrays("elem", -1)
-        phase = shard_arrays("phase", 0)
-        inst = shard_arrays("inst", 0)
-        def_of = shard_arrays("def_of", 0)
-        var_slots = shard_arrays("var_slots", 0.0)
-        join_counts = shard_arrays("join_counts", 0)
-        mi_left = shard_arrays("mi_left", 0)
-        # padding instances are done upfront so they never report newly_done
-        # (a backend's ``done`` plane is a 0/1 int32 view of its packed buffer)
-        done = shard_arrays("done", True).astype(np.bool_)
-
         mesh = self.mesh
         specs = state_specs()
+        uploads = 0
 
-        def put(name, value):
-            return jax.device_put(value, NamedSharding(mesh, specs[name]))
+        def put(value, spec):
+            nonlocal uploads
+            uploads += 1
+            return jax.device_put(value, NamedSharding(mesh, spec))
 
-        row = NamedSharding(mesh, P(BATCH_AXIS))
-        state = {
-            "elem": put("elem", elem),
-            "phase": put("phase", phase),
-            "inst": put("inst", inst),
-            "def_of": put("def_of", def_of),
-            "var_slots": put("var_slots", var_slots),
-            "join_counts": put("join_counts", join_counts),
-            "mi_left": put("mi_left", mi_left),
-            "done": put("done", done),
-            "incident": put("incident", np.zeros(S * I_c, np.bool_)),
+        with phase_annotation("mesh_put"):
+            state = {
+                name: put(shard_arrays(name, fill), specs[name])
+                for name, fill in (("elem", -1), ("phase", 0), ("inst", 0),
+                                   ("def_of", 0), ("var_slots", 0.0),
+                                   ("join_counts", 0), ("mi_left", 0))
+            }
+            # padding instances are done upfront so they never report
+            # newly_done (a backend's ``done`` plane is a 0/1 int32 view of
+            # its packed buffer)
+            state["done"] = put(shard_arrays("done", True).astype(np.bool_),
+                                specs["done"])
+            state["incident"] = put(np.zeros(S * I_c, np.bool_),
+                                    specs["incident"])
             # counters/overflow are per-shard rows (NOT psum'd: a partition's
             # overflow must fall back alone)
-            "transitions": jax.device_put(np.zeros(S, np.int32), row),
-            "jobs_created": jax.device_put(np.zeros(S, np.int32), row),
-            "completed": jax.device_put(np.zeros(S, np.int32), row),
-            "overflow": jax.device_put(np.zeros(S, np.bool_), row),
-        }
+            for name, dtype in (("transitions", np.int32),
+                                ("jobs_created", np.int32),
+                                ("completed", np.int32),
+                                ("overflow", np.bool_)):
+                state[name] = put(np.zeros(S, dtype), P(BATCH_AXIS))
 
         collect = self._sharded_collect(chunk, lead.config)
+        # and, as the single-device path counts them, any host (numpy) leaf
+        # among the sharded program's own arguments
+        uploads += sum(isinstance(leaf, np.ndarray) for leaf in
+                       jax.tree_util.tree_leaves(lead.device_tables))
         FO = lead.device_tables.out_target.shape[2]
         row_len = T_c * (2 + FO) + 2
         n_req = len(requests)
         steps_per: list[list] = [[] for _ in range(n_req)]
+        rows_per = [[] if r.keep_rows else None for r in requests]
         quiesced = [False] * n_req
         overflow = [False] * n_req
+        chunks = 0
         for _ in range(max(1, max_steps // chunk)):
-            state, packed = collect(lead.device_tables, state)
-            self.shard_devices |= packed.devices()
-            flat = np.asarray(jax.device_get(packed))  # [chunk, S*row_len]
-            for ri in range(n_req):
-                if quiesced[ri]:
-                    continue
-                block = flat[:, ri * row_len : (ri + 1) * row_len]
-                events = block[:, :-2].reshape(chunk, T_c, 2 + FO)
-                active = block[:, -2]
-                # overflow is cumulative in device state; run_collect's
-                # early-exit loop leaves rows past quiescence as zeros, so
-                # any written row carrying the bit is the signal
-                overflow[ri] = overflow[ri] or bool(block[:, -1].any())
-                qs = np.flatnonzero(active == 0)
-                keep = int(qs[0]) + 1 if qs.size else chunk
-                for s in range(keep):
-                    steps_per[ri].append(unpack_events(events[s], I_c))
-                if qs.size:
-                    quiesced[ri] = True
+            with phase_annotation("mesh_run"):
+                state, packed = collect(lead.device_tables, state)
+                self.shard_devices |= packed.devices()
+                flat = self._fetch(packed)  # [chunk, S*row_len]
+            chunks += 1
+            with phase_annotation("mesh_unpack"):
+                for ri in range(n_req):
+                    if quiesced[ri]:
+                        continue
+                    block = flat[:, ri * row_len : (ri + 1) * row_len]
+                    if rows_per[ri] is not None:
+                        rows_per[ri].append(np.ascontiguousarray(block))
+                    events = block[:, :-2].reshape(chunk, T_c, 2 + FO)
+                    active = block[:, -2]
+                    # overflow is cumulative in device state; run_collect's
+                    # early-exit loop leaves rows past quiescence as zeros,
+                    # so any written row carrying the bit is the signal
+                    overflow[ri] = overflow[ri] or bool(block[:, -1].any())
+                    qs = np.flatnonzero(active == 0)
+                    keep = int(qs[0]) + 1 if qs.size else chunk
+                    for s in range(keep):
+                        steps_per[ri].append(unpack_events(events[s], I_c))
+                    if qs.size:
+                        quiesced[ri] = True
             if all(quiesced):
                 break
+        elapsed = time.perf_counter() - t_start
+        _M_DISPATCH.observe(elapsed)
+        _M_UPLOADS.observe(uploads)
+        _M_GROUPS.observe(n_req)
+        self._trace_dispatch(requests, elapsed, I_c, T_c, chunks)
+        geometry = (I_c, T_c, chunk)
         return [
             GroupResult(steps=steps_per[ri], overflow=overflow[ri],
-                        quiesced=quiesced[ri])
-            for ri in range(n_req)
+                        quiesced=quiesced[ri], rows=rows_per[ri],
+                        geometry=geometry,
+                        wait_s=(t_start - r.submitted_at
+                                if r.submitted_at else 0.0))
+            for ri, r in enumerate(requests)
         ]
+
+    @staticmethod
+    def _fetch(packed) -> np.ndarray:
+        """The one device→host transfer of a chunk's event rows (a test
+        substitutes a faulty device here)."""
+        import jax
+
+        return np.asarray(jax.device_get(packed))
+
+    def _trace_dispatch(self, requests: list[GroupRequest], elapsed: float,
+                        I: int, T: int, chunks: int) -> None:
+        """The ``mesh.dispatch`` span (tracer on), in the trace of the
+        dispatch's leading group."""
+        from zeebe_tpu.observability.tracer import get_tracer
+
+        lead = requests[0]
+        tracer = get_tracer()
+        if not tracer.enabled or lead.trace is None:
+            return
+        tracer.emit(lead.trace, "mesh.dispatch", elapsed, lead.partition,
+                    parent="processor.kernel_group",
+                    attrs={"groups": len(requests), "shards": self.n_shards,
+                           "partitions": ",".join(str(r.partition)
+                                                  for r in requests),
+                           "I": I, "T": T, "chunks": chunks})
 
     def _sharded_collect(self, n_steps: int, config):
         key = (n_steps, config)
@@ -286,6 +389,7 @@ class MeshKernelRunner:
         """Execute one group, coalescing with other threads' concurrently
         pending groups. The first submitter leads: it drains the queue (one
         sharded dispatch per compatible batch) until empty, then hands off."""
+        request.submitted_at = time.perf_counter()
         waiter = _Waiter(request)
         with self._lock:
             self._queue.append(waiter)
@@ -302,8 +406,6 @@ class MeshKernelRunner:
         try:
             if self.batch_window_s > 0:
                 if not self.adaptive_window or self._recent_backlog:
-                    import time
-
                     self.windows_slept += 1
                     time.sleep(self.batch_window_s)
                 else:
